@@ -19,6 +19,7 @@ import traceback
 from benchmarks import (bench_compression, bench_energy, bench_fftconv,
                         bench_kernels, bench_model_store, bench_nin_latency,
                         bench_roofline, bench_serving)
+from repro.launch.compile_cache import enable_compile_cache
 
 BENCHES = [
     ("nin_latency", bench_nin_latency.main),        # paper sec 1.1 (C6)
@@ -35,6 +36,7 @@ SMOKE = ("model_store", "compression", "fftconv", "serving")
 
 
 def main() -> None:
+    enable_compile_cache()
     smoke = "--smoke" in sys.argv
     if smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"

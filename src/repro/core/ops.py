@@ -448,92 +448,88 @@ REGISTRY.register(OpSpec(
 # ---------------------------------------------------------------------------
 
 
-def _decode_attn_ref_b(q, k_cache, v_cache, valid_len, *, layout="bksd",
-                       interpret=None):
-    """q: (B, 1, H, D) against a ring cache; valid_len scalar or (B,)."""
+def _decode_attn_ref_b(q, k_cache, v_cache, valid_len, *, interpret=None):
+    """q: (B, 1, H, D) against a (B, KV, S, D) ring cache; valid_len
+    scalar or (B,)."""
     del interpret
     from repro.models.common import attention_decode
-    return attention_decode(q, k_cache, v_cache, valid_len, layout=layout)
+    return attention_decode(q, k_cache, v_cache, valid_len, layout="bksd")
 
 
-def _decode_attn_pallas_b(q, k_cache, v_cache, valid_len, *, layout="bksd",
-                          interpret=None):
+def _decode_attn_pallas_b(q, k_cache, v_cache, valid_len, *, interpret=None):
     from repro.kernels import ops as kops
     out = kops.decode_attention(q[:, 0], k_cache, v_cache, valid_len,
-                                layout=layout, interpret=interpret)
+                                interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 
-def _decode_attn_ref_q8_b(q, k_cache, v_cache, valid_len, *, layout="bksd",
-                          k_scale=None, v_scale=None, interpret=None):
+def _decode_attn_ref_q8_b(q, k_cache, v_cache, valid_len, *, k_scale=None,
+                          v_scale=None, interpret=None):
     """Int8 cache + per-slot scales: the ragged q8 jnp oracle."""
     del interpret
     from repro.kernels.ref import decode_attention_q8_ref
     out = decode_attention_q8_ref(q[:, 0], k_cache, v_cache,
-                                  k_scale, v_scale, valid_len, layout=layout)
+                                  k_scale, v_scale, valid_len)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_pallas_q8_b(q, k_cache, v_cache, valid_len, *,
-                             layout="bksd", k_scale=None, v_scale=None,
-                             interpret=None):
+                             k_scale=None, v_scale=None, interpret=None):
     """Int8 cache + per-slot scales: flash-decode with in-kernel dequant."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_q8(q[:, 0], k_cache, v_cache,
                                    k_scale, v_scale, valid_len,
-                                   layout=layout, interpret=interpret)
+                                   interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_paged_ref_b(q, k_cache, v_cache, valid_len, *,
-                             layout="bksd", page_table=None, interpret=None):
+                             page_table=None, interpret=None):
     """Page pool + per-lane page table: the gather-then-ring jnp oracle."""
     del interpret
     from repro.kernels.ref import decode_attention_paged_ref
     out = decode_attention_paged_ref(q[:, 0], k_cache, v_cache, page_table,
-                                     valid_len, layout=layout)
+                                     valid_len)
     return out[:, None].astype(q.dtype)
 
 
-def _decode_attn_paged_b(q, k_cache, v_cache, valid_len, *, layout="bksd",
-                         page_table=None, interpret=None):
+def _decode_attn_paged_b(q, k_cache, v_cache, valid_len, *, page_table=None,
+                         interpret=None):
     """Page pool + per-lane page table: flash-decode with the page table
     as a second scalar-prefetch operand (index maps do the gather)."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged(q[:, 0], k_cache, v_cache, page_table,
-                                      valid_len, layout=layout,
-                                      interpret=interpret)
+                                      valid_len, interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_paged_ref_q8_b(q, k_cache, v_cache, valid_len, *,
-                                layout="bksd", k_scale=None, v_scale=None,
-                                page_table=None, interpret=None):
+                                k_scale=None, v_scale=None, page_table=None,
+                                interpret=None):
     """Paged int8 pools + per-slot scale pools: the jnp oracle."""
     del interpret
     from repro.kernels.ref import decode_attention_paged_q8_ref
     out = decode_attention_paged_q8_ref(q[:, 0], k_cache, v_cache, k_scale,
-                                        v_scale, page_table, valid_len,
-                                        layout=layout)
+                                        v_scale, page_table, valid_len)
     return out[:, None].astype(q.dtype)
 
 
-def _decode_attn_paged_q8_b(q, k_cache, v_cache, valid_len, *,
-                            layout="bksd", k_scale=None, v_scale=None,
-                            page_table=None, interpret=None):
+def _decode_attn_paged_q8_b(q, k_cache, v_cache, valid_len, *, k_scale=None,
+                            v_scale=None, page_table=None, interpret=None):
     """Paged int8 pools: flash-decode, page-table-indirected scale DMA +
     in-kernel dequant."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged_q8(q[:, 0], k_cache, v_cache, k_scale,
                                          v_scale, page_table, valid_len,
-                                         layout=layout, interpret=interpret)
+                                         interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 
 def resolve_decode_backend(name: Optional[str], quantized: bool = False,
                            paged: bool = False) -> str:
-    """``None``/'auto' -> 'pallas' on TPU (Mosaic kernel), 'ref' elsewhere
-    (the interpret-mode kernel would only emulate the block skipping).
+    """``None``/'auto' -> 'pallas' on TPU (Mosaic kernel), 'ref' on the
+    CPU (the interpret-mode kernel would only emulate the block
+    skipping); any other backend raises rather than guess.
 
     ``quantized=True`` (int8 KV cache) maps the base names onto their q8
     twins — 'ref' -> 'ref_q8', 'pallas' -> 'pallas_q8'; ``paged=True``
@@ -542,7 +538,12 @@ def resolve_decode_backend(name: Optional[str], quantized: bool = False,
     so callers keep selecting implementations by the same two names
     regardless of cache dtype OR layout."""
     if name in (None, "auto"):
-        name = "pallas" if jax.default_backend() == "tpu" else "ref"
+        backend = jax.default_backend()
+        if backend not in ("tpu", "cpu"):
+            raise RuntimeError(
+                f"no decode-attention backend chosen for {backend!r}; "
+                f"pass attn_backend='ref' or 'pallas' explicitly")
+        name = "pallas" if backend == "tpu" else "ref"
     if paged and name in ("ref", "pallas"):
         name = "paged_ref" if name == "ref" else "paged"
     if quantized and name in ("ref", "pallas", "paged_ref", "paged"):

@@ -39,16 +39,18 @@ so dequant costs two elementwise multiplies on (G, bs) tiles — no
 transposes, no materialized fp copy of the cache — and composes with the
 block skipping above (skipped blocks also skip their scale DMA).
 
-Layouts: ``bskd`` (k/v ``(B, S, KV, D)`` — the historical kernel-bench
-layout; scales ``(B, S, KV)``) and ``bksd`` (``(B, KV, S, D)`` — the
-serving ring-cache layout, consumed without any transpose; scales
-``(B, KV, S)``).
+Layout: K/V are ``(B, KV, S, D)`` (``bksd``, the serving ring-cache
+layout, consumed without any transpose); int8 scales are ``(B, KV, S)``.
+Inside the call the scales ride as ``(B, KV, 1, S)`` so that their
+``(1, bs)`` block spans the full unit axis, which the TPU's (8, 128)
+block-tiling rule accepts (a ``(1, 1, bs)`` block of the 3-D array does
+not).
 
 Paged caches (PR 7): :func:`decode_attention_paged` reads K/V from a
 global page POOL instead of per-lane rings.  The pool drops the batch
-axis — ``(P, KV, ps, D)`` ('bksd') or ``(P, ps, KV, D)`` ('bskd') — and
-each lane owns a row of an int32 ``page_table`` ``(B, W)`` mapping its
-logical page ``j`` to a physical pool page.  The page table rides in as
+axis — ``(P, KV, ps, D)`` — and each lane owns a row of an int32
+``page_table`` ``(B, W)`` mapping its logical page ``j`` to a physical
+pool page.  The page table rides in as
 a SECOND scalar-prefetch operand, so the only change versus the ring
 kernel is one extra indirection inside the K/V index maps:
 
@@ -76,7 +78,7 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, bs, ns, kv_major, quantized):
+                   scale, bs, ns, quantized):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
@@ -98,18 +100,13 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(si * bs < lane_valid)
     def _flash_update():
         q = q_ref[0, 0].astype(jnp.float32)            # (G, D)
-        if kv_major:                                   # bksd block (1,1,bs,D)
-            k = k_ref[0, 0].astype(jnp.float32)        # (bs, D)
-            v = v_ref[0, 0].astype(jnp.float32)
-        else:                                          # bskd block (1,bs,1,D)
-            k = k_ref[0, :, 0].astype(jnp.float32)
-            v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (bs, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         if quantized:
-            # per-slot K scales dequantize the score COLUMNS — a lane-dim
-            # broadcast over (G, bs), no transpose
-            ks = ks_ref[0, 0] if kv_major else ks_ref[0, :, 0]   # (bs,)
-            s = s * ks[None, :]
+            # per-slot K scales dequantize the score COLUMNS — a (1, bs)
+            # row broadcast over (G, bs), no transpose
+            s = s * ks_ref[0, 0]
         spos = si * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(spos < lane_valid, s, NEG_INF)
         m_prev = m_ref[...]
@@ -120,8 +117,7 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, *rest,
         if quantized:
             # per-slot V scales fold into the probability rows before the
             # PV dot: p . diag(vs) . v_int == (p * vs) . v_int
-            vs = vs_ref[0, 0] if kv_major else vs_ref[0, :, 0]   # (bs,)
-            p = p * vs[None, :]
+            p = p * vs_ref[0, 0]
         acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -132,39 +128,36 @@ def _decode_kernel(valid_ref, q_ref, k_ref, v_ref, *rest,
                        jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def decode_attention(q, k, v, valid_len, *, layout: str = "bskd",
-                     block_s: int = 512, interpret: bool = False,
-                     k_scale=None, v_scale=None):
-    """q: (B, H, D); k, v: (B, S, KV, D) for ``layout='bskd'`` or
-    (B, KV, S, D) for ``layout='bksd'``; valid_len: scalar int32 or a
+def _scale_rows(scale):
+    """(..., KV, S) fp32 scales -> (..., KV, 1, S): the unit axis makes a
+    ``(1, bs)`` block legal under the TPU's (8, 128) tiling rule."""
+    return scale.astype(jnp.float32)[..., None, :]
+
+
+def decode_attention(q, k, v, valid_len, *, block_s: int = 512,
+                     interpret: bool = False, k_scale=None, v_scale=None):
+    """q: (B, H, D); k, v: (B, KV, S, D); valid_len: scalar int32 or a
     per-lane (B,) vector (each entry >= 1 — the number of valid ring
     slots, counted from slot 0).
 
-    When ``k_scale``/``v_scale`` are given (``(B, S, KV)`` for 'bskd',
-    ``(B, KV, S)`` for 'bksd'; fp32), k/v are int8 payloads dequantized
-    per ring slot inside the block loop (the ``pallas_q8`` backend).
+    When ``k_scale``/``v_scale`` ((B, KV, S) fp32) are given, k/v are
+    int8 payloads dequantized per ring slot inside the block loop (the
+    ``pallas_q8`` backend).
     """
     quantized = k_scale is not None
     if quantized:
         assert v_scale is not None
     b, h, d = q.shape
-    if layout == "bskd":
-        s, kvh, seq_axis = k.shape[1], k.shape[2], 1
-    else:
-        assert layout == "bksd", layout
-        kvh, s, seq_axis = k.shape[1], k.shape[2], 2
+    kvh, s = k.shape[1], k.shape[2]
     g = h // kvh
     bs = min(block_s, s)
     pad = (-s) % bs
     if pad:
-        zp = [(0, 0)] * 4
-        zp[seq_axis] = (0, pad)
+        zp = ((0, 0), (0, 0), (0, pad), (0, 0))
         k, v = jnp.pad(k, zp), jnp.pad(v, zp)
         if quantized:
-            sp = [(0, 0)] * 3
-            sp[seq_axis] = (0, pad)      # scale seq axis == cache seq axis
-            k_scale = jnp.pad(k_scale, sp)
-            v_scale = jnp.pad(v_scale, sp)
+            k_scale = jnp.pad(k_scale, zp[:3])
+            v_scale = jnp.pad(v_scale, zp[:3])
     ns = (s + pad) // bs
     scale = 1.0 / math.sqrt(d)
     qg = q.reshape(b, kvh, g, d)
@@ -178,21 +171,10 @@ def decode_attention(q, k, v, valid_len, *, layout: str = "bskd",
         last = jnp.maximum(pl.cdiv(valid_ref[bi], bs) - 1, 0)
         return jnp.minimum(si, last)
 
-    if layout == "bskd":
-        kv_spec = pl.BlockSpec(
-            (1, bs, 1, d),
-            lambda bi, ki, si, vr: (bi, _clamp(si, vr, bi), ki, 0))
-        sc_spec = pl.BlockSpec(
-            (1, bs, 1),
-            lambda bi, ki, si, vr: (bi, _clamp(si, vr, bi), ki))
-    else:
-        kv_spec = pl.BlockSpec(
-            (1, 1, bs, d),
-            lambda bi, ki, si, vr: (bi, ki, _clamp(si, vr, bi), 0))
-        sc_spec = pl.BlockSpec(
-            (1, 1, bs),
-            lambda bi, ki, si, vr: (bi, ki, _clamp(si, vr, bi)))
-
+    kv_spec = pl.BlockSpec(
+        (1, 1, bs, d), lambda bi, ki, si, vr: (bi, ki, _clamp(si, vr, bi), 0))
+    sc_spec = pl.BlockSpec(
+        (1, 1, 1, bs), lambda bi, ki, si, vr: (bi, ki, 0, _clamp(si, vr, bi)))
     in_specs = [
         pl.BlockSpec((1, 1, g, d),
                      lambda bi, ki, si, vr: (bi, ki, 0, 0)),
@@ -202,12 +184,11 @@ def decode_attention(q, k, v, valid_len, *, layout: str = "bskd",
     operands = [valid, qg, k, v]
     if quantized:
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
 
     out = pl.pallas_call(
         functools.partial(_decode_kernel, scale=scale, bs=bs, ns=ns,
-                          kv_major=(layout == "bksd"), quantized=quantized),
+                          quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, kvh, ns),
@@ -236,20 +217,19 @@ def _paged_kernel(valid_ref, pt_ref, *args, **kw):
 
 
 def decode_attention_paged(q, k, v, page_table, valid_len, *,
-                           layout: str = "bskd", interpret: bool = False,
-                           k_scale=None, v_scale=None):
+                           interpret: bool = False, k_scale=None,
+                           v_scale=None):
     """Flash-decode against a paged KV pool.
 
-    q: (B, H, D); k, v: page pools — (P, ps, KV, D) for ``layout='bskd'``
-    or (P, KV, ps, D) for ``layout='bksd'`` (``P`` physical pages of
-    ``ps`` sequence slots); page_table: (B, W) int32 mapping each lane's
-    logical page j to a pool page (logical position ``t`` of lane ``b``
-    lives at ``k[page_table[b, t // ps], ..., t % ps, ...]``); valid_len:
+    q: (B, H, D); k, v: page pools (P, KV, ps, D) (``P`` physical pages
+    of ``ps`` sequence slots); page_table: (B, W) int32 mapping each
+    lane's logical page j to a pool page (logical position ``t`` of lane
+    ``b`` lives at ``k[page_table[b, t // ps], :, t % ps]``); valid_len:
     scalar or per-lane (B,) count of valid logical slots.
 
-    With ``k_scale``/``v_scale`` ((P, ps, KV) / (P, KV, ps) fp32 scale
-    pools) the payload pools are int8, dequantized per slot inside the
-    block loop exactly as in the ring kernel.
+    With ``k_scale``/``v_scale`` ((P, KV, ps) fp32 scale pools) the
+    payload pools are int8, dequantized per slot inside the block loop
+    exactly as in the ring kernel.
 
     The block size is the page size, so every lane reads exactly
     ``ceil(valid_len / ps)`` pages — fragmentation in the pool costs
@@ -261,11 +241,7 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
     if quantized:
         assert v_scale is not None
     b, h, d = q.shape
-    if layout == "bskd":
-        ps, kvh = k.shape[1], k.shape[2]
-    else:
-        assert layout == "bksd", layout
-        kvh, ps = k.shape[1], k.shape[2]
+    kvh, ps = k.shape[1], k.shape[2]
     w = page_table.shape[1]
     g = h // kvh
     scale = 1.0 / math.sqrt(d)
@@ -281,21 +257,12 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
         last = jnp.maximum(pl.cdiv(valid_ref[bi], ps) - 1, 0)
         return pt_ref[bi, jnp.minimum(si, last)]
 
-    if layout == "bskd":
-        kv_spec = pl.BlockSpec(
-            (1, ps, 1, d),
-            lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), 0, ki, 0))
-        sc_spec = pl.BlockSpec(
-            (1, ps, 1),
-            lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), 0, ki))
-    else:
-        kv_spec = pl.BlockSpec(
-            (1, 1, ps, d),
-            lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), ki, 0, 0))
-        sc_spec = pl.BlockSpec(
-            (1, 1, ps),
-            lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), ki, 0))
-
+    kv_spec = pl.BlockSpec(
+        (1, 1, ps, d),
+        lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), ki, 0, 0))
+    sc_spec = pl.BlockSpec(
+        (1, 1, 1, ps),
+        lambda bi, ki, si, vr, pr: (_page(si, vr, pr, bi), ki, 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, g, d),
                      lambda bi, ki, si, vr, pr: (bi, ki, 0, 0)),
@@ -305,12 +272,11 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
     operands = [valid, pt, qg, k, v]
     if quantized:
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
+        operands += [_scale_rows(k_scale), _scale_rows(v_scale)]
 
     out = pl.pallas_call(
         functools.partial(_paged_kernel, scale=scale, bs=ps, ns=w,
-                          kv_major=(layout == "bksd"), quantized=quantized),
+                          quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, kvh, w),

@@ -1,9 +1,10 @@
 """Public jit'd wrappers for all Pallas kernels.
 
-On non-TPU backends (this container is CPU) every kernel runs in
-``interpret=True`` mode — the kernel body executes as traced jnp on CPU,
-which is how correctness is validated; on TPU the same calls compile to
-real Mosaic kernels.  Call sites can force either via ``interpret=``.
+On a TPU every kernel compiles to a Mosaic kernel, and asking for
+interpret mode there is an error.  On the CPU (the test backend) every
+kernel runs in ``interpret=True`` mode — the kernel body executes as
+traced jnp, which is how correctness is validated off the chip.  Any
+other backend has no Pallas path here and raises.
 """
 from __future__ import annotations
 
@@ -20,9 +21,15 @@ from repro.kernels import (conv2d as _conv2d_mod, decode_attention as _da,
 
 
 def _interpret(override: Optional[bool]) -> bool:
-    if override is not None:
-        return override
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        if override:
+            raise ValueError("interpret=True on a TPU: the kernel would be "
+                             "emulated instead of compiled")
+        return False
+    if backend == "cpu":
+        return True if override is None else override
+    raise RuntimeError(f"no Pallas kernel path for backend {backend!r}")
 
 
 # thin wrappers (jit applied here so benchmarks measure steady-state)
@@ -92,46 +99,39 @@ def flash_attention_trainable(q, k, v, *, causal=True, window=0,
         q, k, v, causal, window, block_q, block_k, _interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "block_s",
-                                             "interpret"))
-def decode_attention(q, k, v, valid_len, *, layout="bskd", block_s=512,
-                     interpret=None):
-    return _da.decode_attention(q, k, v, valid_len, layout=layout,
-                                block_s=block_s,
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
+def decode_attention(q, k, v, valid_len, *, block_s=512, interpret=None):
+    return _da.decode_attention(q, k, v, valid_len, block_s=block_s,
                                 interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "block_s",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def decode_attention_q8(q, k, v, k_scale, v_scale, valid_len, *,
-                        layout="bskd", block_s=512, interpret=None):
+                        block_s=512, interpret=None):
     """Int8-cache flash-decode: k/v are int8 payloads dequantized inside
     the block loop with per-(lane, head, slot) fp32 scales."""
-    return _da.decode_attention(q, k, v, valid_len, layout=layout,
-                                block_s=block_s, k_scale=k_scale,
-                                v_scale=v_scale,
+    return _da.decode_attention(q, k, v, valid_len, block_s=block_s,
+                                k_scale=k_scale, v_scale=v_scale,
                                 interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_paged(q, k, v, page_table, valid_len, *,
-                           layout="bskd", interpret=None):
+                           interpret=None):
     """Paged flash-decode: K/V live in a global page pool, each lane's
     int32 page-table row supplies the physical page per KV block (block
     size = page size)."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
-                                      layout=layout,
                                       interpret=_interpret(interpret))
 
 
-@functools.partial(jax.jit, static_argnames=("layout", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_paged_q8(q, k, v, k_scale, v_scale, page_table,
-                              valid_len, *, layout="bskd", interpret=None):
+                              valid_len, *, interpret=None):
     """Paged int8 flash-decode: page-table indirection over int8 payload
     pools AND their per-slot fp32 scale pools, dequant in the block loop."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
-                                      layout=layout, k_scale=k_scale,
-                                      v_scale=v_scale,
+                                      k_scale=k_scale, v_scale=v_scale,
                                       interpret=_interpret(interpret))
 
 

@@ -47,37 +47,35 @@ def int8_matmul_ref(a_q, b_q, a_scale, b_scale):
     return acc.astype(jnp.float32) * a_scale[:, None] * b_scale[None, :]
 
 
-def decode_attention_ref(q, k, v, valid_len, *, layout="bskd"):
-    """q: (B, H, D); k, v: (B, S, KV, D) ('bskd') or (B, KV, S, D)
-    ('bksd'); valid_len: scalar int or per-lane (B,) vector."""
+def decode_attention_ref(q, k, v, valid_len):
+    """q: (B, H, D); k, v: (B, KV, S, D); valid_len: scalar int or
+    per-lane (B,) vector."""
     b, h, d = q.shape
-    if layout == "bksd":
-        k = k.transpose(0, 2, 1, 3)
-        v = v.transpose(0, 2, 1, 3)
-    s, kvh = k.shape[1], k.shape[2]
+    kvh, s = k.shape[1], k.shape[2]
     g = h // kvh
     qg = q.reshape(b, kvh, g, d)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
+    scores = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(d)
-    valid = jnp.asarray(valid_len)
-    if valid.ndim == 0:
-        mask = (jnp.arange(s) < valid)[None, None, None]
-    else:
-        mask = (jnp.arange(s)[None, :] < valid[:, None])[:, None, None]
-    scores = jnp.where(mask, scores, -1e30)
+    scores = jnp.where(_valid_mask(valid_len, s), scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v.astype(jnp.float32))
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def decode_attention_q8_ref(q, k_q, v_q, k_scale, v_scale, valid_len, *,
-                            layout="bskd"):
+def _valid_mask(valid_len, s):
+    """(B|1, 1, 1, S) mask of the slots below each lane's valid length."""
+    valid = jnp.asarray(valid_len)
+    if valid.ndim == 0:
+        return (jnp.arange(s) < valid)[None, None, None]
+    return (jnp.arange(s)[None, :] < valid[:, None])[:, None, None]
+
+
+def decode_attention_q8_ref(q, k_q, v_q, k_scale, v_scale, valid_len):
     """Ragged q8 decode oracle: int8 K/V payloads + one fp32 scale per
     (lane, kv-head, ring slot), fp32 accumulation throughout.
 
-    q: (B, H, D); k_q, v_q: int8 (B, S, KV, D) ('bskd') or (B, KV, S, D)
-    ('bksd'); k_scale, v_scale: (B, S, KV) / (B, KV, S); valid_len:
-    scalar int or per-lane (B,) vector.
+    q: (B, H, D); k_q, v_q: int8 (B, KV, S, D); k_scale, v_scale:
+    (B, KV, S); valid_len: scalar int or per-lane (B,) vector.
 
     Scales are applied in the SAME order as the Pallas kernel — K scales
     multiply the score columns after the QK dot, V scales fold into the
@@ -85,70 +83,54 @@ def decode_attention_q8_ref(q, k_q, v_q, k_scale, v_scale, valid_len, *,
     limited only by the online-softmax accumulation order.
     """
     b, h, d = q.shape
-    if layout == "bksd":
-        k_q = k_q.transpose(0, 2, 1, 3)
-        v_q = v_q.transpose(0, 2, 1, 3)
-        k_scale = k_scale.transpose(0, 2, 1)      # -> (B, S, KV)
-        v_scale = v_scale.transpose(0, 2, 1)
-    s, kvh = k_q.shape[1], k_q.shape[2]
+    kvh, s = k_q.shape[1], k_q.shape[2]
     g = h // kvh
     qg = q.reshape(b, kvh, g, d)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg.astype(jnp.float32),
+    scores = jnp.einsum("bkgd,bksd->bkgs", qg.astype(jnp.float32),
                         k_q.astype(jnp.float32)) / math.sqrt(d)
-    # (B, S, KV) -> (B, KV, 1, S) broadcast over the g query heads
-    scores = scores * k_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
-    valid = jnp.asarray(valid_len)
-    if valid.ndim == 0:
-        mask = (jnp.arange(s) < valid)[None, None, None]
-    else:
-        mask = (jnp.arange(s)[None, :] < valid[:, None])[:, None, None]
-    scores = jnp.where(mask, scores, -1e30)
+    # (B, KV, S) -> (B, KV, 1, S) broadcast over the g query heads
+    scores = scores * k_scale.astype(jnp.float32)[:, :, None]
+    scores = jnp.where(_valid_mask(valid_len, s), scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    probs = probs * v_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None]
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v_q.astype(jnp.float32))
+    probs = probs * v_scale.astype(jnp.float32)[:, :, None]
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v_q.astype(jnp.float32))
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_gather(pool, page_table, *, layout="bksd"):
+def paged_gather(pool, page_table):
     """Gather a lane-major ring-equivalent cache out of a page pool.
 
-    pool: (P, KV, ps, D) ('bksd') or (P, ps, KV, D) ('bskd') payloads —
-    or the scale pools (P, KV, ps) / (P, ps, KV); page_table: (B, W)
-    int32.  Returns the (B, KV, W*ps, D)-shaped (resp. (B, W*ps, KV, D),
-    and the scale analogues) array in which lane b's logical slot t is
-    ``pool[page_table[b, t // ps]][..., t % ps, ...]`` — a pure memory
-    reorder, so any ring-cache oracle applied to the gather is
-    bit-identical to true paged attention.
+    pool: (P, KV, ps, D) payloads — or the scale pools (P, KV, ps);
+    page_table: (B, W) int32.  Returns the (B, KV, W*ps, D)-shaped
+    (resp. (B, KV, W*ps)) array in which lane b's logical slot t is
+    ``pool[page_table[b, t // ps]][:, t % ps]`` — a pure memory reorder,
+    so any ring-cache oracle applied to the gather is bit-identical to
+    true paged attention.
     """
-    g = pool[page_table]                # (B, W, *page_shape)
+    g = pool[page_table]                # (B, W, KV, ps[, D])
     b, w = g.shape[:2]
-    if layout == "bskd":                # page (ps, KV[, D]) — seq leads
-        return g.reshape(b, w * g.shape[2], *g.shape[3:])
-    assert layout == "bksd", layout     # page (KV, ps[, D]) — seq 2nd
     g = jnp.moveaxis(g, 1, 2)           # (B, KV, W, ps[, D])
     return g.reshape(b, g.shape[1], w * g.shape[3], *g.shape[4:])
 
 
-def decode_attention_paged_ref(q, k_pool, v_pool, page_table, valid_len, *,
-                               layout="bksd"):
+def decode_attention_paged_ref(q, k_pool, v_pool, page_table, valid_len):
     """Paged decode oracle: gather pages into the equivalent ring layout
     and reuse the ragged ring oracle.  q: (B, H, D); pools as in
     :func:`paged_gather`; valid_len counts LOGICAL slots (< W*ps)."""
-    k = paged_gather(k_pool, page_table, layout=layout)
-    v = paged_gather(v_pool, page_table, layout=layout)
-    return decode_attention_ref(q, k, v, valid_len, layout=layout)
+    k = paged_gather(k_pool, page_table)
+    v = paged_gather(v_pool, page_table)
+    return decode_attention_ref(q, k, v, valid_len)
 
 
 def decode_attention_paged_q8_ref(q, k_pool, v_pool, k_scale, v_scale,
-                                  page_table, valid_len, *, layout="bksd"):
+                                  page_table, valid_len):
     """Paged int8 decode oracle: gather payload AND per-slot scale pools
     through the page table, then reuse the ragged q8 ring oracle."""
-    k = paged_gather(k_pool, page_table, layout=layout)
-    v = paged_gather(v_pool, page_table, layout=layout)
-    ks = paged_gather(k_scale, page_table, layout=layout)
-    vs = paged_gather(v_scale, page_table, layout=layout)
-    return decode_attention_q8_ref(q, k, v, ks, vs, valid_len,
-                                   layout=layout)
+    k = paged_gather(k_pool, page_table)
+    v = paged_gather(v_pool, page_table)
+    ks = paged_gather(k_scale, page_table)
+    vs = paged_gather(v_scale, page_table)
+    return decode_attention_q8_ref(q, k, v, ks, vs, valid_len)
 
 
 def flash_attention_ref(q, k, v, *, causal=True, window=0):

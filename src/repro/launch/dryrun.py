@@ -34,7 +34,7 @@ from repro.sharding_hints import axis_rules
 # TPU v5e hardware constants (per chip) — shared with the serving
 # roofline accountant so dryrun estimates and live MBU/MFU gauges are
 # anchored to the same peaks.
-HW = hlo_costs.HW_PEAKS["tpu"]
+HW = hlo_costs.HW_PEAKS[hlo_costs.V5E]
 
 ARCHS = [
     "rwkv6-3b", "whisper-medium", "qwen3-8b", "chameleon-34b",
@@ -110,13 +110,13 @@ def dryrun(arch: str, shape_name: str, *, multi_pod: bool = False,
     t0 = time.time()
     with axis_rules(rules, mesh):
         fn, structs, shardings = build_step(cfg, shape, rules, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, in_shardings=shardings).lower(*structs)
             t_lower = time.time() - t0
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
     mem = compiled.memory_analysis()
-    cost = hlo_costs.xla_cost_analysis(compiled)   # version-portable dict
+    cost = hlo_costs.xla_cost_analysis(compiled)
     hlo = compiled.as_text()
     # trip-count-aware analysis (cost_analysis counts while bodies ONCE —
     # every model scans over layers, so it understates by ~num_layers)

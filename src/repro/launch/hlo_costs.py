@@ -41,27 +41,40 @@ _DTYPE_BYTES = {
 }
 
 # Hardware peak table shared by the dryrun estimator and the serving
-# roofline accountant (runtime/roofline.py).  Values are per chip/host.
-# The TPU row is v5e; the CPU row is a deliberately modest dev-box figure
-# so CPU-smoke MBU numbers are indicative, not comparable across machines
-# (override via REPRO_HW_PEAK_FLOPS / REPRO_HW_HBM_BW / REPRO_HW_ICI_BW).
+# roofline accountant (runtime/roofline.py), keyed by
+# ``jax.Device.device_kind``.  Values are per chip.  A device kind that
+# is not listed is an error (:func:`hw_peaks`): no accelerator inherits
+# another's peaks.
+V5E = "TPU v5 lite"
 HW_PEAKS = {
-    "tpu": {"name": "tpu-v5e", "peak_flops": 197e12, "hbm_bw": 819e9,
-            "ici_bw": 50e9},
-    "gpu": {"name": "gpu-generic", "peak_flops": 60e12, "hbm_bw": 1.0e12,
-            "ici_bw": 25e9},
-    "cpu": {"name": "cpu-host", "peak_flops": 2.0e11, "hbm_bw": 5.0e10,
-            "ici_bw": 1e9},
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s, 1,600 Gbit/s (200 GB/s) chip-to-chip interconnect
+    V5E: {"name": "tpu-v5e", "peak_flops": 197e12, "hbm_bw": 819e9,
+          "ici_bw": 200e9},
+    # The host CPU, for tests only: a nominal dev-box figure, neither
+    # published nor measured, so CPU MBU numbers show shape, not magnitude
+    "cpu": {"name": "cpu-host (test figure)", "peak_flops": 2.0e11,
+            "hbm_bw": 5.0e10, "ici_bw": 1e9},
 }
+
+
+def hw_peaks(device_kind: str) -> Dict[str, float]:
+    """The :data:`HW_PEAKS` row of ``device_kind``; raises ``KeyError``
+    for a device without published peaks in the table."""
+    if device_kind not in HW_PEAKS:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"add its published figures to HW_PEAKS "
+                       f"(have {sorted(HW_PEAKS)})")
+    return HW_PEAKS[device_kind]
 
 
 def roofline_terms(flops: float, hbm_bytes: float, wire_bytes: float = 0.0,
                    hw: Optional[Dict[str, float]] = None) -> Dict[str, object]:
     """Classic roofline decomposition: time lower bounds per resource and
-    the binding one.  ``hw`` is a row of :data:`HW_PEAKS` (default TPU);
+    the binding one.  ``hw`` is a row of :data:`HW_PEAKS` (default v5e);
     the same terms drive ``dryrun`` estimates and the live serving
     accountant, so "achieved vs roofline" means one thing repo-wide."""
-    hw = hw or HW_PEAKS["tpu"]
+    hw = hw or HW_PEAKS[V5E]
     compute_s = flops / hw["peak_flops"]
     memory_s = hbm_bytes / hw["hbm_bw"]
     collective_s = wire_bytes / hw["ici_bw"] if wire_bytes else 0.0
@@ -100,16 +113,9 @@ _FREE_OPS = {
 
 
 def xla_cost_analysis(compiled) -> Dict[str, float]:
-    """Normalize ``Compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a one-element list of per-program dicts, newer JAX
-    returns the dict itself; either way callers get a plain dict with
-    ``.get`` (empty when XLA provides nothing).
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
+    """``Compiled.cost_analysis()`` as a plain dict (empty when XLA
+    provides nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _type_bytes(type_str: str) -> int:

@@ -1,8 +1,12 @@
-"""Production mesh construction.
+"""Mesh construction.
 
 Single pod: 256 TPU v5e chips as (data=16, model=16).
 Multi-pod:  2 pods = 512 chips as (pod=2, data=16, model=16) — the ``pod``
 axis carries pure data parallelism across the DCN/ICI boundary.
+
+Every mesh has ``Auto`` axes: the models place activations with
+``with_sharding_constraint`` hints (``repro.sharding_hints``), which JAX
+accepts only on Auto axes, whereas ``jax.make_mesh`` defaults to Explicit.
 
 Functions, not module-level constants: importing this module never touches
 jax device state (the dry-run sets XLA_FLAGS before any jax import).
@@ -10,10 +14,17 @@ jax device state (the dry-run sets XLA_FLAGS before any jax import).
 from __future__ import annotations
 
 import math
+from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
 
-from repro.launch.compat import make_mesh
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -32,13 +43,13 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 "
             f"(repro.launch.dryrun does this) or on real hardware")
-    if len(devs) == n:
-        return make_mesh(shape, axes)
     return make_mesh(shape, axes, devices=devs[:n])
 
 
-def make_host_mesh(model_axis: int = 1):
-    """Tiny mesh over whatever devices exist (CPU smoke tests)."""
-    n = len(jax.devices())
-    data = n // model_axis
-    return make_mesh((data, model_axis), ("data", "model"))
+def make_host_mesh(model_axis: int = 1, devices: Optional[Sequence] = None):
+    """(data, model) mesh over ``devices`` (default: every visible
+    device), ``model_axis`` of them per model replica."""
+    devs = list(devices) if devices is not None else jax.devices()
+    data = len(devs) // model_axis
+    return make_mesh((data, model_axis), ("data", "model"),
+                     devices=devs[:data * model_axis])

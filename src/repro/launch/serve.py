@@ -1,12 +1,15 @@
 """Serving driver: load models from a store, batch requests, generate.
 
     PYTHONPATH=src python -m repro.launch.serve --store /tmp/store \
-        --model tinyllama-1.1b --requests 8 --max-new 16
+        --model tinyllama-1.1b --requests 8 --max-new 16 [--full]
 
-If the store is empty the driver bootstraps it by publishing a
-reduced-config model with random weights (so the example is runnable
-offline) — the paper's deployment flow: store -> resident cache ->
-continuous-batching generation, with hot switching between models.
+If the store lacks a model the driver bootstraps it by publishing random
+weights from ``--seed`` — the reduced smoke config by default, the
+published widths with ``--full`` (so the example is runnable offline).
+A model already in the store whose config differs from the one asked
+for is an error, never silently served.  The paper's deployment flow:
+store -> resident cache -> continuous-batching generation, with hot
+switching between models.
 Generation runs on the slot-based scheduler (device-side sampling,
 zero host syncs per token); pass ``--aligned`` to drive the legacy
 aligned-batch baseline instead for comparison.
@@ -34,24 +37,37 @@ import numpy as np
 
 from repro import models
 from repro.checkpoint.ckpt import publish_checkpoint
-from repro.configs.base import get_config, reduced as reduce_cfg
+from repro.configs.base import ArchConfig, get_config, reduced as reduce_cfg
 from repro.core.modelstore import ModelStore
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime.metrics_http import MetricsServer
 from repro.runtime.telemetry import Telemetry
 from repro.serving.engine import MultiModelServer, Request
 
 
-def ensure_model(store: ModelStore, arch: str, *, seed: int = 0):
+def ensure_model(store: ModelStore, arch: str, *, full: bool = False,
+                 seed: int = 0) -> None:
+    """Make sure ``arch`` is in ``store`` at the asked config (published
+    widths with ``full``, else the reduced smoke config): publish f32
+    random weights from ``seed`` if it is missing, raise ``ValueError``
+    if the stored config differs."""
+    cfg = get_config(arch) if full else reduce_cfg(get_config(arch))
     try:
-        store.get(arch)
-        return
+        rec = store.get(arch)
     except KeyError:
-        pass
-    cfg = reduce_cfg(get_config(arch))
-    params = models.init_params(cfg, jax.random.PRNGKey(seed))
-    rec = publish_checkpoint(store, arch, cfg, params,
-                             metadata={"bootstrap": True})
-    print(f"bootstrapped {rec.name}:{rec.version} (random reduced weights)")
+        params = models.init_params(cfg, jax.random.PRNGKey(seed))
+        rec = publish_checkpoint(store, arch, cfg, params,
+                                 metadata={"bootstrap": True, "seed": seed})
+        print(f"bootstrapped {rec.name}:{rec.version} (random "
+              f"{'full-width' if full else 'reduced'} weights, seed {seed})")
+        return
+    stored = ArchConfig(**rec.load_spec()["arch"])
+    if stored != cfg:
+        raise ValueError(
+            f"{rec.name}:{rec.version} in {store.root} was published with "
+            f"a different config than the {'full' if full else 'reduced'} "
+            f"{arch} asked for (stored d_model={stored.d_model}, "
+            f"layers={stored.num_layers}); use another --store")
 
 
 def main():
@@ -64,6 +80,11 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-batch", type=int, default=8)
     ap.add_argument("--cache-len", type=int, default=128)
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published-width config (default: "
+                         "reduced smoke variant)")
+    ap.add_argument("--seed", type=int, default=0,
+                    help="seed of the random weights a bootstrap publishes")
     ap.add_argument("--aligned", action="store_true",
                     help="use the legacy aligned-batch loop (baseline)")
     ap.add_argument("--trace", default=None, metavar="PATH",
@@ -82,6 +103,7 @@ def main():
     ap.add_argument("--slo-itl", type=float, default=None, metavar="S",
                     help="default inter-token-latency budget (seconds)")
     args = ap.parse_args()
+    enable_compile_cache()
     model_names = args.model or ["tinyllama-1.1b", "qwen3-0.6b"]
     # a Telemetry bundle exists whenever any observability surface is on;
     # metrics-only runs keep the tracer's memory bound tiny
@@ -100,7 +122,7 @@ def main():
 
     store = ModelStore(args.store)
     for m in model_names:
-        ensure_model(store, m)
+        ensure_model(store, m, full=args.full, seed=args.seed)
     # power-of-two prefill buckets bound XLA compiles to a handful of
     # prompt shapes instead of one executable per distinct length
     buckets, b = [], 4

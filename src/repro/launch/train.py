@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch tinyllama-1.1b \
         --reduced --steps 200 --batch 8 --seq 128 --publish store/
 
-Runs a real data-parallel training loop on whatever devices exist (CPU
-smoke: 1 device; TPU pod: the production mesh), checkpointing into the
-model store so the serving path can load the result — the paper's
-train-once / reuse-everywhere loop closed end to end.
+Runs a real data-parallel training loop over a ``data`` mesh (default:
+every visible device; the parameters are replicated and the global batch
+is split along it), checkpointing into the model store so the serving
+path can load the result — the paper's train-once / reuse-everywhere
+loop closed end to end.
 """
 from __future__ import annotations
 
@@ -16,10 +17,12 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import models
 from repro.configs.base import get_config, reduced as reduce_cfg
 from repro.data.pipeline import DataConfig, SyntheticLM, shard_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim.adamw import AdamW, cosine_schedule
 
@@ -40,22 +43,25 @@ def make_train_step(cfg, opt):
 
 def train(arch: str, *, steps: int = 100, batch: int = 8, seq: int = 128,
           lr: float = 3e-4, warmup: int = 20, use_reduced: bool = True,
-          publish_to=None, log_every: int = 10, seed: int = 0):
+          publish_to=None, log_every: int = 10, seed: int = 0, mesh=None):
+    """Train ``arch`` for ``steps`` steps; ``mesh`` (default
+    :func:`make_host_mesh`) carries the data parallelism."""
     cfg = get_config(arch)
     if use_reduced:
         cfg = reduce_cfg(cfg)
-    mesh = make_host_mesh()
+    mesh = mesh if mesh is not None else make_host_mesh()
+    replicated = NamedSharding(mesh, PartitionSpec())
     key = jax.random.PRNGKey(seed)
-    params = models.init_params(cfg, key)
+    params = jax.device_put(models.init_params(cfg, key), replicated)
     n_params = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
     opt = AdamW(lr=cosine_schedule(lr, warmup, steps))
-    opt_state = opt.init(params)
+    opt_state = jax.device_put(opt.init(params), replicated)
     data = SyntheticLM(DataConfig(vocab_size=cfg.vocab_size, seq_len=seq,
                                   global_batch=batch, seed=seed))
     step_fn = make_train_step(cfg, opt)
 
     print(f"training {cfg.name} ({n_params/1e6:.1f}M params) on "
-          f"{jax.device_count()} device(s), {steps} steps "
+          f"{mesh.size} device(s), {steps} steps "
           f"batch={batch} seq={seq}")
     losses = []
     t0 = time.perf_counter()
@@ -97,6 +103,7 @@ def main():
                     help="full config (default: reduced smoke variant)")
     ap.add_argument("--publish", default=None, metavar="STORE_DIR")
     args = ap.parse_args()
+    enable_compile_cache()
     _, losses = train(args.arch, steps=args.steps, batch=args.batch,
                       seq=args.seq, lr=args.lr, use_reduced=not args.full,
                       publish_to=args.publish)

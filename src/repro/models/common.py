@@ -317,44 +317,35 @@ def attention_decode(q, k_cache, v_cache, valid_len, layout="bskd"):
 # ---------------------------------------------------------------------------
 
 
-def cache_write(cache_k, cache_v, k_new, v_new, pos, seq_axis: int = 1):
-    """Write one token at ring position pos % S (along ``seq_axis``)."""
-    s = cache_k.shape[seq_axis]
+def cache_write(cache_k, cache_v, k_new, v_new, pos):
+    """Write one token at ring position pos % S of (B, KV, S, D) caches;
+    ``k_new``/``v_new``: (B, KV, 1, D)."""
+    s = cache_k.shape[2]
     idx = jnp.mod(pos, s)
     cache_k = lax.dynamic_update_slice_in_dim(
-        cache_k, k_new.astype(cache_k.dtype), idx, axis=seq_axis)
+        cache_k, k_new.astype(cache_k.dtype), idx, axis=2)
     cache_v = lax.dynamic_update_slice_in_dim(
-        cache_v, v_new.astype(cache_v.dtype), idx, axis=seq_axis)
+        cache_v, v_new.astype(cache_v.dtype), idx, axis=2)
     return cache_k, cache_v
 
 
-def cache_write_batch(cache_k, cache_v, k_new, v_new, pos, seq_axis: int = 2):
+def cache_write_batch(cache_k, cache_v, k_new, v_new, pos):
     """Per-lane ring write for the lane-major batched decode step.
 
     ``pos`` is a (B,) vector of absolute positions; lane b's token lands
-    at ring slot ``pos[b] % S``.  ``k_new``/``v_new``: (B, KV, 1, D) for
-    ``seq_axis=2`` (bksd caches) or (B, 1, KV, D) for ``seq_axis=1``
-    (bskd caches).
+    at ring slot ``pos[b] % S`` of the (B, KV, S, D) caches.
+    ``k_new``/``v_new``: (B, KV, 1, D).
     """
-    s = cache_k.shape[seq_axis]
+    s = cache_k.shape[2]
     idx = jnp.mod(pos, s)
     rows = jnp.arange(cache_k.shape[0])
-    if seq_axis == 2:
-        cache_k = cache_k.at[rows, :, idx].set(
-            k_new[:, :, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, :, idx].set(
-            v_new[:, :, 0].astype(cache_v.dtype))
-    else:
-        assert seq_axis == 1, seq_axis
-        cache_k = cache_k.at[rows, idx].set(
-            k_new[:, 0].astype(cache_k.dtype))
-        cache_v = cache_v.at[rows, idx].set(
-            v_new[:, 0].astype(cache_v.dtype))
+    cache_k = cache_k.at[rows, :, idx].set(k_new[:, :, 0].astype(cache_k.dtype))
+    cache_v = cache_v.at[rows, :, idx].set(v_new[:, :, 0].astype(cache_v.dtype))
     return cache_k, cache_v
 
 
 def cache_write_batch_q8(cache_k, cache_v, scale_k, scale_v, k_new, v_new,
-                         pos, seq_axis: int = 2):
+                         pos):
     """Quantizing per-lane ring write for the int8 KV cache.
 
     The incoming token's K/V rows are int8-quantized per (lane, kv-head)
@@ -367,30 +358,19 @@ def cache_write_batch_q8(cache_k, cache_v, scale_k, scale_v, k_new, v_new,
     this cache exists to avoid.  The 4-byte scale adds ``4/D`` bytes per
     int8 row (~6%% at D=64) against the 2x payload saving.
 
-    ``cache_k``/``cache_v``: int8, (B, KV, S, D) for ``seq_axis=2`` or
-    (B, S, KV, D) for ``seq_axis=1``; ``scale_k``/``scale_v``: fp32,
-    (B, KV, S) / (B, S, KV); ``k_new``/``v_new``: float, (B, KV, 1, D) /
-    (B, 1, KV, D).
+    ``cache_k``/``cache_v``: int8 (B, KV, S, D); ``scale_k``/``scale_v``:
+    fp32 (B, KV, S); ``k_new``/``v_new``: float (B, KV, 1, D).
     """
     from repro.core.quantize import quantize_into
-    s = cache_k.shape[seq_axis]
+    s = cache_k.shape[2]
     idx = jnp.mod(pos, s)
     rows = jnp.arange(cache_k.shape[0])
-    if seq_axis == 2:
-        kq, ks = quantize_into(k_new[:, :, 0], axis=-1)    # (B,KV,D),(B,KV)
-        vq, vs = quantize_into(v_new[:, :, 0], axis=-1)
-        cache_k = cache_k.at[rows, :, idx].set(kq)
-        cache_v = cache_v.at[rows, :, idx].set(vq)
-        scale_k = scale_k.at[rows, :, idx].set(ks)
-        scale_v = scale_v.at[rows, :, idx].set(vs)
-    else:
-        assert seq_axis == 1, seq_axis
-        kq, ks = quantize_into(k_new[:, 0], axis=-1)       # (B,KV,D),(B,KV)
-        vq, vs = quantize_into(v_new[:, 0], axis=-1)
-        cache_k = cache_k.at[rows, idx].set(kq)
-        cache_v = cache_v.at[rows, idx].set(vq)
-        scale_k = scale_k.at[rows, idx].set(ks)
-        scale_v = scale_v.at[rows, idx].set(vs)
+    kq, ks = quantize_into(k_new[:, :, 0], axis=-1)    # (B,KV,D),(B,KV)
+    vq, vs = quantize_into(v_new[:, :, 0], axis=-1)
+    cache_k = cache_k.at[rows, :, idx].set(kq)
+    cache_v = cache_v.at[rows, :, idx].set(vq)
+    scale_k = scale_k.at[rows, :, idx].set(ks)
+    scale_v = scale_v.at[rows, :, idx].set(vs)
     return cache_k, cache_v, scale_k, scale_v
 
 
@@ -412,68 +392,51 @@ def _paged_slot(page_table, pos, page_size):
     return phys, p % page_size
 
 
-def cache_write_batch_paged(pool_k, pool_v, page_table, k_new, v_new, pos,
-                            seq_axis: int = 2):
+def cache_write_batch_paged(pool_k, pool_v, page_table, k_new, v_new, pos):
     """Per-lane one-token write into a paged KV pool.
 
-    ``pool_k``/``pool_v``: (P, KV, ps, D) for ``seq_axis=2`` (the bksd
-    pool) or (P, ps, KV, D) for ``seq_axis=1`` (bskd); ``page_table``:
-    (B, W) int32; ``k_new``/``v_new``: (B, KV, 1, D) / (B, 1, KV, D) as
-    in :func:`cache_write_batch`.  The allocator guarantees every ACTIVE
-    lane's current page is exclusively owned (copy-on-write happens
-    host-side before the step), so the scatter cannot collide; inactive
-    lanes' table rows are all zeros and land in the reserved garbage
-    page 0.
+    ``pool_k``/``pool_v``: (P, KV, ps, D); ``page_table``: (B, W) int32;
+    ``k_new``/``v_new``: (B, KV, 1, D) as in :func:`cache_write_batch`.
+    The allocator guarantees every ACTIVE lane's current page is
+    exclusively owned (copy-on-write happens host-side before the step),
+    so the scatter cannot collide; inactive lanes' table rows are all
+    zeros and land in the reserved garbage page 0.
     """
-    ps = pool_k.shape[seq_axis]
+    ps = pool_k.shape[2]
     phys, off = _paged_slot(page_table, pos, ps)
-    if seq_axis == 2:
-        pool_k = pool_k.at[phys, :, off].set(k_new[:, :, 0].astype(pool_k.dtype))
-        pool_v = pool_v.at[phys, :, off].set(v_new[:, :, 0].astype(pool_v.dtype))
-    else:
-        assert seq_axis == 1, seq_axis
-        pool_k = pool_k.at[phys, off].set(k_new[:, 0].astype(pool_k.dtype))
-        pool_v = pool_v.at[phys, off].set(v_new[:, 0].astype(pool_v.dtype))
+    pool_k = pool_k.at[phys, :, off].set(k_new[:, :, 0].astype(pool_k.dtype))
+    pool_v = pool_v.at[phys, :, off].set(v_new[:, :, 0].astype(pool_v.dtype))
     return pool_k, pool_v
 
 
 def cache_write_batch_paged_q8(pool_k, pool_v, scale_k, scale_v, page_table,
-                               k_new, v_new, pos, seq_axis: int = 2):
-    """Quantizing paged write: int8 payload pools (P, KV, ps, D) /
-    (P, ps, KV, D) plus per-slot fp32 scale pools (P, KV, ps) /
-    (P, ps, KV) — the paged analogue of :func:`cache_write_batch_q8`,
-    same per-(lane, head, slot) scale semantics."""
+                               k_new, v_new, pos):
+    """Quantizing paged write: int8 payload pools (P, KV, ps, D) plus
+    per-slot fp32 scale pools (P, KV, ps) — the paged analogue of
+    :func:`cache_write_batch_q8`, same per-(lane, head, slot) scale
+    semantics."""
     from repro.core.quantize import quantize_into
-    ps = pool_k.shape[seq_axis]
+    ps = pool_k.shape[2]
     phys, off = _paged_slot(page_table, pos, ps)
-    if seq_axis == 2:
-        kq, ks = quantize_into(k_new[:, :, 0], axis=-1)    # (B,KV,D),(B,KV)
-        vq, vs = quantize_into(v_new[:, :, 0], axis=-1)
-        pool_k = pool_k.at[phys, :, off].set(kq)
-        pool_v = pool_v.at[phys, :, off].set(vq)
-        scale_k = scale_k.at[phys, :, off].set(ks)
-        scale_v = scale_v.at[phys, :, off].set(vs)
-    else:
-        assert seq_axis == 1, seq_axis
-        kq, ks = quantize_into(k_new[:, 0], axis=-1)
-        vq, vs = quantize_into(v_new[:, 0], axis=-1)
-        pool_k = pool_k.at[phys, off].set(kq)
-        pool_v = pool_v.at[phys, off].set(vq)
-        scale_k = scale_k.at[phys, off].set(ks)
-        scale_v = scale_v.at[phys, off].set(vs)
+    kq, ks = quantize_into(k_new[:, :, 0], axis=-1)    # (B,KV,D),(B,KV)
+    vq, vs = quantize_into(v_new[:, :, 0], axis=-1)
+    pool_k = pool_k.at[phys, :, off].set(kq)
+    pool_v = pool_v.at[phys, :, off].set(vq)
+    scale_k = scale_k.at[phys, :, off].set(ks)
+    scale_v = scale_v.at[phys, :, off].set(vs)
     return pool_k, pool_v, scale_k, scale_v
 
 
 def decode_attention_named(q, k_cache, v_cache, valid_len, *,
-                           layout: str = "bksd",
                            backend: Optional[str] = None,
                            k_scale=None, v_scale=None, page_table=None):
     """Decode attention through the op-registry named-backend mechanism.
 
+    Caches are (B, KV, S, D) rings or (P, KV, ps, D) page pools.
     ``backend`` is a registry backend name — 'ref' (the jnp
     :func:`attention_decode` oracle), 'pallas' (the ragged flash-decode
     kernel in repro.kernels.decode_attention), or None/'auto' (pallas on
-    TPU, ref elsewhere).  Same resolution path as the graph ops: adding a
+    TPU, ref on the CPU).  Same resolution path as the graph ops: adding a
     new decode implementation is one ``REGISTRY.register_backend`` call.
 
     Passing ``k_scale``/``v_scale`` marks the cache as int8 + per-slot
@@ -493,7 +456,7 @@ def decode_attention_named(q, k_cache, v_cache, valid_len, *,
         kw.update(k_scale=k_scale, v_scale=v_scale)
     if paged:
         kw.update(page_table=page_table)
-    return fn(q, k_cache, v_cache, valid_len, layout=layout, **kw)
+    return fn(q, k_cache, v_cache, valid_len, **kw)
 
 
 # ---------------------------------------------------------------------------

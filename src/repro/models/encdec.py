@@ -159,33 +159,18 @@ def init_cache(cfg: ArchConfig, batch: int, cache_len: int,
                num_pages=None):
     L, kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
     se = cfg.encoder_seq
-    kvd = tfm.kv_cache_dtype(dtype, kv_dtype)
     xd = jnp.bfloat16 if kv_dtype == "bf16" else dtype
     cache = {
         "xk": jnp.zeros((L, batch, se, kv, hd), xd),
         "xv": jnp.zeros((L, batch, se, kv, hd), xd),
     }
-    if page_size is None:
-        cache["k"] = jnp.zeros((L, batch, cache_len, kv, hd), kvd)
-        cache["v"] = jnp.zeros((L, batch, cache_len, kv, hd), kvd)
-        if kv_dtype == "int8":
-            # bskd layout -> per-slot scales indexed (L, B, S, KV)
-            cache["k_scale"] = jnp.zeros((L, batch, cache_len, kv),
-                                         jnp.float32)
-            cache["v_scale"] = jnp.zeros((L, batch, cache_len, kv),
-                                         jnp.float32)
-        return cache
-    # paged decoder self-attention; xk/xv (cross-attention, written once
-    # at admission) stay dense per-lane.  bskd pages: (L, P, ps, KV, D).
-    ps = page_size
-    w = -(-cache_len // ps)
-    p = num_pages if num_pages is not None else 1 + batch * w
-    cache["k_pages"] = jnp.zeros((L, p, ps, kv, hd), kvd)
-    cache["v_pages"] = jnp.zeros((L, p, ps, kv, hd), kvd)
-    cache["page_table"] = jnp.zeros((batch, w), jnp.int32)
-    if kv_dtype == "int8":
-        cache["k_scale_pages"] = jnp.zeros((L, p, ps, kv), jnp.float32)
-        cache["v_scale_pages"] = jnp.zeros((L, p, ps, kv), jnp.float32)
+    # decoder self-attention: the decoder-only layout of
+    # transformer.init_cache ((L, B, KV, S, D) ring or (L, P, KV, ps, D)
+    # pages); xk/xv (cross-attention, written once at admission) stay
+    # dense per-lane (L, B, Se, KV, D)
+    cache.update(tfm.init_cache(cfg, batch, cache_len, dtype,
+                                kv_dtype=kv_dtype, page_size=page_size,
+                                num_pages=num_pages))
     return cache
 
 
@@ -202,31 +187,13 @@ def paged_info(cfg: ArchConfig, cache_len: int, page_size: int):
 def cache_splice_paged(cfg: ArchConfig, cache, row, slot, pages,
                        page_size: int):
     """Splice a prefilled B=1 cache into lane ``slot``: dense xk/xv land
-    in the lane row; the first ``len(pages)`` self-attention KV blocks
-    scatter into the given pages (bskd pages reshape directly — the seq
-    axis already leads)."""
-    n = pages.shape[0]
-    ps = page_size
-    w = cache["page_table"].shape[1]
-    out = dict(cache)
+    in the lane row; the self-attention blocks scatter into the given
+    pages exactly as in :func:`transformer.cache_splice_paged`."""
+    out = tfm.cache_splice_paged(cfg, cache, row, slot, pages, page_size)
     out["xk"] = cache["xk"].at[:, slot].set(
         row["xk"][:, 0].astype(cache["xk"].dtype))
     out["xv"] = cache["xv"].at[:, slot].set(
         row["xv"][:, 0].astype(cache["xv"].dtype))
-    for key in ("k", "v"):
-        src = row[key][:, 0, :n * ps]                  # (L, n*ps, KV, D)
-        L = src.shape[0]
-        x = src.reshape(L, n, ps, *src.shape[2:])
-        pool = cache[key + "_pages"]
-        out[key + "_pages"] = pool.at[:, pages].set(x.astype(pool.dtype))
-        skey = key + "_scale"
-        if skey in row:
-            ssrc = row[skey][:, 0, :n * ps]            # (L, n*ps, KV)
-            sx = ssrc.reshape(L, n, ps, ssrc.shape[2])
-            spool = cache[skey + "_pages"]
-            out[skey + "_pages"] = spool.at[:, pages].set(sx)
-    trow = jnp.zeros((w,), jnp.int32).at[:n].set(pages.astype(jnp.int32))
-    out["page_table"] = cache["page_table"].at[slot].set(trow)
     return out
 
 
@@ -248,10 +215,10 @@ def cache_to_kv_dtype(cfg: ArchConfig, cache, kv_dtype):
 def cache_spec(cfg: ArchConfig, batch: int, cache_len: int, dtype):
     L, kv, hd = cfg.num_layers, cfg.num_kv_heads, cfg.resolved_head_dim
     se = cfg.encoder_seq
-    kvax = (None, "batch", "cache_seq", "tp_kv", None)
+    kvax = (None, "batch", "tp_kv", "cache_seq", None)
     return ({
-        "k": jax.ShapeDtypeStruct((L, batch, cache_len, kv, hd), dtype),
-        "v": jax.ShapeDtypeStruct((L, batch, cache_len, kv, hd), dtype),
+        "k": jax.ShapeDtypeStruct((L, batch, kv, cache_len, hd), dtype),
+        "v": jax.ShapeDtypeStruct((L, batch, kv, cache_len, hd), dtype),
         "xk": jax.ShapeDtypeStruct((L, batch, se, kv, hd), dtype),
         "xv": jax.ShapeDtypeStruct((L, batch, se, kv, hd), dtype),
     }, {"k": kvax, "v": kvax,
@@ -272,9 +239,11 @@ def decode_step(cfg: ArchConfig, params, token, cache, pos, *,
         posv = jnp.full((b, 1), pos, jnp.int32)
         q = cm.apply_rope(q, posv, cfg.rope_theta)
         k = cm.apply_rope(k, posv, cfg.rope_theta)
-        ck, cv = cm.cache_write(ck, cv, k, v, pos)
-        valid = cm.cache_valid_len(pos, ck.shape[1])
-        a = cm.attention_decode(q, ck, cv, valid)
+        # (B, 1, KV, D) -> (B, KV, 1, D) to write along the bksd seq axis
+        ck, cv = cm.cache_write(ck, cv, k.transpose(0, 2, 1, 3),
+                                v.transpose(0, 2, 1, 3), pos)
+        valid = cm.cache_valid_len(pos, ck.shape[2])
+        a = cm.attention_decode(q, ck, cv, valid, layout="bksd")
         x = x + (a.reshape(b, 1, cfg.q_dim) @ lp["wo"] + lp["bo"])
         xn = _ln(x, lp, "x_ln")
         qx = (xn @ lp["x_wq"] + lp["x_bq"]).reshape(b, 1, cfg.num_heads, hd)
@@ -295,7 +264,7 @@ def decode_step_batch(cfg: ArchConfig, params, token, cache, pos, *,
                       window: int = 0, attn_backend=None):
     """Lane-major decode: token (B, 1); pos (B,) per-lane positions.
     Self-attention goes through the ragged named-backend decode path
-    (per-lane RoPE + ring writes, bskd cache layout); cross-attention
+    (per-lane RoPE + ring writes, bksd cache layout); cross-attention
     keys are the full encoder output, identical for every lane.  A paged
     cache (``page_table`` leaf) pages only the self-attention ring —
     xk/xv stay dense per-lane."""
@@ -309,9 +278,9 @@ def decode_step_batch(cfg: ArchConfig, params, token, cache, pos, *,
         else ("k_scale", "v_scale")
     quantized = ksk in cache
     if paged:
-        cap = pt.shape[1] * cache[kk].shape[2]         # W * ps logical
+        cap = pt.shape[1] * cache[kk].shape[3]         # W * ps logical
     else:
-        cap = cache[kk].shape[2]
+        cap = cache[kk].shape[3]
 
     def self_attn(lp, x, ck, cv, cks=None, cvs=None):
         xn = _ln(x, lp, "ln")
@@ -319,24 +288,24 @@ def decode_step_batch(cfg: ArchConfig, params, token, cache, pos, *,
         posv = pos[:, None]
         q = cm.apply_rope(q, posv, cfg.rope_theta)
         k = cm.apply_rope(k, posv, cfg.rope_theta)
+        kT, vT = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         valid = cm.cache_valid_len(pos, cap)
         if cks is None:
             if paged:
-                ck, cv = cm.cache_write_batch_paged(ck, cv, pt, k, v, pos,
-                                                    seq_axis=1)
+                ck, cv = cm.cache_write_batch_paged(ck, cv, pt, kT, vT, pos)
             else:
-                ck, cv = cm.cache_write_batch(ck, cv, k, v, pos, seq_axis=1)
-            a = cm.decode_attention_named(q, ck, cv, valid, layout="bskd",
+                ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos)
+            a = cm.decode_attention_named(q, ck, cv, valid,
                                           backend=attn_backend,
                                           page_table=pt)
         else:
             if paged:
                 ck, cv, cks, cvs = cm.cache_write_batch_paged_q8(
-                    ck, cv, cks, cvs, pt, k, v, pos, seq_axis=1)
+                    ck, cv, cks, cvs, pt, kT, vT, pos)
             else:
                 ck, cv, cks, cvs = cm.cache_write_batch_q8(
-                    ck, cv, cks, cvs, k, v, pos, seq_axis=1)
-            a = cm.decode_attention_named(q, ck, cv, valid, layout="bskd",
+                    ck, cv, cks, cvs, kT, vT, pos)
+            a = cm.decode_attention_named(q, ck, cv, valid,
                                           backend=attn_backend,
                                           k_scale=cks, v_scale=cvs,
                                           page_table=pt)
@@ -399,11 +368,14 @@ def prefill(cfg: ArchConfig, params, tokens, cache_len: int, frames=None, *,
     logits = x @ params["embed"].T.astype(x.dtype)
     cache = init_cache(cfg, b, cache_len, cache_dtype)
     keep = min(s, cache_len)
-    ck = lax.dynamic_update_slice_in_dim(cache["k"], ks[:, :, s - keep:],
-                                         0, axis=2)
-    cv = lax.dynamic_update_slice_in_dim(cache["v"], vs[:, :, s - keep:],
-                                         0, axis=2)
+    # (L, B, S, KV, D) stacked attn outputs -> bksd (L, B, KV, S, D)
+    ks = ks.transpose(0, 1, 3, 2, 4)
+    vs = vs.transpose(0, 1, 3, 2, 4)
+    ck = lax.dynamic_update_slice_in_dim(cache["k"], ks[:, :, :, s - keep:],
+                                         0, axis=3)
+    cv = lax.dynamic_update_slice_in_dim(cache["v"], vs[:, :, :, s - keep:],
+                                         0, axis=3)
     if s > cache_len:
-        ck = jnp.roll(ck, s % cache_len, axis=2)
-        cv = jnp.roll(cv, s % cache_len, axis=2)
+        ck = jnp.roll(ck, s % cache_len, axis=3)
+        cv = jnp.roll(cv, s % cache_len, axis=3)
     return logits, {"k": ck, "v": cv, "xk": kxs, "xv": vxs}

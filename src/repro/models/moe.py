@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ArchConfig
-from repro.launch.compat import shard_map
 from repro.models import common as cm
 from repro.models import transformer as tfm
 from repro.models.common import P
@@ -207,7 +206,7 @@ def moe_ffn_a2a(cfg: ArchConfig, lp, x) -> Tuple[jax.Array, jax.Array]:
         aux = lax.pmean(aux, maxis) if seq_axis is None else aux
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, rspec, wspec, wspec,
                   P(maxis, None, "data" if "data" in names else None)),
@@ -255,7 +254,7 @@ def moe_ffn_local(cfg: ArchConfig, lp, x) -> Tuple[jax.Array, jax.Array]:
         aux = lax.pmean(aux, maxis) if seq_axis is None and maxis else aux
         return out.reshape(bl, sl, d), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(xspec, P(dshard, None), P(None, dshard, None),
                   P(None, dshard, None), P(None, None, dshard)),

@@ -115,7 +115,7 @@ def attn_decode(cfg: ArchConfig, lp, x, ck, cv, pos, *, window: int = 0):
     k = cm.apply_rope(k, posv, cfg.rope_theta)
     # (B, 1, KV, D) -> (B, KV, 1, D) to write along the bksd seq axis
     ck, cv = cm.cache_write(ck, cv, k.transpose(0, 2, 1, 3),
-                            v.transpose(0, 2, 1, 3), pos, seq_axis=2)
+                            v.transpose(0, 2, 1, 3), pos)
     valid = cm.cache_valid_len(pos, cache_size)
     out = cm.attention_decode(q, ck, cv, valid, layout="bksd")
     out = out.reshape(b, 1, cfg.q_dim)
@@ -165,23 +165,22 @@ def attn_decode_batch(cfg: ArchConfig, lp, x, ck, cv, pos, *,
     if cks is None:
         if paged:
             ck, cv = cm.cache_write_batch_paged(ck, cv, page_table, kT, vT,
-                                                pos, seq_axis=2)
+                                                pos)
         else:
-            ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos, seq_axis=2)
-        out = cm.decode_attention_named(q, ck, cv, valid, layout="bksd",
-                                        backend=backend,
+            ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos)
+        out = cm.decode_attention_named(q, ck, cv, valid, backend=backend,
                                         page_table=page_table)
         out = out.reshape(b, 1, cfg.q_dim)
         return out @ lp["wo"], ck, cv
     if paged:
         ck, cv, cks, cvs = cm.cache_write_batch_paged_q8(
-            ck, cv, cks, cvs, page_table, kT, vT, pos, seq_axis=2)
+            ck, cv, cks, cvs, page_table, kT, vT, pos)
     else:
         ck, cv, cks, cvs = cm.cache_write_batch_q8(ck, cv, cks, cvs, kT, vT,
-                                                   pos, seq_axis=2)
-    out = cm.decode_attention_named(q, ck, cv, valid, layout="bksd",
-                                    backend=backend, k_scale=cks,
-                                    v_scale=cvs, page_table=page_table)
+                                                   pos)
+    out = cm.decode_attention_named(q, ck, cv, valid, backend=backend,
+                                    k_scale=cks, v_scale=cvs,
+                                    page_table=page_table)
     out = out.reshape(b, 1, cfg.q_dim)
     return out @ lp["wo"], ck, cv, cks, cvs
 

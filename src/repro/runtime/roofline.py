@@ -37,7 +37,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 
 from repro.core.ops import REGISTRY
-from repro.launch.hlo_costs import HW_PEAKS, roofline_terms
+from repro.launch.hlo_costs import hw_peaks, roofline_terms
 
 __all__ = ["HWSpec", "RooflineAccountant"]
 
@@ -46,9 +46,10 @@ __all__ = ["HWSpec", "RooflineAccountant"]
 class HWSpec:
     """Peak rates the achieved numbers are divided by.  ``detect()``
     picks the row of :data:`repro.launch.hlo_costs.HW_PEAKS` matching
-    the JAX backend; on CPU the peaks are an indicative dev-box figure
-    (MBU there shows *shape*, not cross-machine-comparable magnitude).
-    Override with ``REPRO_HW_PEAK_FLOPS`` / ``REPRO_HW_HBM_BW``."""
+    the first device's ``device_kind`` and raises for a device that is
+    not in the table; on CPU the peaks are a test figure (MBU there shows
+    *shape*, not magnitude).  ``REPRO_HW_PEAK_FLOPS`` /
+    ``REPRO_HW_HBM_BW`` override the peaks of a KNOWN device only."""
 
     name: str
     peak_flops: float
@@ -56,7 +57,7 @@ class HWSpec:
 
     @classmethod
     def detect(cls) -> "HWSpec":
-        row = HW_PEAKS.get(jax.default_backend(), HW_PEAKS["cpu"])
+        row = hw_peaks(jax.devices()[0].device_kind)
         name = str(row["name"])
         env_f = os.environ.get("REPRO_HW_PEAK_FLOPS")
         env_b = os.environ.get("REPRO_HW_HBM_BW")

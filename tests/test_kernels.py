@@ -216,8 +216,8 @@ def test_flash_attention_causality():
 def test_decode_attention(s, h, kv, d):
     ks = jax.random.split(KEY, 3)
     q = rand((2, h, d), key=ks[0])
-    k = rand((2, s, kv, d), key=ks[1])
-    v = rand((2, s, kv, d), key=ks[2])
+    k = rand((2, kv, s, d), key=ks[1])
+    v = rand((2, kv, s, d), key=ks[2])
     for valid in (1, s // 3, s):
         assert_close(ops.decode_attention(q, k, v, jnp.int32(valid)),
                      ref.decode_attention_ref(q, k, v, valid),
@@ -228,33 +228,40 @@ def test_decode_attention_masks_invalid_slots():
     """Garbage beyond valid_len must not affect the output."""
     ks = jax.random.split(KEY, 3)
     q = rand((1, 4, 32), key=ks[0])
-    k = rand((1, 128, 4, 32), key=ks[1])
-    v = rand((1, 128, 4, 32), key=ks[2])
+    k = rand((1, 4, 128, 32), key=ks[1])
+    v = rand((1, 4, 128, 32), key=ks[2])
     out1 = np.asarray(ops.decode_attention(q, k, v, jnp.int32(64)))
-    k2 = k.at[:, 64:].set(99.0)
-    v2 = v.at[:, 64:].set(-99.0)
+    k2 = k.at[:, :, 64:].set(99.0)
+    v2 = v.at[:, :, 64:].set(-99.0)
     out2 = np.asarray(ops.decode_attention(q, k2, v2, jnp.int32(64)))
     np.testing.assert_allclose(out1, out2, rtol=1e-5)
 
 
-@pytest.mark.parametrize("layout", ["bskd", "bksd"])
+def _valid(kind, s):
+    """Per-lane (B=4,) valid lengths — the continuous-batching shape —
+    or one scalar length broadcast to every lane."""
+    if kind == "ragged":
+        return jnp.array([1, s // 3, s // 2 + 1, s], jnp.int32)
+    return jnp.int32(s // 2 + 1)
+
+
+@pytest.mark.parametrize("valid_kind", ["scalar", "ragged"])
 @pytest.mark.parametrize("s,h,kv,d,block", [(256, 8, 4, 64, 64),
                                             (128, 4, 1, 32, 128),
                                             (192, 16, 16, 32, 64)])
-def test_decode_attention_ragged(layout, s, h, kv, d, block):
-    """Per-lane (B,) valid_len vector — the continuous-batching shape —
-    across GQA group counts, both cache layouts, and block counts that
-    force the @pl.when early-exit path (valid not a block multiple)."""
+def test_decode_attention_ragged(valid_kind, s, h, kv, d, block):
+    """Per-lane (B,) or scalar valid_len across GQA group counts and
+    block counts that force the @pl.when early-exit path (valid not a
+    block multiple)."""
     b = 4
     ks = jax.random.split(KEY, 3)
     q = rand((b, h, d), key=ks[0])
-    shape = (b, s, kv, d) if layout == "bskd" else (b, kv, s, d)
-    k = rand(shape, key=ks[1])
-    v = rand(shape, key=ks[2])
-    valid = jnp.array([1, s // 3, s // 2 + 1, s], jnp.int32)
+    k = rand((b, kv, s, d), key=ks[1])
+    v = rand((b, kv, s, d), key=ks[2])
+    valid = _valid(valid_kind, s)
     assert_close(
-        ops.decode_attention(q, k, v, valid, layout=layout, block_s=block),
-        ref.decode_attention_ref(q, k, v, valid, layout=layout),
+        ops.decode_attention(q, k, v, valid, block_s=block),
+        ref.decode_attention_ref(q, k, v, valid),
         rtol=2e-3, atol=2e-3)
 
 
@@ -267,12 +274,11 @@ def test_decode_attention_ragged_matches_per_lane_scalar():
     k = rand((b, kv, s, d), key=ks[1])
     v = rand((b, kv, s, d), key=ks[2])
     valid = jnp.array([17, 64, 128], jnp.int32)
-    ragged = np.asarray(ops.decode_attention(q, k, v, valid, layout="bksd",
-                                             block_s=32))
+    ragged = np.asarray(ops.decode_attention(q, k, v, valid, block_s=32))
     for i in range(b):
         solo = np.asarray(ops.decode_attention(
             q[i:i + 1], k[i:i + 1], v[i:i + 1], jnp.int32(int(valid[i])),
-            layout="bksd", block_s=32))
+            block_s=32))
         np.testing.assert_allclose(ragged[i:i + 1], solo, rtol=1e-5,
                                    atol=1e-5)
 
@@ -286,12 +292,10 @@ def test_decode_attention_ragged_masks_per_lane():
     k = rand((b, kv, s, d), key=ks[1])
     v = rand((b, kv, s, d), key=ks[2])
     valid = jnp.array([32, 64, 128], jnp.int32)
-    out1 = np.asarray(ops.decode_attention(q, k, v, valid, layout="bksd",
-                                           block_s=32))
+    out1 = np.asarray(ops.decode_attention(q, k, v, valid, block_s=32))
     k2 = k.at[0, :, 32:].set(99.0).at[1, :, 64:].set(-99.0)
     v2 = v.at[0, :, 32:].set(-99.0).at[1, :, 64:].set(99.0)
-    out2 = np.asarray(ops.decode_attention(q, k2, v2, valid, layout="bksd",
-                                           block_s=32))
+    out2 = np.asarray(ops.decode_attention(q, k2, v2, valid, block_s=32))
     np.testing.assert_allclose(out1, out2, rtol=1e-5)
 
 
@@ -300,19 +304,18 @@ def test_decode_attention_ragged_masks_per_lane():
 # ---------------------------------------------------------------------------
 
 
-def _quantize_cache(k, v, layout):
+def _quantize_cache(k, v):
     from repro.core.quantize import quantize_into
-    del layout  # per-slot scales come from axis=-1 in either layout
     kq, ks = quantize_into(k, axis=-1)
     vq, vs = quantize_into(v, axis=-1)
     return kq, vq, ks, vs
 
 
-@pytest.mark.parametrize("layout", ["bskd", "bksd"])
+@pytest.mark.parametrize("valid_kind", ["scalar", "ragged"])
 @pytest.mark.parametrize("s,h,kv,d,block", [(256, 8, 4, 64, 64),
                                             (128, 4, 1, 32, 128),
                                             (192, 16, 16, 32, 64)])
-def test_decode_attention_q8_matches_oracle(layout, s, h, kv, d, block):
+def test_decode_attention_q8_matches_oracle(valid_kind, s, h, kv, d, block):
     """The pallas_q8 kernel must match the ragged q8 jnp oracle exactly
     (same int8 payloads, same scales, fp32 math in both) — including
     ragged valid lengths that force the block-skip early exit to compose
@@ -320,15 +323,13 @@ def test_decode_attention_q8_matches_oracle(layout, s, h, kv, d, block):
     b = 4
     ks = jax.random.split(KEY, 3)
     q = rand((b, h, d), key=ks[0])
-    shape = (b, s, kv, d) if layout == "bskd" else (b, kv, s, d)
-    k = rand(shape, key=ks[1])
-    v = rand(shape, key=ks[2])
-    kq, vq, kscale, vscale = _quantize_cache(k, v, layout)
-    valid = jnp.array([1, s // 3, s // 2 + 1, s], jnp.int32)
+    k = rand((b, kv, s, d), key=ks[1])
+    v = rand((b, kv, s, d), key=ks[2])
+    kq, vq, kscale, vscale = _quantize_cache(k, v)
+    valid = _valid(valid_kind, s)
     got = ops.decode_attention_q8(q, kq, vq, kscale, vscale, valid,
-                                  layout=layout, block_s=block)
-    want = ref.decode_attention_q8_ref(q, kq, vq, kscale, vscale, valid,
-                                       layout=layout)
+                                  block_s=block)
+    want = ref.decode_attention_q8_ref(q, kq, vq, kscale, vscale, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-6)
 
@@ -341,12 +342,11 @@ def test_decode_attention_q8_close_to_fp():
     q = rand((b, h, d), key=ks[0])
     k = rand((b, kv, s, d), key=ks[1])
     v = rand((b, kv, s, d), key=ks[2])
-    kq, vq, kscale, vscale = _quantize_cache(k, v, "bksd")
+    kq, vq, kscale, vscale = _quantize_cache(k, v)
     valid = jnp.array([64, 128], jnp.int32)
     got = np.asarray(ops.decode_attention_q8(q, kq, vq, kscale, vscale,
-                                             valid, layout="bksd"))
-    want = np.asarray(ref.decode_attention_ref(q, k, v, valid,
-                                               layout="bksd"))
+                                             valid))
+    want = np.asarray(ref.decode_attention_ref(q, k, v, valid))
     assert np.abs(got - want).max() < 0.05
 
 
@@ -358,18 +358,16 @@ def test_decode_attention_q8_masks_per_lane():
     q = rand((b, h, d), key=ks[0])
     k = rand((b, kv, s, d), key=ks[1])
     v = rand((b, kv, s, d), key=ks[2])
-    kq, vq, kscale, vscale = _quantize_cache(k, v, "bksd")
+    kq, vq, kscale, vscale = _quantize_cache(k, v)
     valid = jnp.array([32, 64, 128], jnp.int32)
     out1 = np.asarray(ops.decode_attention_q8(q, kq, vq, kscale, vscale,
-                                              valid, layout="bksd",
-                                              block_s=32))
+                                              valid, block_s=32))
     kq2 = kq.at[0, :, 32:].set(127).at[1, :, 64:].set(-127)
     vq2 = vq.at[0, :, 32:].set(-127).at[1, :, 64:].set(127)
     ks2 = kscale.at[0, :, 32:].set(99.0).at[1, :, 64:].set(99.0)
     vs2 = vscale.at[0, :, 32:].set(99.0).at[1, :, 64:].set(99.0)
     out2 = np.asarray(ops.decode_attention_q8(q, kq2, vq2, ks2, vs2,
-                                              valid, layout="bksd",
-                                              block_s=32))
+                                              valid, block_s=32))
     np.testing.assert_allclose(out1, out2, rtol=1e-5)
 
 

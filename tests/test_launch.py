@@ -26,9 +26,8 @@ def test_logical_to_spec_basic():
 def test_logical_to_spec_divisibility_guard():
     """A mapping that does not divide the dim is dropped, not an error —
     e.g. granite's 40-expert bank on a 16-way model axis."""
-    from repro.launch.compat import abstract_mesh
     from repro.sharding_hints import axis_rules
-    mesh = abstract_mesh((16,), ("model",))
+    mesh = jax.sharding.AbstractMesh((16,), ("model",))
     rules = {"experts": "model"}
     with axis_rules(rules, mesh):
         spec = logical_to_spec(("experts", None), rules, (40, 64))
@@ -201,7 +200,7 @@ def test_sharded_train_step_compiles_on_host_mesh():
 
         bstruct = {"tokens": jax.ShapeDtypeStruct((4, 16), jnp.int32),
                    "labels": jax.ShapeDtypeStruct((4, 16), jnp.int32)}
-        with mesh:
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=(pshard, None)).lower(
                 pstruct, bstruct)
             compiled = lowered.compile()
@@ -212,3 +211,44 @@ def test_mesh_requires_enough_devices():
     from repro.launch.mesh import make_production_mesh
     with pytest.raises(RuntimeError):
         make_production_mesh()         # 1 CPU device < 256
+
+
+_CACHE_PROBE = """
+import json, sys
+import jax, jax.numpy as jnp
+from repro.launch import compile_cache
+d = compile_cache.enable_compile_cache()
+if sys.argv[1:] == ["compile"]:
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: jnp.sin(x) * 3 + 1)(jnp.arange(8.0)).block_until_ready()
+print(json.dumps({"dir": d, "config": jax.config.jax_compilation_cache_dir,
+                  "default": str(compile_cache.DEFAULT_DIR)}))
+"""
+
+
+@pytest.mark.parametrize("placed", [True, False], ids=["env", "default"])
+def test_compile_cache_dir_is_placed_from_outside(tmp_path, placed):
+    """With JAX_COMPILATION_CACHE_DIR set the entries land there and the
+    code sets nothing; unset, the cache is <checkout>/.jax_cache (checked
+    without compiling, so the checkout stays clean)."""
+    import json
+    import os
+    import pathlib
+    import subprocess
+    import sys
+    root = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(root / "src"))
+    if placed:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    out = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE] + (["compile"] if placed else []),
+        env=env, capture_output=True, text=True, timeout=120,
+        check=True).stdout.splitlines()[-1]
+    got = json.loads(out)
+    assert got["default"] == str(root / ".jax_cache")
+    want = str(tmp_path) if placed else got["default"]
+    assert got["dir"] == got["config"] == want
+    if placed:
+        assert any(tmp_path.iterdir()), "no cache entry written"

@@ -29,8 +29,8 @@ CODE = textwrap.dedent("""
     key = jax.random.PRNGKey(0)
     params = models.init_params(cfg, key)
     tokens = jax.random.randint(key, (4, 16), 0, cfg.vocab_size)
-    from repro.launch.compat import make_mesh
-    mesh = make_mesh((2, 4), ("data", "model"), auto_axis_types=True)
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 4), ("data", "model"))
     outs = {}
     for impl, extra in [("dense", {}), ("a2a", {"tp_ff": None}),
                         ("local", {"experts": None, "tp_ff": None})]:
@@ -39,7 +39,7 @@ CODE = textwrap.dedent("""
             pshard = shd.param_shardings(models.param_template(cfg),
                                          rules, mesh)
             pp = jax.device_put(params, pshard)
-            with mesh:
+            with jax.set_mesh(mesh):
                 logits, aux = jax.jit(
                     lambda p, t: mod.forward(cfg, p, t))(pp, tokens)
         outs[impl] = np.asarray(logits, np.float32)

@@ -328,3 +328,49 @@ def test_flush_on_interrupt_writes_loadable_trace(tmp_path):
     finally:
         uninstall()
     assert signal.getsignal(signal.SIGINT) is prev
+
+
+class _FakeDevice:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_hwspec_keys_peaks_by_device_kind(monkeypatch):
+    """The v5e row carries its published peaks; the CPU row is the
+    labelled test figure."""
+    from repro.runtime.roofline import HWSpec
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice("TPU v5 lite")])
+    hw = HWSpec.detect()
+    assert (hw.name, hw.peak_flops, hw.hbm_bw) == ("tpu-v5e", 197e12, 819e9)
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice("cpu")])
+    assert "test figure" in HWSpec.detect().name
+
+
+@pytest.mark.parametrize("env", [{}, {"REPRO_HW_PEAK_FLOPS": "1e12",
+                                      "REPRO_HW_HBM_BW": "1e11"}])
+def test_hwspec_unknown_device_raises(monkeypatch, env):
+    """An accelerator outside the table never inherits another's peaks,
+    and the overrides cannot stand in for a missing row."""
+    from repro.runtime.roofline import HWSpec
+    for k, v in env.items():
+        monkeypatch.setenv(k, v)
+    monkeypatch.setattr(jax, "devices", lambda: [_FakeDevice("TPU v4")])
+    with pytest.raises(KeyError, match="TPU v4"):
+        HWSpec.detect()
+
+
+def test_no_interpret_mode_or_ref_fallback_on_tpu(monkeypatch):
+    """On a TPU the kernels compile (interpret=True is refused) and auto
+    decode resolves to pallas; an unknown backend is an error."""
+    from repro.core.ops import resolve_decode_backend
+    from repro.kernels import ops as kops
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert kops._interpret(None) is False
+    with pytest.raises(ValueError, match="interpret"):
+        kops._interpret(True)
+    assert resolve_decode_backend(None, quantized=True) == "pallas_q8"
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError):
+        kops._interpret(None)
+    with pytest.raises(RuntimeError):
+        resolve_decode_backend("auto")

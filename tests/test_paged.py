@@ -60,37 +60,38 @@ def _run(cfg, params, prompts, *, max_new=8, **kw):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("layout", ["bskd", "bksd"])
+@pytest.mark.parametrize("valid_kind", ["scalar", "ragged"])
 @pytest.mark.parametrize("quantized", [False, True])
-def test_paged_kernel_fragmented_out_of_order_pages(layout, quantized):
+def test_paged_kernel_fragmented_out_of_order_pages(valid_kind, quantized):
     """The paged flash-decode kernel must match the gather-based oracle
     when lanes' pages are shuffled arbitrarily across the pool — the
-    whole point of the block-table indirection."""
+    whole point of the block-table indirection — with per-lane or one
+    shared valid length."""
     rng = np.random.default_rng(0)
     b, h, kvh, d, ps, w = 3, 8, 2, 32, 16, 4
     p = 1 + b * w + 3
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    shape = (p, ps, kvh, d) if layout == "bskd" else (p, kvh, ps, d)
-    sshape = (p, ps, kvh) if layout == "bskd" else (p, kvh, ps)
+    shape = (p, kvh, ps, d)
+    sshape = (p, kvh, ps)
     # non-contiguous, interleaved, reverse-ordered physical pages
     perm = rng.permutation(np.arange(1, p))[:b * w].reshape(b, w)
     pt = jnp.asarray(perm, jnp.int32)
     valid = jnp.asarray(rng.integers(1, w * ps + 1, size=(b,)), jnp.int32)
+    if valid_kind == "scalar":
+        valid = valid[0]
     if quantized:
         k = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
         v = jnp.asarray(rng.integers(-127, 128, shape), jnp.int8)
         ks = jnp.asarray(rng.uniform(0.01, 0.05, sshape), jnp.float32)
         vs = jnp.asarray(rng.uniform(0.01, 0.05, sshape), jnp.float32)
-        got = kops.decode_attention_paged_q8(q, k, v, ks, vs, pt, valid,
-                                             layout=layout)
+        got = kops.decode_attention_paged_q8(q, k, v, ks, vs, pt, valid)
         want = kref.decode_attention_paged_q8_ref(q, k, v, ks, vs, pt,
-                                                  valid, layout=layout)
+                                                  valid)
     else:
         k = jnp.asarray(rng.standard_normal(shape), jnp.float32)
         v = jnp.asarray(rng.standard_normal(shape), jnp.float32)
-        got = kops.decode_attention_paged(q, k, v, pt, valid, layout=layout)
-        want = kref.decode_attention_paged_ref(q, k, v, pt, valid,
-                                               layout=layout)
+        got = kops.decode_attention_paged(q, k, v, pt, valid)
+        want = kref.decode_attention_paged_ref(q, k, v, pt, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
 
@@ -107,12 +108,10 @@ def test_paged_gather_matches_ring_oracle_exactly():
     pt = jnp.asarray(rng.permutation(np.arange(1, p)).reshape(b, w),
                      jnp.int32)
     valid = jnp.asarray([5, w * ps], jnp.int32)
-    ring_k = kref.paged_gather(pool_k, pt, layout="bksd")
-    ring_v = kref.paged_gather(pool_v, pt, layout="bksd")
-    want = kref.decode_attention_ref(q, ring_k, ring_v, valid,
-                                     layout="bksd")
-    got = kref.decode_attention_paged_ref(q, pool_k, pool_v, pt, valid,
-                                          layout="bksd")
+    ring_k = kref.paged_gather(pool_k, pt)
+    ring_v = kref.paged_gather(pool_v, pt)
+    want = kref.decode_attention_ref(q, ring_k, ring_v, valid)
+    got = kref.decode_attention_paged_ref(q, pool_k, pool_v, pt, valid)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 

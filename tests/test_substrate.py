@@ -156,13 +156,13 @@ def test_attention_decode_equals_last_row_of_full():
 
 
 def test_cache_write_ring_semantics():
-    ck = jnp.zeros((1, 4, 1, 2))
-    cv = jnp.zeros((1, 4, 1, 2))
+    ck = jnp.zeros((1, 1, 4, 2))                   # (B, KV, S, D)
+    cv = jnp.zeros((1, 1, 4, 2))
     for pos in range(6):
         k_new = jnp.full((1, 1, 1, 2), pos + 1.0)
         ck, cv = cm.cache_write(ck, cv, k_new, k_new, jnp.int32(pos))
     # slots hold tokens [5, 6, 3, 4] (pos 4->slot 0, 5->slot 1)
-    got = np.asarray(ck[0, :, 0, 0])
+    got = np.asarray(ck[0, 0, :, 0])
     np.testing.assert_array_equal(got, [5.0, 6.0, 3.0, 4.0])
 
 

@@ -1,0 +1,6 @@
+"""Share of the traced slice in which no operation ran on the device."""
+
+
+def read(ctx):
+    w = ctx.trace["window_s"]
+    return 100.0 * (1.0 - ctx.trace["busy_s"] / w) if w > 0 else None

@@ -150,39 +150,45 @@ def attn_decode_batch(cfg: ArchConfig, lp, x, ck, cv, pos, *,
         cache_size = page_table.shape[1] * ck.shape[2]  # W * ps logical
     else:
         cache_size = ck.shape[2]
-    xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
-    q = (xn @ lp["wq"]).reshape(b, 1, cfg.num_heads, hd)
-    k = (xn @ lp["wk"]).reshape(b, 1, cfg.num_kv_heads, hd)
-    v = (xn @ lp["wv"]).reshape(b, 1, cfg.num_kv_heads, hd)
-    if cfg.qk_norm:
-        q = cm.rms_norm(q, lp["q_norm"], cfg.norm_eps)
-        k = cm.rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    posv = pos[:, None]                                # (B, 1) per-lane
-    q = cm.apply_rope(q, posv, cfg.rope_theta)
-    k = cm.apply_rope(k, posv, cfg.rope_theta)
-    kT, vT = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    with jax.named_scope("qkv"):
+        xn = cm.rms_norm(x, lp["ln1"], cfg.norm_eps)
+        q = (xn @ lp["wq"]).reshape(b, 1, cfg.num_heads, hd)
+        k = (xn @ lp["wk"]).reshape(b, 1, cfg.num_kv_heads, hd)
+        v = (xn @ lp["wv"]).reshape(b, 1, cfg.num_kv_heads, hd)
+        if cfg.qk_norm:
+            q = cm.rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = cm.rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        posv = pos[:, None]                            # (B, 1) per-lane
+        q = cm.apply_rope(q, posv, cfg.rope_theta)
+        k = cm.apply_rope(k, posv, cfg.rope_theta)
+        kT, vT = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     valid = cm.cache_valid_len(pos, cache_size)        # (B,) ragged
     if cks is None:
+        with jax.named_scope("kv_write"):
+            if paged:
+                ck, cv = cm.cache_write_batch_paged(ck, cv, page_table, kT,
+                                                    vT, pos)
+            else:
+                ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos)
+        with jax.named_scope("attention"):
+            out = cm.decode_attention_named(q, ck, cv, valid,
+                                            backend=backend,
+                                            page_table=page_table)
+            out = out.reshape(b, 1, cfg.q_dim) @ lp["wo"]
+        return out, ck, cv
+    with jax.named_scope("kv_write"):
         if paged:
-            ck, cv = cm.cache_write_batch_paged(ck, cv, page_table, kT, vT,
-                                                pos)
+            ck, cv, cks, cvs = cm.cache_write_batch_paged_q8(
+                ck, cv, cks, cvs, page_table, kT, vT, pos)
         else:
-            ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos)
+            ck, cv, cks, cvs = cm.cache_write_batch_q8(ck, cv, cks, cvs, kT,
+                                                       vT, pos)
+    with jax.named_scope("attention"):
         out = cm.decode_attention_named(q, ck, cv, valid, backend=backend,
+                                        k_scale=cks, v_scale=cvs,
                                         page_table=page_table)
-        out = out.reshape(b, 1, cfg.q_dim)
-        return out @ lp["wo"], ck, cv
-    if paged:
-        ck, cv, cks, cvs = cm.cache_write_batch_paged_q8(
-            ck, cv, cks, cvs, page_table, kT, vT, pos)
-    else:
-        ck, cv, cks, cvs = cm.cache_write_batch_q8(ck, cv, cks, cvs, kT, vT,
-                                                   pos)
-    out = cm.decode_attention_named(q, ck, cv, valid, backend=backend,
-                                    k_scale=cks, v_scale=cvs,
-                                    page_table=page_table)
-    out = out.reshape(b, 1, cfg.q_dim)
-    return out @ lp["wo"], ck, cv, cks, cvs
+        out = out.reshape(b, 1, cfg.q_dim) @ lp["wo"]
+    return out, ck, cv, cks, cvs
 
 
 def mlp(cfg: ArchConfig, lp, x):
@@ -392,7 +398,8 @@ def decode_step_batch(cfg: ArchConfig, params, tokens, cache, pos, *,
     POOLS through the scan instead of per-lane rings; the page table is
     layer-invariant, so it rides as a closure constant and comes back
     unchanged."""
-    x = _embed(cfg, params, tokens)
+    with jax.named_scope("embed"):
+        x = _embed(cfg, params, tokens)
     if "page_table" in cache:
         return _decode_step_batch_paged(cfg, params, x, cache, pos,
                                         window=window,
@@ -443,14 +450,17 @@ def _decode_step_batch_paged(cfg: ArchConfig, params, x, cache, pos, *,
                 cfg, lp, x, ck, cv, pos, window=window,
                 backend=attn_backend, cks=cks, cvs=cvs, page_table=pt)
             x = x + a
-            x = x + mlp(cfg, lp, x)
+            with jax.named_scope("mlp"):
+                x = x + mlp(cfg, lp, x)
             return x, (ck, cv, cks, cvs)
 
         x, (ck, cv, cks, cvs) = lax.scan(
             layer, x, (params["layers"], cache["k_pages"],
                        cache["v_pages"], cache["k_scale_pages"],
                        cache["v_scale_pages"]))
-        return _logits(cfg, params, x), {
+        with jax.named_scope("head"):
+            logits = _logits(cfg, params, x)
+        return logits, {
             "k_pages": ck, "v_pages": cv, "k_scale_pages": cks,
             "v_scale_pages": cvs, "page_table": pt}
 
@@ -460,13 +470,15 @@ def _decode_step_batch_paged(cfg: ArchConfig, params, x, cache, pos, *,
                                       window=window, backend=attn_backend,
                                       page_table=pt)
         x = x + a
-        x = x + mlp(cfg, lp, x)
+        with jax.named_scope("mlp"):
+            x = x + mlp(cfg, lp, x)
         return x, (ck, cv)
 
     x, (ck, cv) = lax.scan(layer, x, (params["layers"], cache["k_pages"],
                                       cache["v_pages"]))
-    return _logits(cfg, params, x), {"k_pages": ck, "v_pages": cv,
-                                     "page_table": pt}
+    with jax.named_scope("head"):
+        logits = _logits(cfg, params, x)
+    return logits, {"k_pages": ck, "v_pages": cv, "page_table": pt}
 
 
 def prefill(cfg: ArchConfig, params, tokens, cache_len: int,

@@ -70,9 +70,9 @@ class PagePool:
 
     # -- allocation -------------------------------------------------------
 
-    def _count(self, name: str) -> None:
+    def _count(self, name: str, n: int = 1) -> None:
         if self.metrics is not None:
-            self.metrics.counter(name).inc()
+            self.metrics.counter(name).inc(n)
 
     def available(self) -> int:
         return len(self._free)
@@ -112,16 +112,21 @@ class PagePool:
     def prefix_lookup(self, tokens: Sequence[int]) -> Optional[PrefixEntry]:
         """Longest cached prefix of ``tokens``: the full length first,
         then page-aligned cuts descending.  A hit is moved to the LRU
-        tail (most recent)."""
+        tail (most recent).  Every cut builds its own key tuple:
+        ``pool.prefix_key_tokens`` counts the tokens keyed."""
         ps = self.page_size
         n = len(tokens)
         cuts = [n] + [c for c in range((n // ps) * ps, 0, -ps) if c < n]
+        keyed, entry = 0, None
         for cut in cuts:
-            entry = self._prefixes.get(self._key(tokens, cut))
+            key = self._key(tokens, cut)
+            keyed += cut
+            entry = self._prefixes.get(key)
             if entry is not None:
-                self._prefixes.move_to_end(self._key(tokens, cut))
-                return entry
-        return None
+                self._prefixes.move_to_end(key)
+                break
+        self._count("pool.prefix_key_tokens", keyed)
+        return entry
 
     def prefix_register(self, tokens: Sequence[int],
                         pages: Sequence[int]) -> None:
@@ -132,6 +137,7 @@ class PagePool:
         ps = self.page_size
         n = len(tokens)
         cuts = list(range(ps, n, ps)) + [n]
+        self._count("pool.prefix_key_tokens", sum(cuts))
         for cut in cuts:
             key = self._key(tokens, cut)
             if key in self._prefixes:

@@ -70,19 +70,22 @@ queue-time / end-to-end latency histograms recorded at each request's
 lifecycle transitions.  Passing ``telemetry=Telemetry(...)`` also turns
 on the Chrome-trace recorder: per-request lifecycle rows (submit →
 admit → prefix hit/miss → first token → per-tick progress →
-preempt/requeue → finish) and scheduler tick spans (admission,
-prepare_writes, step dispatch, retirement fetch), exported with
-``telemetry.export_chrome_trace(path)`` and viewable in Perfetto.  All
-instrumentation is host-clock only and measures *dispatch*, not device
-completion (the zero-host-syncs-per-token invariant survives tracing);
-see ``runtime/telemetry.py`` for the exact timestamp semantics.
+preempt/requeue → finish), exported with
+``telemetry.export_chrome_trace(path)`` and viewable in Perfetto.  The
+scheduler's phases (``telemetry.SPANS``: tick, admission and its parts,
+page-table updates, step dispatch, bookkeeping, retirement) are always
+marked as ``sched.*`` profiler annotations, so a ``jax.profiler`` trace
+puts them beside the device's operations; with ``telemetry=`` they are
+Chrome spans too.  All instrumentation is host-clock only and measures
+*dispatch*, not device completion (the zero-host-syncs-per-token
+invariant survives tracing); see ``runtime/telemetry.py`` for the exact
+timestamp semantics.
 """
 from __future__ import annotations
 
 import math
 import time
 from collections import deque, namedtuple
-from contextlib import nullcontext
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any, Deque, Dict, List, Optional
@@ -96,7 +99,8 @@ from repro.configs.base import ArchConfig
 from repro.runtime.faults import FaultInjector
 from repro.runtime.pagepool import GARBAGE_PAGE, PagePool
 from repro.runtime.roofline import HWSpec, RooflineAccountant
-from repro.runtime.telemetry import (PID_SCHED, MetricsRegistry, Telemetry)
+from repro.runtime.telemetry import (PID_SCHED, MetricsRegistry, Span,
+                                     Telemetry)
 
 FreeCapacity = namedtuple("FreeCapacity", ["lanes", "pages"])
 
@@ -175,13 +179,15 @@ class ContinuousBatchingScheduler:
         self.cfg = cfg
         self.params = params
         self.mod = models.get_module(cfg)
-        # telemetry: None keeps the tracer off (zero trace events, and
-        # the transfer-guard tests prove zero extra device traffic
-        # either way); the MetricsRegistry ALWAYS exists — it is the one
+        # telemetry: None keeps the Chrome tracer off (zero trace events,
+        # and the transfer-guard tests prove zero extra device traffic
+        # either way; the sched.* profiler spans are always on); the
+        # MetricsRegistry ALWAYS exists — it is the one
         # stats surface behind prefill_s/decode_s, paged_stats() and
         # lifecycle_stats(), whose legacy attributes are now properties
         # over registry counters (see _METRIC_ATTRS below).
         self.telemetry = telemetry
+        self._tracer = telemetry.tracer if telemetry is not None else None
         self.metrics = telemetry.metrics if telemetry is not None \
             else MetricsRegistry()
         if telemetry is not None:
@@ -390,19 +396,21 @@ class ContinuousBatchingScheduler:
     def _step(self, params, state):
         last, cache = self._decode_lanes(params, state["tokens"],
                                          state["cache"], state["pos"])
-        key, sub = jax.random.split(state["key"])
-        nxt = _sample(sub, last, state["temp"])
-        write = state["active"] & (state["out_len"] < state["budget"])
-        rows = jnp.arange(self.max_slots)
-        cols = jnp.clip(state["out_len"], 0, self.max_new_cap - 1)
-        cur = state["out_buf"][rows, cols]
-        out_buf = state["out_buf"].at[rows, cols].set(
-            jnp.where(write, nxt, cur))
-        # device-side EOS: a lane whose sampled token is in its stop set
-        # clears its own active bit.  The stop token IS written to the
-        # output (so "length" retirement sees it too); the lane simply
-        # stops advancing.  -1 entries never match (tokens are >= 0).
-        stop_hit = write & (nxt[:, None] == state["stop"]).any(axis=-1)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(state["key"])
+            nxt = _sample(sub, last, state["temp"])
+            write = state["active"] & (state["out_len"] < state["budget"])
+            rows = jnp.arange(self.max_slots)
+            cols = jnp.clip(state["out_len"], 0, self.max_new_cap - 1)
+            cur = state["out_buf"][rows, cols]
+            out_buf = state["out_buf"].at[rows, cols].set(
+                jnp.where(write, nxt, cur))
+            # device-side EOS: a lane whose sampled token is in its stop
+            # set clears its own active bit.  The stop token IS written to
+            # the output (so "length" retirement sees it too); the lane
+            # simply stops advancing.  -1 entries never match (tokens are
+            # >= 0).
+            stop_hit = write & (nxt[:, None] == state["stop"]).any(axis=-1)
         return {
             "tokens": jnp.where(write[:, None], nxt[:, None],
                                 state["tokens"]),
@@ -566,11 +574,14 @@ class ContinuousBatchingScheduler:
     # anchored at the real sync points (retirement fetch, done-mask
     # fetch).
 
-    def _span(self, name: str, **args):
-        """Tracer span (no-op context when telemetry is off)."""
-        if self.telemetry is None:
-            return nullcontext()
-        return self.telemetry.tracer.span(name, args=args or None)
+    def _pt_update(self, slot: int, fn, *args) -> None:
+        """Dispatch one page-table update (``fn`` one of the
+        ``_set_pt_*``/``_copy_page`` programs) for lane ``slot``.  Each
+        returns a new state, so it copies the whole pool on the device:
+        ``sched.pt_updates`` counts them."""
+        with Span("pt_update", self._tracer, slot=slot):
+            self.state = fn(self.state, *args)
+        self.metrics.counter("sched.pt_updates").inc()
 
     def _rt(self, uid: int):
         """The request's trace row, or None when telemetry is off."""
@@ -711,17 +722,16 @@ class ContinuousBatchingScheduler:
             if got is None:
                 return False
             self._pt_host[slot, idx] = got[0]
-            self.state = self._set_pt_entry_fn(
-                self.state, jnp.int32(slot), jnp.int32(idx),
-                jnp.int32(got[0]))
+            self._pt_update(slot, self._set_pt_entry_fn, jnp.int32(slot),
+                            jnp.int32(idx), jnp.int32(got[0]))
         elif self.pool.refcount[phys] > 1:
             got = self._alloc_pages(1, site=site + "cow", slot=slot)
             if got is None:
                 return False
             self._pt_host[slot, idx] = got[0]
-            self.state = self._copy_page_fn(
-                self.state, jnp.int32(phys), jnp.int32(got[0]),
-                jnp.int32(slot), jnp.int32(idx))
+            self._pt_update(slot, self._copy_page_fn, jnp.int32(phys),
+                            jnp.int32(got[0]), jnp.int32(slot),
+                            jnp.int32(idx))
             self.pool.free(phys)               # drop the lane's shared ref
             self.cow_copies += 1
         return True
@@ -739,16 +749,17 @@ class ContinuousBatchingScheduler:
         preempted instead of written."""
         if self._alloc_mode != "incremental":
             return
-        for slot in range(self.max_slots):
-            if slot == extra:
-                continue
-            while self.slots[slot] is not None \
-                    and self._steps_left[slot] > 0 \
-                    and not self._ensure_writable(
-                        slot, int(self._host_pos[slot])):
-                victim = self._preempt_lowest(protect=extra)
-                if victim is None or victim == slot:
-                    break
+        with Span("prepare_writes", self._tracer):
+            for slot in range(self.max_slots):
+                if slot == extra:
+                    continue
+                while self.slots[slot] is not None \
+                        and self._steps_left[slot] > 0 \
+                        and not self._ensure_writable(
+                            slot, int(self._host_pos[slot])):
+                    victim = self._preempt_lowest(protect=extra)
+                    if victim is None or victim == slot:
+                        break
 
     def _preempt_lowest(self, protect: Optional[int] = None
                         ) -> Optional[int]:
@@ -817,73 +828,73 @@ class ContinuousBatchingScheduler:
                 self.pool.free(phys)
         self._pt_host[slot] = 0
         self._host_pos[slot] = 0
-        self.state = self._set_pt_row_fn(
-            self.state, jnp.int32(slot),
-            jnp.zeros((self.pages_per_lane,), jnp.int32))
+        self._pt_update(slot, self._set_pt_row_fn, jnp.int32(slot),
+                        jnp.zeros((self.pages_per_lane,), jnp.int32))
 
     # -- host-side scheduling ------------------------------------------------
 
     def submit(self, request: Request) -> None:
-        request.submitted_at = time.perf_counter()
-        if request.max_new_tokens > self.max_new_cap:
-            raise ValueError(
-                f"request {request.uid}: max_new_tokens="
-                f"{request.max_new_tokens} exceeds scheduler cap "
-                f"{self.max_new_cap}")
-        if len(self._stop_set(request)) > self.max_stop_tokens:
-            raise ValueError(
-                f"request {request.uid}: {len(self._stop_set(request))} "
-                f"stop tokens exceed max_stop_tokens="
-                f"{self.max_stop_tokens}")
-        plen = self._bucket(len(request.prompt))
-        # the last decode step writes KV at position plen + max_new - 2
-        # (the final sampled token is never fed back), so any request
-        # with plen + max_new_tokens - 1 > window would wrap the cache
-        # mid-decode and corrupt its own prefix.  Families whose window
-        # wraps by design (rglru's local attention) or that have no KV
-        # ring at all (rwkv6) set RING_WRAP_SAFE and skip the guard.
-        wrap_safe = getattr(self.mod, "RING_WRAP_SAFE", False)
-        if self._paged:
-            # pool-capacity guard (the old cache_len bound is obsolete:
-            # a lane's logical window wraps at pages_per_lane * page_size
-            # like the ring did, but pages must EXIST in the pool)
-            if plen > self._capacity:
+        with Span("submit", self._tracer, uid=request.uid):
+            request.submitted_at = time.perf_counter()
+            if request.max_new_tokens > self.max_new_cap:
+                raise ValueError(
+                    f"request {request.uid}: max_new_tokens="
+                    f"{request.max_new_tokens} exceeds scheduler cap "
+                    f"{self.max_new_cap}")
+            if len(self._stop_set(request)) > self.max_stop_tokens:
+                raise ValueError(
+                    f"request {request.uid}: {len(self._stop_set(request))} "
+                    f"stop tokens exceed max_stop_tokens="
+                    f"{self.max_stop_tokens}")
+            plen = self._bucket(len(request.prompt))
+            # the last decode step writes KV at position plen + max_new - 2
+            # (the final sampled token is never fed back), so any request
+            # with plen + max_new_tokens - 1 > window would wrap the cache
+            # mid-decode and corrupt its own prefix.  Families whose window
+            # wraps by design (rglru's local attention) or that have no KV
+            # ring at all (rwkv6) set RING_WRAP_SAFE and skip the guard.
+            wrap_safe = getattr(self.mod, "RING_WRAP_SAFE", False)
+            if self._paged:
+                # pool-capacity guard (the old cache_len bound is obsolete:
+                # a lane's logical window wraps at pages_per_lane * page_size
+                # like the ring did, but pages must EXIST in the pool)
+                if plen > self._capacity:
+                    raise ValueError(
+                        f"request {request.uid}: prompt length "
+                        f"{len(request.prompt)} (padded to {plen}) exceeds "
+                        f"the paged lane capacity {self._capacity} "
+                        f"({self.pages_per_lane} pages x {self.page_size})")
+                if not wrap_safe and \
+                        plen + request.max_new_tokens - 1 > self._capacity:
+                    raise ValueError(
+                        f"request {request.uid}: prompt ({plen} padded) + "
+                        f"max_new_tokens ({request.max_new_tokens}) would "
+                        f"wrap the paged window ({self._capacity}) mid-decode "
+                        "and corrupt the prompt prefix; shrink one of them")
+                need = min(-(-(plen + request.max_new_tokens)
+                             // self.page_size), self.pages_per_lane)
+                if need > self.num_pages - 1:
+                    raise ValueError(
+                        f"request {request.uid}: needs {need} pages but the "
+                        f"pool holds only {self.num_pages - 1} allocatable "
+                        f"(num_pages={self.num_pages} incl. garbage page)")
+            elif plen > self.cache_len:
                 raise ValueError(
                     f"request {request.uid}: prompt length "
-                    f"{len(request.prompt)} (padded to {plen}) exceeds "
-                    f"the paged lane capacity {self._capacity} "
-                    f"({self.pages_per_lane} pages x {self.page_size})")
-            if not wrap_safe and \
-                    plen + request.max_new_tokens - 1 > self._capacity:
+                    f"{len(request.prompt)} (padded to {plen} by the prefill "
+                    f"bucket) exceeds cache_len={self.cache_len} — the ring "
+                    f"cache would wrap during prefill and corrupt the prefix")
+            elif not wrap_safe and \
+                    plen + request.max_new_tokens - 1 > self.cache_len:
                 raise ValueError(
                     f"request {request.uid}: prompt ({plen} padded) + "
-                    f"max_new_tokens ({request.max_new_tokens}) would "
-                    f"wrap the paged window ({self._capacity}) mid-decode "
+                    f"max_new_tokens ({request.max_new_tokens}) would wrap "
+                    f"the ring cache (cache_len={self.cache_len}) mid-decode "
                     "and corrupt the prompt prefix; shrink one of them")
-            need = min(-(-(plen + request.max_new_tokens)
-                         // self.page_size), self.pages_per_lane)
-            if need > self.num_pages - 1:
-                raise ValueError(
-                    f"request {request.uid}: needs {need} pages but the "
-                    f"pool holds only {self.num_pages - 1} allocatable "
-                    f"(num_pages={self.num_pages} incl. garbage page)")
-        elif plen > self.cache_len:
-            raise ValueError(
-                f"request {request.uid}: prompt length "
-                f"{len(request.prompt)} (padded to {plen} by the prefill "
-                f"bucket) exceeds cache_len={self.cache_len} — the ring "
-                f"cache would wrap during prefill and corrupt the prefix")
-        elif not wrap_safe and \
-                plen + request.max_new_tokens - 1 > self.cache_len:
-            raise ValueError(
-                f"request {request.uid}: prompt ({plen} padded) + "
-                f"max_new_tokens ({request.max_new_tokens}) would wrap "
-                f"the ring cache (cache_len={self.cache_len}) mid-decode "
-                "and corrupt the prompt prefix; shrink one of them")
-        rt = self._rt(request.uid)
-        if rt is not None:
-            rt.submitted(len(request.prompt), request.max_new_tokens)
-        self.pending.append(request)
+            rt = self._rt(request.uid)
+            if rt is not None:
+                rt.submitted(len(request.prompt), request.max_new_tokens)
+            self.pending.append(request)
 
     def _stop_set(self, req: Request) -> frozenset:
         stops = set(req.stop_tokens or ())
@@ -942,18 +953,20 @@ class ContinuousBatchingScheduler:
                 plen = self._bucket(len(req.prompt))
                 toks = np.full((1, plen), self.pad_id, np.int32)
                 toks[0, plen - len(req.prompt):] = req.prompt  # left-pad
-                with self._span("admit", uid=req.uid, slot=slot,
+                with Span("admit", self._tracer, uid=req.uid, slot=slot,
                                 plen=plen):
                     if self._paged:
                         verdict = self._admit_paged_host(req, slot, toks,
                                                          plen)
                     else:
                         verdict = "ok"
-                        self.state = self._admit_fn(
-                            self.params, self.state, jnp.asarray(toks),
-                            jnp.int32(slot), jnp.float32(req.temperature),
-                            jnp.int32(req.max_new_tokens),
-                            self._stop_row(req), plen=plen)
+                        with Span("prefill", self._tracer):
+                            self.state = self._admit_fn(
+                                self.params, self.state, jnp.asarray(toks),
+                                jnp.int32(slot),
+                                jnp.float32(req.temperature),
+                                jnp.int32(req.max_new_tokens),
+                                self._stop_row(req), plen=plen)
                 if verdict == "dropped":
                     continue                   # cancelled mid-admission
                 if verdict == "defer":
@@ -991,17 +1004,18 @@ class ContinuousBatchingScheduler:
         ps = self.page_size
         npages = self.pages_per_lane if self._alloc_mode == "full" \
             else -(-plen // ps)
-        key_tokens = [int(t) for t in toks[0]]
         self.admissions += 1
         self.prefill_tokens_total += plen
-        entry = self.pool.prefix_lookup(key_tokens) \
-            if self.prefix_sharing else None
+        with Span("prefix_lookup", self._tracer, tokens=plen):
+            key_tokens = [int(t) for t in toks[0]]
+            entry = self.pool.prefix_lookup(key_tokens) \
+                if self.prefix_sharing else None
         if entry is not None:
             # cap the reused length at plen - 1 so at least one suffix
             # step runs — its logits seed the first sampled token
             t = min(entry.length, plen - 1)
-            span = -(-t // ps)
-            shared = list(entry.pages[:span])
+            nshared = -(-t // ps)
+            shared = list(entry.pages[:nshared])
             self.prefix_hits += 1
             self.prefill_tokens_saved += t
             rt = self._rt(req.uid)
@@ -1010,15 +1024,15 @@ class ContinuousBatchingScheduler:
             for p in shared:
                 self.pool.ref(p)
             self._pt_host[slot] = 0
-            self._pt_host[slot, :span] = shared
+            self._pt_host[slot, :nshared] = shared
             row = np.zeros((self.pages_per_lane,), np.int32)
-            row[:span] = shared
-            self.state = self._set_pt_row_fn(self.state, jnp.int32(slot),
-                                             jnp.asarray(row))
+            row[:nshared] = shared
+            self._pt_update(slot, self._set_pt_row_fn, jnp.int32(slot),
+                            jnp.asarray(row))
             # suffix prefill: one batched step per remaining prompt token
             logits = None
             aborted = None
-            with self._span("suffix_prefill", uid=req.uid,
+            with Span("suffix_prefill", self._tracer, uid=req.uid,
                             tokens=plen - t):
                 for i in range(t, plen):
                     if self.faults is not None:
@@ -1061,28 +1075,32 @@ class ContinuousBatchingScheduler:
             rt = self._rt(req.uid)
             if rt is not None:
                 rt.prefix_lookup(False, 0)
-            pages = self._alloc_pages(npages, site="admission", slot=slot)
+            with Span("alloc", self._tracer, pages=npages):
+                pages = self._alloc_pages(npages, site="admission",
+                                          slot=slot)
             if pages is None:
                 self.admissions -= 1
                 self.prefill_tokens_total -= plen
                 return "defer"
             self._pt_host[slot] = 0
             self._pt_host[slot, :npages] = pages
-            self.state = self._admit_paged_fn(
-                self.params, self.state, jnp.asarray(toks),
-                jnp.int32(slot), jnp.float32(req.temperature),
-                jnp.int32(req.max_new_tokens),
-                jnp.asarray(pages, jnp.int32), self._stop_row(req),
-                plen=plen)
+            with Span("prefill", self._tracer):
+                self.state = self._admit_paged_fn(
+                    self.params, self.state, jnp.asarray(toks),
+                    jnp.int32(slot), jnp.float32(req.temperature),
+                    jnp.int32(req.max_new_tokens),
+                    jnp.asarray(pages, jnp.int32), self._stop_row(req),
+                    plen=plen)
         self._host_pos[slot] = plen
         if self.prefix_sharing:
             # publish this lane's page-aligned prefixes (and the full
             # prompt).  COW keeps the entries pristine once the lane
             # decodes past them.
             span_full = -(-plen // ps)
-            self.pool.prefix_register(
-                key_tokens,
-                [int(p) for p in self._pt_host[slot, :span_full]])
+            with Span("prefix_register", self._tracer, tokens=plen):
+                self.pool.prefix_register(
+                    key_tokens,
+                    [int(p) for p in self._pt_host[slot, :span_full]])
         return "ok"
 
     def _retire_slot(self, slot: int, reason: str,
@@ -1091,50 +1109,52 @@ class ContinuousBatchingScheduler:
         ONE device->host transfer, record its finish reason, free its
         lane (and pages), and tally the lifecycle counters."""
         req = self.slots[slot]
-        if _prefetched is not None:
-            row, n = _prefetched
-        else:
-            # the fetch is where async dispatch settles — this span's
-            # duration is real device catch-up time, not dispatch cost
-            with self._span("retire_fetch", uid=req.uid, slot=slot):
-                row, n = jax.device_get((self.state["out_buf"][slot],
-                                         self.state["out_len"][slot]))
-            self.host_syncs += 1
-        n = int(n)
-        produced = [int(t) for t in row[:n]]
-        req.output.extend(produced)
-        self.tokens_generated += n
-        if reason == "length" and produced \
-                and produced[-1] in self._stop_sets[slot]:
-            # the lane sampled EOS on its final budgeted step (or the
-            # periodic mask check hadn't run yet) — the budget is spent
-            # but the sequence still terminated properly
-            reason = "eos"
-        if reason == "eos":
-            self.eos_finishes += 1
-            self.eos_steps_saved += max(req.max_new_tokens - n, 0)
-        elif reason == "cancelled":
-            self.cancellations += 1
-        elif reason == "timeout":
-            self.deadline_misses += 1
-        if reason in ("cancelled", "timeout"):
-            # the lane may still be active on device: mask it out so its
-            # writes stop before the slot is reused
-            self.state = self._deactivate_fn(self.state, jnp.int32(slot))
-        req.finish_reason = reason
-        req.done = True
-        req.finished_at = time.perf_counter()
-        if reason in ("cancelled", "timeout"):
-            # attach the why-did-this-die snapshot before the lane state
-            # is torn down (satellite: "stuck" becomes a diagnosis)
-            req.diagnostics = self.telemetry_snapshot()
-        self._record_finish(req)
-        self.slots[slot] = None
-        self._steps_left[slot] = 0
-        self._host_valid[slot] = 0
-        self._set_stop_host(slot, None)
-        if self._paged:
-            self._release_lane_pages(slot)
+        with Span("retire", self._tracer, uid=req.uid, slot=slot):
+            if _prefetched is not None:
+                row, n = _prefetched
+            else:
+                # the fetch is where async dispatch settles — this span's
+                # duration is real device catch-up time, not dispatch cost
+                with Span("retire_fetch", self._tracer, uid=req.uid,
+                          slot=slot):
+                    row, n = jax.device_get((self.state["out_buf"][slot],
+                                             self.state["out_len"][slot]))
+                self.host_syncs += 1
+            n = int(n)
+            produced = [int(t) for t in row[:n]]
+            req.output.extend(produced)
+            self.tokens_generated += n
+            if reason == "length" and produced \
+                    and produced[-1] in self._stop_sets[slot]:
+                # the lane sampled EOS on its final budgeted step (or the
+                # periodic mask check hadn't run yet) — the budget is spent
+                # but the sequence still terminated properly
+                reason = "eos"
+            if reason == "eos":
+                self.eos_finishes += 1
+                self.eos_steps_saved += max(req.max_new_tokens - n, 0)
+            elif reason == "cancelled":
+                self.cancellations += 1
+            elif reason == "timeout":
+                self.deadline_misses += 1
+            if reason in ("cancelled", "timeout"):
+                # the lane may still be active on device: mask it out so its
+                # writes stop before the slot is reused
+                self.state = self._deactivate_fn(self.state, jnp.int32(slot))
+            req.finish_reason = reason
+            req.done = True
+            req.finished_at = time.perf_counter()
+            if reason in ("cancelled", "timeout"):
+                # attach the why-did-this-die snapshot before the lane state
+                # is torn down (satellite: "stuck" becomes a diagnosis)
+                req.diagnostics = self.telemetry_snapshot()
+            self._record_finish(req)
+            self.slots[slot] = None
+            self._steps_left[slot] = 0
+            self._host_valid[slot] = 0
+            self._set_stop_host(slot, None)
+            if self._paged:
+                self._release_lane_pages(slot)
 
     def _retire_finished(self) -> None:
         for slot, req in enumerate(self.slots):
@@ -1201,13 +1221,11 @@ class ContinuousBatchingScheduler:
                    and self._steps_left[s] > 0
                    for s in range(self.max_slots)):
             return
-        alive = np.asarray(self.state["active"])
+        # this fetch is a real sync point — its span shows trace readers
+        # where device completion is anchored
+        with Span("eos_mask_fetch", self._tracer, tick=self._tick_no):
+            alive = np.asarray(self.state["active"])
         self.mask_syncs += 1
-        if self.telemetry is not None:
-            # this fetch is a real sync point — mark it so trace readers
-            # know where device completion is anchored
-            self.telemetry.tracer.instant(
-                "eos_mask_fetch", args={"tick": self._tick_no})
         for slot, req in enumerate(self.slots):
             if req is not None and self._steps_left[slot] > 0 \
                     and self._has_stops[slot] and not alive[slot]:
@@ -1221,9 +1239,21 @@ class ContinuousBatchingScheduler:
         fetch is where JAX's async dispatch settles, so excluding it
         would credit the scheduler with near-zero decode time."""
         self._tick_no += 1
+        with Span("tick", self._tracer) as tick_span:
+            busy, did = self._tick()
+            # the Chrome trace keeps only the ticks that did something
+            tick_span.record = any(did.values())
+            tick_span.args = {"tick": self._tick_no, **did,
+                              "pending": len(self.pending)}
+        if self._tracer is not None and tick_span.record and self._paged:
+            self._tracer.counter_event("free_pages",
+                                       {"free": self.pool.available()})
+        return busy
+
+    def _tick(self):
+        """The body of :meth:`tick`: returns whether the scheduler is
+        still busy, and whether the tick admitted, worked and retired."""
         t_tick0 = time.perf_counter()
-        tr = self.telemetry.tracer if self.telemetry is not None else None
-        tick_ts0 = tr.now_us() if tr is not None else 0.0
         # progress snapshot for the no-progress watchdog
         marker = (self.host_syncs, self.preemptions, self.cancellations,
                   self.deadline_misses, len(self.pending))
@@ -1233,20 +1263,19 @@ class ContinuousBatchingScheduler:
         admitted = self._admit_pending()
         t0 = time.perf_counter()
         worked = False
-        if any(self._steps_left[s] > 0 for s, r in enumerate(self.slots)
-               if r is not None):
-            if self._paged:
-                # every writing lane must own its target page before the
-                # step lands (first-touch allocation / copy-on-write) —
-                # this can preempt lanes, so re-check below
-                with self._span("prepare_writes"):
-                    self._prepare_writes()
+        if self._paged and any(self._steps_left[s] > 0
+                               for s, r in enumerate(self.slots)
+                               if r is not None):
+            # every writing lane must own its target page before the
+            # step lands (first-touch allocation / copy-on-write) —
+            # this can preempt lanes, so re-check below
+            self._prepare_writes()
         work = [s for s, r in enumerate(self.slots)
                 if r is not None and self._steps_left[s] > 0]
         if work:
             # span/histogram measure ENQUEUE cost: the jitted step is
             # dispatched asynchronously, the device may still be running
-            with self._span("step_dispatch"):
+            with Span("step_dispatch", self._tracer):
                 ts0 = time.perf_counter()
                 self.state = self._step_fn(self.params, self.state)
                 self.metrics.histogram("sched.step_dispatch_s").record(
@@ -1254,21 +1283,22 @@ class ContinuousBatchingScheduler:
             # roofline accounting for the step just dispatched: host
             # arithmetic over the mirrored positions (pre-advance), no
             # device reads
-            rf_bytes, rf_flops = self.roofline.step_cost(
-                [int(self._host_valid[s]) for s in work])
-            self.metrics.counter("roofline.analytic_bytes").inc(rf_bytes)
-            self.metrics.counter("roofline.analytic_flops").inc(rf_flops)
-            self.metrics.counter("roofline.tokens").inc(len(work))
-            for slot in work:
-                req = self.slots[slot]
-                self._steps_left[slot] -= 1
-                self._host_valid[slot] += 1
-                if self._paged:
-                    self._host_pos[slot] += 1
-                rt = self._rt(req.uid)
-                if rt is not None:
-                    rt.progressed(req.max_new_tokens
-                                  - int(self._steps_left[slot]))
+            with Span("account", self._tracer):
+                rf_bytes, rf_flops = self.roofline.step_cost(
+                    [int(self._host_valid[s]) for s in work])
+                self.metrics.counter("roofline.analytic_bytes").inc(rf_bytes)
+                self.metrics.counter("roofline.analytic_flops").inc(rf_flops)
+                self.metrics.counter("roofline.tokens").inc(len(work))
+                for slot in work:
+                    req = self.slots[slot]
+                    self._steps_left[slot] -= 1
+                    self._host_valid[slot] += 1
+                    if self._paged:
+                        self._host_pos[slot] += 1
+                    rt = self._rt(req.uid)
+                    if rt is not None:
+                        rt.progressed(req.max_new_tokens
+                                      - int(self._steps_left[slot]))
             worked = True
         if worked and self._tick_no % self.eos_check_interval == 0:
             self._reconcile_eos()
@@ -1277,11 +1307,6 @@ class ContinuousBatchingScheduler:
         retired = self.host_syncs > syncs
         if worked or retired:
             self.decode_s += time.perf_counter() - t0
-        if retired:
-            # the retirement fetch is where async dispatch settles —
-            # amortize achieved-vs-roofline utilization against it so
-            # MBU/MFU cost no extra sync
-            self._record_utilization()
         busy = bool(self.pending) or any(r is not None for r in self.slots)
         progressed = admitted or worked or marker != (
             self.host_syncs, self.preemptions, self.cancellations,
@@ -1292,27 +1317,28 @@ class ContinuousBatchingScheduler:
                 self._raise_stalled()
         else:
             self._stall_ticks = 0
-        self._last_tick_s = time.perf_counter() - t_tick0
-        if admitted or worked or retired:
-            self.metrics.histogram("sched.tick_s").record(self._last_tick_s)
-        self.metrics.gauge("sched.live_lanes").set(
-            sum(r is not None for r in self.slots))
-        if self._paged:
-            self.metrics.gauge("pool.free_pages").set(self.pool.available())
-            self.metrics.gauge("pool.occupancy_frac").set(
-                1.0 - self.pool.available() / self.num_pages)
-            if self.admissions:
-                self.metrics.gauge("sched.prefix_hit_ratio").set(
-                    self.prefix_hits / self.admissions)
-        if tr is not None and (admitted or worked or retired):
-            tr.complete("tick", tick_ts0, tr.now_us() - tick_ts0,
-                        args={"tick": self._tick_no, "admitted": admitted,
-                              "worked": worked, "retired": retired,
-                              "pending": len(self.pending)})
+        with Span("account", self._tracer):
+            if retired:
+                # the retirement fetch is where async dispatch settles —
+                # amortize achieved-vs-roofline utilization against it so
+                # MBU/MFU cost no extra sync
+                self._record_utilization()
+            self._last_tick_s = time.perf_counter() - t_tick0
+            if admitted or worked or retired:
+                self.metrics.histogram("sched.tick_s").record(
+                    self._last_tick_s)
+            self.metrics.gauge("sched.live_lanes").set(
+                sum(r is not None for r in self.slots))
             if self._paged:
-                tr.counter_event("free_pages",
-                                 {"free": self.pool.available()})
-        return busy
+                self.metrics.gauge("pool.free_pages").set(
+                    self.pool.available())
+                self.metrics.gauge("pool.occupancy_frac").set(
+                    1.0 - self.pool.available() / self.num_pages)
+                if self.admissions:
+                    self.metrics.gauge("sched.prefix_hit_ratio").set(
+                        self.prefix_hits / self.admissions)
+        return busy, {"admitted": admitted, "worked": worked,
+                      "retired": retired}
 
     def _raise_stalled(self) -> None:
         lanes = [f"slot {s}: uid={r.uid} steps_left="

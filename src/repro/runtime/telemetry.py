@@ -1,4 +1,5 @@
-"""Serving telemetry: metrics registry + Chrome-trace request tracing.
+"""Serving telemetry: metrics registry, profiler spans and Chrome-trace
+request tracing.
 
 The paper's core argument is that inference performance must be
 *measured*, not assumed — its GPU-vs-CPU convolution benchmarks are what
@@ -6,7 +7,7 @@ justify the Metal implementation.  This module is the measurement
 substrate for the serving stack: every later perf item (chunked
 prefill, speculative decoding, TP sharding) reports through it.
 
-Three layers, all pure host Python (no jax, no deps):
+Four layers, all host Python:
 
 * :class:`MetricsRegistry` — named :class:`Counter`/:class:`Gauge`/
   :class:`Histogram` instruments.  Histograms are log-bucketed
@@ -16,6 +17,13 @@ Three layers, all pure host Python (no jax, no deps):
   scheduler *always* owns a registry — the ad-hoc ``prefill_s`` /
   ``paged_stats()`` counters of earlier PRs are now thin views over it
   — so there is exactly one stats surface.
+
+* :class:`Span` — the scheduler's phase spans (:data:`SPANS`).  Always
+  on: each opens a ``jax.profiler.TraceAnnotation`` named
+  ``sched.<phase>`` with integer args, so a ``jax.profiler`` trace holds
+  them on the same clock as the device's operations, and without a
+  profiler running it is a ~1 µs no-op.  With a :class:`Tracer` the same
+  call also records a Chrome span named ``<phase>``.
 
 * :class:`Tracer` — records span ("X"), instant ("i"), async ("b"/"e"),
   counter ("C") and metadata ("M") events and exports Chrome
@@ -55,7 +63,8 @@ timestamps measure *dispatch*, not device completion:
 
 None of the above adds a device→host transfer: telemetry-on and
 telemetry-off schedulers make byte-identical device traffic (guarded
-by ``tests/test_telemetry.py``).
+by ``tests/test_telemetry.py``).  The profiler spans are host time too;
+what the device did meanwhile is on the trace's device planes.
 """
 from __future__ import annotations
 
@@ -68,8 +77,10 @@ import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
-__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "Tracer", "RequestTrace", "Telemetry", "prom_name"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry", "SPANS",
+           "Span", "Tracer", "RequestTrace", "Telemetry", "prom_name"]
 
 _PROM_INVALID = re.compile(r"[^a-zA-Z0-9_:]")
 
@@ -400,6 +411,52 @@ class Tracer:
         self.events.clear()
         self._named_threads.clear()
         self.dropped = 0
+
+
+# -- scheduler phase spans ----------------------------------------------------
+
+SPAN_PREFIX = "sched."
+# every phase the scheduler marks, as the profiler's trace names it; the
+# nesting is documented in docs/serving.md ("Profiler spans")
+SPANS = tuple(SPAN_PREFIX + n for n in (
+    "submit", "tick", "admit", "prefix_lookup", "alloc", "prefill",
+    "suffix_prefill", "prefix_register", "prepare_writes", "pt_update",
+    "step_dispatch", "account", "eos_mask_fetch", "retire",
+    "retire_fetch"))
+_PHASES = frozenset(n[len(SPAN_PREFIX):] for n in SPANS)
+
+
+class Span:
+    """One scheduler phase, ``with Span("admit", tracer, uid=3):``: a
+    profiler annotation ``sched.<name>`` with the integer ``args``, plus
+    a Chrome ``<name>`` span when a tracer is given (``name`` is one of
+    :data:`SPANS` without its prefix).  ``args`` and ``record`` may be
+    changed inside the ``with`` body; they then apply to the Chrome span
+    only (``record = False`` drops it)."""
+
+    __slots__ = ("name", "args", "record", "_tracer", "_ann", "_t0")
+
+    def __init__(self, name: str, tracer: Optional["Tracer"] = None,
+                 **args: int) -> None:
+        if name not in _PHASES:
+            raise ValueError(f"unknown scheduler span {name!r}")
+        self.name, self.args, self.record = name, args, True
+        self._tracer = tracer
+        self._ann = TraceAnnotation(SPAN_PREFIX + name, **args)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "Span":
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer.now_us()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._tracer is not None and self.record:
+            self._tracer.complete(self.name, self._t0,
+                                  self._tracer.now_us() - self._t0,
+                                  args=self.args or None)
+        self._ann.__exit__(*exc)
 
 
 class RequestTrace:

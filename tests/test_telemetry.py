@@ -379,3 +379,104 @@ def test_cancel_and_timeout_attach_diagnostics(tiny):
         assert {"tick", "free_pages", "free_lanes",
                 "last_tick_ms"} <= set(d)
     assert reqs[0].diagnostics is None   # clean finishes carry none
+
+
+# ---------------------------------------------------------------------------
+# scheduler phases as profiler spans
+# ---------------------------------------------------------------------------
+
+# each phase's innermost enclosing phase (None: no enclosing phase)
+SPAN_PARENTS = {
+    "sched.submit": {None}, "sched.tick": {None},
+    "sched.admit": {"sched.tick"},
+    "sched.prefix_lookup": {"sched.admit"}, "sched.alloc": {"sched.admit"},
+    "sched.prefill": {"sched.admit"},
+    "sched.suffix_prefill": {"sched.admit"},
+    "sched.prefix_register": {"sched.admit"},
+    "sched.prepare_writes": {"sched.tick", "sched.suffix_prefill"},
+    "sched.pt_update": {"sched.prepare_writes", "sched.admit",
+                        "sched.suffix_prefill", "sched.retire"},
+    "sched.step_dispatch": {"sched.tick"}, "sched.account": {"sched.tick"},
+    "sched.eos_mask_fetch": {"sched.tick"}, "sched.retire": {"sched.tick"},
+    "sched.retire_fetch": {"sched.retire"},
+}
+
+
+def _profiled_spans(tmp_path, body):
+    """Run ``body`` under the JAX profiler; the ``sched.*`` events of the
+    trace's host plane as ``(name, start_ns, end_ns, stats)``."""
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    pd = ProfileData.from_file(str(next(tmp_path.rglob("*.xplane.pb"))))
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for p in pd.planes if p.name == "/host:CPU"
+            for line in p.lines for e in line.events
+            if e.name.startswith("sched.")]
+
+
+def _innermost_parent(ev, events):
+    """The shortest other event that encloses ``ev`` (None if none)."""
+    best = None
+    for other in events:
+        if other is ev or not (other[1] <= ev[1] and ev[2] <= other[2]):
+            continue
+        if other[2] - other[1] == ev[2] - ev[1] and other[0] == ev[0]:
+            continue
+        if best is None or other[2] - other[1] < best[2] - best[1]:
+            best = other
+    return None if best is None else best[0]
+
+
+def test_scheduler_phases_reach_the_profiler_trace(tiny, tmp_path):
+    """With ``jax.profiler`` running and no ``telemetry=``, a paged
+    scheduler ticked through a cold admission, a prefix-hit admission, a
+    page boundary, the EOS mask fetch and retirements writes every phase
+    of ``telemetry.SPANS`` into the trace's host plane, nested as the
+    scheduler nests them, with integer args as event stats."""
+    from repro.runtime.telemetry import SPANS
+    cfg, params = tiny
+    # a stop token turns on the periodic done-mask fetch
+    s = _sched(cfg, params, kv_layout="paged", page_size=16,
+               eos_id=cfg.vocab_size - 1, eos_check_interval=1)
+
+    def body():
+        s.submit(Request(uid=0, prompt=list(P0), max_new_tokens=6))
+        s.run()                       # cold admission, page boundary
+        s.submit(Request(uid=1, prompt=list(P0), max_new_tokens=4))
+        s.run()                       # prefix hit: suffix prefill
+
+    events = _profiled_spans(tmp_path, body)
+    names = {e[0] for e in events}
+    assert names == set(SPANS)
+    for ev in events:
+        assert _innermost_parent(ev, events) in SPAN_PARENTS[ev[0]], ev[:3]
+    admits = [e for e in events if e[0] == "sched.admit"]
+    assert sorted(e[3]["uid"] for e in admits) == [0, 1]
+    assert {e[3]["plen"] for e in admits} == {len(P0)}
+    assert s.metrics.counter("sched.pt_updates").value == sum(
+        e[0] == "sched.pt_update" for e in events)
+    assert s.metrics.counter("pool.prefix_key_tokens").value > 0
+
+
+def test_phase_spans_keep_their_chrome_names(tiny):
+    """With ``telemetry=`` the same calls record Chrome spans under the
+    phase's bare name, and an unknown phase is refused."""
+    from repro.runtime.telemetry import SPANS, Span
+    cfg, params = tiny
+    tel = Telemetry()
+    s = _sched(cfg, params, kv_layout="paged", page_size=16, telemetry=tel)
+    s.submit(Request(uid=0, prompt=list(P0), max_new_tokens=4))
+    s.run()
+    xs = {e["name"] for e in tel.tracer.events if e["ph"] == "X"}
+    assert xs <= {n[len("sched."):] for n in SPANS}
+    assert {"submit", "tick", "admit", "prefill", "retire",
+            "retire_fetch", "account"} <= xs
+    with pytest.raises(ValueError, match="unknown scheduler span"):
+        Span("tock")

@@ -495,8 +495,8 @@ def _decode_attn_paged_ref_b(q, k_cache, v_cache, valid_len, *,
 
 def _decode_attn_paged_b(q, k_cache, v_cache, valid_len, *, page_table=None,
                          interpret=None):
-    """Page pool + per-lane page table: flash-decode with the page table
-    as a second scalar-prefetch operand (index maps do the gather)."""
+    """Page pool + per-lane page table: flash-decode that copies each
+    lane's valid pages through its page-table row."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged(q[:, 0], k_cache, v_cache, page_table,
                                       valid_len, interpret=interpret)
@@ -516,8 +516,8 @@ def _decode_attn_paged_ref_q8_b(q, k_cache, v_cache, valid_len, *,
 
 def _decode_attn_paged_q8_b(q, k_cache, v_cache, valid_len, *, k_scale=None,
                             v_scale=None, page_table=None, interpret=None):
-    """Paged int8 pools: flash-decode, page-table-indirected scale DMA +
-    in-kernel dequant."""
+    """Paged int8 pools: flash-decode, page-table-indirected payload and
+    scale DMA + in-kernel dequant."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged_q8(q[:, 0], k_cache, v_cache, k_scale,
                                          v_scale, page_table, valid_len,

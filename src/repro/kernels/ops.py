@@ -119,8 +119,8 @@ def decode_attention_q8(q, k, v, k_scale, v_scale, valid_len, *,
 def decode_attention_paged(q, k, v, page_table, valid_len, *,
                            interpret=None):
     """Paged flash-decode: K/V live in a global page pool, each lane's
-    int32 page-table row supplies the physical page per KV block (block
-    size = page size)."""
+    int32 page-table row names the physical pages its valid slots fill;
+    the kernel copies only those pages, one DMA per page of all KV heads."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
                                       interpret=_interpret(interpret))
 
@@ -129,7 +129,8 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
 def decode_attention_paged_q8(q, k, v, k_scale, v_scale, page_table,
                               valid_len, *, interpret=None):
     """Paged int8 flash-decode: page-table indirection over int8 payload
-    pools AND their per-slot fp32 scale pools, dequant in the block loop."""
+    pools AND their per-slot fp32 scale pools, dequantized per block in
+    VMEM."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
                                       k_scale=k_scale, v_scale=v_scale,
                                       interpret=_interpret(interpret))

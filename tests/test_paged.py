@@ -20,6 +20,7 @@ import pytest
 
 from repro import models
 from repro.configs.base import get_config, reduced
+from repro.kernels import decode_attention as da
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.runtime.pagepool import GARBAGE_PAGE, PagePool
@@ -94,6 +95,63 @@ def test_paged_kernel_fragmented_out_of_order_pages(valid_kind, quantized):
         want = kref.decode_attention_paged_ref(q, k, v, pt, valid)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("group", [2, 4])
+@pytest.mark.parametrize("kind", ["bf16", "f32", "int8"])
+def test_paged_kernel_block_edges_at_serving_head_layouts(kind, group):
+    """The paged kernel against the gather oracle at the served models'
+    head layouts (8 KV heads of 128, groups of 2 and 4), one lane per edge
+    of its page blocks: 1, ps and ps+1 slots, exactly one block, one block
+    + 1, and the whole table, whose width is no multiple of the block.
+    Two lanes share their first physical pages (a prefix hit), and the
+    unused tail of every row points at the garbage page 0, which holds
+    large values that a read past a lane's end would show."""
+    rng = np.random.default_rng(2)
+    kvh, d, ps = 8, 128, 16
+    itemsize = {"bf16": 2, "f32": 4, "int8": 1}[kind]
+    ppb = da._pages_per_block(kvh, ps, d, 1 << 10, itemsize, kind == "int8")
+    block = ppb * ps
+    w = ppb + 3                               # no multiple of the block
+    lens = [1, ps, ps + 1, block, block + 1, w * ps]
+    b, h = len(lens), kvh * group
+    n_pages = [-(-n // ps) for n in lens]
+    p = 1 + sum(n_pages)
+    perm = iter(rng.permutation(np.arange(1, p)))
+    pt = np.zeros((b, w), np.int32)           # tails: the garbage page
+    for lane, n in enumerate(n_pages):
+        pt[lane, :n] = [next(perm) for _ in range(n)]
+    pt[4, :ppb] = pt[3, :ppb]                 # lanes 3, 4: shared prefix
+    pt, valid = jnp.asarray(pt), jnp.asarray(lens, jnp.int32)
+    shape, sshape = (p, kvh, ps, d), (p, kvh, ps)
+    q = jnp.asarray(rng.standard_normal((b, h, d)),
+                    jnp.bfloat16 if kind == "bf16" else jnp.float32)
+    if kind == "int8":
+        k, v = (np.asarray(rng.integers(-127, 128, shape), np.int8)
+                for _ in range(2))
+        k[0], v[0] = 127, 127
+        ks, vs = (np.asarray(rng.uniform(0.01, 0.05, sshape), np.float32)
+                  for _ in range(2))
+        ks[0], vs[0] = 1e3, 1e3
+        k, v, ks, vs = map(jnp.asarray, (k, v, ks, vs))
+        got = kops.decode_attention_paged_q8(q, k, v, ks, vs, pt, valid)
+        want = kref.decode_attention_paged_q8_ref(q, k, v, ks, vs, pt,
+                                                  valid)
+        tol = 2e-5
+    else:
+        k, v = (np.asarray(rng.standard_normal(shape), np.float32)
+                for _ in range(2))
+        k[0], v[0] = 1e4, 1e4
+        dt = q.dtype
+        k, v = jnp.asarray(k, dt), jnp.asarray(v, dt)
+        got = kops.decode_attention_paged(q, k, v, pt, valid)
+        want = kref.decode_attention_paged_ref(q, k, v, pt, valid)
+        # bf16 outputs: one rounding of the result either side
+        tol = 2e-5 if kind == "f32" else 8e-3
+    assert got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
 
 
 def test_paged_gather_matches_ring_oracle_exactly():

@@ -1,6 +1,7 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached, at TinyLlama's published widths (B=8 lanes, 32 query / 4
-KV heads, head_dim 64, 2048 cache slots, 16-slot pages).
+KV heads, head_dim 64, 2048 cache slots, 16-slot pages), and the paged
+decode kernel at the benchmark cells' widths.
 
 Interpret mode (every other kernel test) cannot see the TPU's tiling
 rules; the chip's compiler, which is installed here, can.  Each case
@@ -20,9 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
+from repro.kernels import ops as kops
 
 B, H, KV, D, S, PS = 8, 32, 4, 64, 2048, 16
-PAGES = 1 + B * (S // PS)
 
 
 @pytest.fixture(scope="module")
@@ -69,14 +70,21 @@ def _ring(one_chip, dtype, quantized):
         lambda q, k, v, n: da.decode_attention(q, k, v, n), *args)
 
 
-def _paged(one_chip, dtype, quantized):
+def _paged_args(one_chip, dtype, quantized, b=B, h=H, kvh=KV, d=D, w=S // PS):
     st = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
     kv_dt = jnp.int8 if quantized else dtype
-    args = [st((B, H, D), dtype), st((PAGES, KV, PS, D), kv_dt),
-            st((PAGES, KV, PS, D), kv_dt), st((B, S // PS), jnp.int32),
-            st((B,), jnp.int32)]
+    pages = 1 + b * w
+    args = [st((b, h, d), dtype), st((pages, kvh, PS, d), kv_dt),
+            st((pages, kvh, PS, d), kv_dt), st((b, w), jnp.int32),
+            st((b,), jnp.int32)]
     if quantized:
-        args += [st((PAGES, KV, PS), jnp.float32)] * 2
+        args += [st((pages, kvh, PS), jnp.float32)] * 2
+    return args
+
+
+def _paged(one_chip, dtype, quantized, **shape):
+    args = _paged_args(one_chip, dtype, quantized, **shape)
+    if quantized:
         return _compile_text(
             lambda q, k, v, pt, n, ks, vs: da.decode_attention_paged(
                 q, k, v, pt, n, k_scale=ks, v_scale=vs), *args)
@@ -96,6 +104,34 @@ def test_decode_attention_compiles_for_v5e(one_chip, layout, dtype,
                                            quantized):
     build = _ring if layout == "ring" else _paged
     assert "tpu_custom_call" in build(one_chip, dtype, quantized)
+
+
+# the benchmark cells' decode shapes: lanes, query heads, KV heads,
+# head_dim and page-table width (cache_len / 16)
+CELL_SHAPES = {"qwen3-8b-pp3": dict(b=24, h=32, kvh=8, d=128, w=160),
+               "qwen3-0.6b": dict(b=8, h=16, kvh=8, d=128, w=256)}
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_paged_decode_compiles_at_cell_widths(one_chip, cell, quantized):
+    dtype = jnp.float32 if quantized else jnp.bfloat16
+    text = _paged(one_chip, dtype, quantized, **CELL_SHAPES[cell])
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_custom_call_keeps_its_name(one_chip):
+    """The benchmark finds the kernel in a device trace by the name of its
+    HLO instruction, ``%decode_attention_paged.<n>``, which the jitted
+    wrapper in ``kernels/ops.py`` gives it."""
+    args = _paged_args(one_chip, jnp.bfloat16, False,
+                       **CELL_SHAPES["qwen3-8b-pp3"])
+    text = kops.decode_attention_paged.lower(
+        *args, interpret=False).compile().as_text()
+    names = [line.split("=")[0].strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and "=" in line]
+    assert names and all(n.startswith("%decode_attention_paged")
+                         for n in names), names
 
 
 def test_flash_prefill_compiles_for_v5e(one_chip):

@@ -28,9 +28,12 @@ class ArchConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     # --- MoE ---
-    num_experts: int = 0
+    num_experts: int = 0           # the router's width: the whole bank
     experts_per_token: int = 0
-    capacity_factor: float = 1.25
+    # (first id, count) of the experts this chip computes, for a model
+    # whose experts are spread over chips; () holds the whole bank
+    held_experts: Tuple[int, int] = ()
+    capacity_factor: float = 1.25  # training dispatch only
     router_aux_coef: float = 0.01
     # --- recurrent (ssm / hybrid) ---
     rwkv_head_dim: int = 64        # rwkv6 head size
@@ -47,6 +50,15 @@ class ArchConfig:
     dtype: str = "bfloat16"
     source: str = ""
 
+    def __post_init__(self):
+        # a stored config comes back from JSON with a list here
+        object.__setattr__(self, "held_experts", tuple(self.held_experts))
+        first, count = self.expert_range
+        if self.is_moe and not (0 <= first and count > 0
+                                and first + count <= self.num_experts):
+            raise ValueError(f"held_experts {self.held_experts} is not a "
+                             f"range of the {self.num_experts} experts")
+
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // self.num_heads)
@@ -62,6 +74,11 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def expert_range(self) -> Tuple[int, int]:
+        """(first id, count) of the experts held here."""
+        return self.held_experts or (0, self.num_experts)
 
     def param_count(self) -> int:
         """Analytic parameter count (matches init_params; used for roofline

@@ -1,8 +1,9 @@
 """Qwen3-MoE 235B-A22B — 128-expert top-8 mixture of experts.
 
-[hf:Qwen/Qwen3-30B-A3B family card] 94 layers, d_model 4096, 64 heads
-(GQA kv=4), expert d_ff 1536, 128 experts top-8, vocab 151936.
-~235B total / ~22B active parameters.
+[hf:Qwen/Qwen3-235B-A22B config.json] 94 layers, d_model 4096, 64 heads
+(GQA kv=4) of head_dim 128, expert d_ff 1536, 128 experts top-8 with
+renormalised weights, vocab 151936, untied.  ~235B total / ~22B active
+parameters.
 """
 from repro.configs.base import ArchConfig, register
 
@@ -16,7 +17,7 @@ def config() -> ArchConfig:
         d_model=4096,
         num_heads=64,
         num_kv_heads=4,
-        head_dim=64,
+        head_dim=128,
         qk_norm=True,
         d_ff=1536,               # per-expert FFN width
         num_experts=128,
@@ -24,5 +25,5 @@ def config() -> ArchConfig:
         vocab_size=151936,
         rope_theta=1_000_000.0,
         sliding_window=8192,
-        source="hf:Qwen/Qwen3-235B-A22B (via Qwen3-30B-A3B card)",
+        source="hf:Qwen/Qwen3-235B-A22B",
     )

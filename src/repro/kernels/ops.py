@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 from repro.kernels import (conv2d as _conv2d_mod, decode_attention as _da,
                            elementwise as _ew, flash_attention as _fa,
-                           int8_matmul as _i8, matmul as _mm, pool as _pool,
+                           grouped_ffn as _gf, int8_matmul as _i8,
+                           matmul as _mm, pool as _pool,
                            rwkv6_chunk as _rwkv, softmax as _sm)
 
 
@@ -134,6 +135,17 @@ def decode_attention_paged_q8(q, k, v, k_scale, v_scale, page_table,
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
                                       k_scale=k_scale, v_scale=v_scale,
                                       interpret=_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "chunks", "interpret"))
+def grouped_expert_ffn(x, w_gate, w_up, w_down, start, rows, layer, *, tile,
+                       chunks, interpret=None):
+    """Grouped SwiGLU over the held experts of layer ``layer`` of the
+    stacked weights: each expert's weights stream once per chunk of its
+    rows, and only its routed rows are computed (``kernels/grouped_ffn``)."""
+    return _gf.grouped_ffn(x, w_gate, w_up, w_down, start, rows, layer,
+                           tile=tile, chunks=chunks,
+                           interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))

@@ -133,6 +133,24 @@ def decode_attention_paged_q8_ref(q, k_pool, v_pool, k_scale, v_scale,
     return decode_attention_q8_ref(q, k, v, ks, vs, valid_len)
 
 
+def grouped_ffn_ref(x, w_gate, w_up, w_down, start, rows, layer, *,
+                    tile: int):
+    """Oracle of ``kernels/grouped_ffn``: each expert of layer ``layer``
+    applies its SwiGLU to the live rows of its group (block ``start[e]``,
+    ``rows[e]`` rows); zeros on every other row."""
+    w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
+    r = jnp.arange(x.shape[0])
+    out = jnp.zeros(x.shape, jnp.float32)
+    for e in range(w_gate.shape[0]):
+        lo = start[e] * tile
+        g = jnp.dot(x, w_gate[e], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, w_up[e], preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(w_down.dtype)
+        y = jnp.dot(h, w_down[e], preferred_element_type=jnp.float32)
+        out = jnp.where(((r >= lo) & (r < lo + rows[e]))[:, None], y, out)
+    return out.astype(x.dtype)
+
+
 def flash_attention_ref(q, k, v, *, causal=True, window=0):
     """q: (B, S, H, D); k, v: (B, S, KV, D) — full-sequence attention."""
     from repro.models.common import attention_full

@@ -52,11 +52,11 @@ def init_params(cfg: ArchConfig, key, dtype=jnp.float32):
 def param_count(cfg: ArchConfig, active_only: bool = False) -> int:
     n = common.param_count_of(param_template(cfg))
     if active_only and cfg.is_moe:
-        # experts contribute k/E of their FLOPs per token
+        # a token uses k of the E experts, so k/E of those held here
         d, f, L, E, k = (cfg.d_model, cfg.d_ff, cfg.num_layers,
                          cfg.num_experts, cfg.experts_per_token)
-        expert_params = L * E * 3 * d * f
-        n = n - expert_params + L * k * 3 * d * f
+        held = cfg.expert_range[1]
+        n = n - L * held * 3 * d * f + L * k * held * 3 * d * f // E
     return n
 
 

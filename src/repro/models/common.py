@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import zlib
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -41,7 +42,9 @@ class P:
 
 
 def _leaf_key(key, path) -> jax.Array:
-    h = hash(jax.tree_util.keystr(path)) % (2 ** 31)
+    """The leaf's own key: ``key`` folded with a digest of its path that
+    is the same in every process (``hash()`` of a string is not)."""
+    h = zlib.crc32(jax.tree_util.keystr(path).encode()) % (2 ** 31)
     return jax.random.fold_in(key, h)
 
 

@@ -89,6 +89,16 @@ def _qkv(cfg, lp, xq, xkv, prefix=""):
     return q, k, v
 
 
+def _cross_decode(qx, xk, xv):
+    """One decode token's cross-attention against the stored encoder K/V,
+    widened to the query's dtype: `attention_decode` would round an f32
+    query to the bf16 cache, so two queries one f32 ulp apart (a lane of
+    a batch and the same lane alone) could round to different bf16
+    values and move the output by a bf16 step."""
+    return cm.attention_decode(qx, xk.astype(qx.dtype), xv.astype(qx.dtype),
+                               xk.shape[1])
+
+
 def _mlp(cfg, lp, x):
     xn = _ln(x, lp, "mlp_ln")
     h = hint(jax.nn.gelu(xn @ lp["w_in"] + lp["b_in"]), "batch", "seq", "ff")
@@ -247,7 +257,7 @@ def decode_step(cfg: ArchConfig, params, token, cache, pos, *,
         x = x + (a.reshape(b, 1, cfg.q_dim) @ lp["wo"] + lp["bo"])
         xn = _ln(x, lp, "x_ln")
         qx = (xn @ lp["x_wq"] + lp["x_bq"]).reshape(b, 1, cfg.num_heads, hd)
-        ax = cm.attention_decode(qx, xk, xv, xk.shape[1])
+        ax = _cross_decode(qx, xk, xv)
         x = x + (ax.reshape(b, 1, cfg.q_dim) @ lp["x_wo"] + lp["x_bo"])
         x = x + _mlp(cfg, lp, x)
         return x, (ck, cv)
@@ -315,7 +325,7 @@ def decode_step_batch(cfg: ArchConfig, params, token, cache, pos, *,
     def rest(lp, x, xk, xv):
         xn = _ln(x, lp, "x_ln")
         qx = (xn @ lp["x_wq"] + lp["x_bq"]).reshape(b, 1, cfg.num_heads, hd)
-        ax = cm.attention_decode(qx, xk, xv, xk.shape[1])
+        ax = _cross_decode(qx, xk, xv)
         x = x + (ax.reshape(b, 1, cfg.q_dim) @ lp["x_wo"] + lp["x_bo"])
         return x + _mlp(cfg, lp, x)
 

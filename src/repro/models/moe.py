@@ -1,10 +1,23 @@
 """Mixture-of-Experts decoder (qwen3-moe, granite-moe families).
 
-Token-choice top-k routing with sort-based capacity dispatch: tokens are
-argsorted by expert id into an (E, C, d) buffer, each expert runs a dense
-SwiGLU over its slice, and results are combined with the (renormalized)
-router weights.  Overflowing tokens beyond capacity C are dropped (classic
-GShard/Switch semantics, capacity_factor controls the slack).
+Token-choice top-k routing: softmax over the whole bank's router logits
+in f32, the top k, their weights renormalised over the k chosen.
+
+Serving (``prefill``, ``decode_step``, ``decode_step_batch``) is
+dropless and holds a stated range of the bank (``cfg.held_experts``;
+the whole bank when unset), as one chip of an expert-parallel
+deployment would: every token routes over all ``num_experts``, the
+(token, expert) pairs whose expert is held are sorted into per-expert
+groups, and the grouped expert kernel (``kernels/grouped_ffn``) computes
+exactly those rows.  A token's output is its held experts' weighted
+SwiGLU outputs; what absent experts would add is left out.  Nothing
+depends on a token's batch neighbours.
+
+Training (``forward``/``loss_fn``) holds the whole bank and keeps
+sort-based capacity dispatch: tokens are argsorted by expert id into an
+(E, C, d) buffer, each expert runs a dense SwiGLU over its slice, and
+tokens beyond capacity C are dropped (GShard/Switch semantics,
+``capacity_factor`` sets the slack).
 
 Sharding: the expert dim carries the logical axis ``experts`` -> the mesh
 ``model`` axis when E divides it (expert parallelism; the (T,d)->(E,C,d)
@@ -29,15 +42,27 @@ from repro.models.common import P
 from repro.sharding_hints import hint
 
 
+# device-side routing counters of the serving path, in the order of the
+# ``stats`` vector its steps return: routed (token, held expert) rows
+# computed, layer calls x held experts (summed), and the most rows one
+# held expert took in one call (a maximum)
+ROUTING_STATS = ("moe.rows_here", "moe.expert_calls", "moe.rows_max")
+MAX_TILE = 256     # rows of one block of an expert's group, at most
+EXPERTS = ("we_gate", "we_up", "we_down")
+
+
 def param_template(cfg: ArchConfig):
-    L, d, f, E = cfg.num_layers, cfg.d_model, cfg.d_ff, cfg.num_experts
+    """The router spans the whole bank (``num_experts`` outputs); the
+    expert weights only the experts held here."""
+    L, d, f = cfg.num_layers, cfg.d_model, cfg.d_ff
+    E = cfg.expert_range[1]
     t = {
         "embed": P((cfg.vocab_size, d), ("tp_vocab", "fsdp"), "embed"),
         "final_ln": P((d,), (None,), "zeros"),
         "layers": {
             **tfm._attn_template(cfg, L),
             "ln2": P((L, d), (None, None), "zeros"),
-            "router": P((L, d, E), (None, "fsdp", None)),
+            "router": P((L, d, cfg.num_experts), (None, "fsdp", None)),
             "we_gate": P((L, E, d, f), (None, "experts", "fsdp", "tp_ff")),
             "we_up": P((L, E, d, f), (None, "experts", "fsdp", "tp_ff")),
             "we_down": P((L, E, f, d), (None, "experts", "tp_ff", "fsdp")),
@@ -264,6 +289,73 @@ def moe_ffn_local(cfg: ArchConfig, lp, x) -> Tuple[jax.Array, jax.Array]:
     return out, aux
 
 
+def _tile(tokens: int, dtype) -> int:
+    """Rows of one block of an expert's group: a whole group of a batch
+    of up to ``MAX_TILE`` tokens (an expert takes each token at most
+    once), in whole sublane tiles."""
+    from repro.kernels.grouped_ffn import sublane_rows
+    sub = sublane_rows(dtype)
+    return min(-(-tokens // sub) * sub, MAX_TILE)
+
+
+def _grouped_ffn(x, wg, wu, wd, start, rows, layer, *, tile, chunks):
+    """The grouped expert kernel on a TPU, its jnp oracle elsewhere."""
+    if jax.default_backend() == "tpu":
+        from repro.kernels import ops as kops
+        return kops.grouped_expert_ffn(x, wg, wu, wd, start, rows, layer,
+                                       tile=tile, chunks=chunks)
+    from repro.kernels.ref import grouped_ffn_ref
+    return grouped_ffn_ref(x, wg, wu, wd, start, rows, layer, tile=tile)
+
+
+def moe_ffn_held(cfg: ArchConfig, lp, x, layer=None):
+    """The serving path's expert layer, dropless: x (B, S, d) ->
+    (the held experts' part of the output (B, S, d), routing counters
+    (3,) int32 in ``ROUTING_STATS`` order).
+
+    Routing is over the whole bank; the (token, choice) pairs whose
+    expert is held are laid out by expert in groups of whole ``tile``-row
+    blocks (one spare block last) and run through the grouped kernel,
+    which computes each group's rows only.  Pairs of absent experts add
+    nothing.  With ``layer``, ``lp``'s expert weights are the whole
+    stack of layers, which the kernel reads in place at that index."""
+    if layer is None:
+        lp = {**lp, **{k: lp[k][None] for k in EXPERTS}}
+        layer = 0
+    b, s, d = x.shape
+    T, k = b * s, cfg.experts_per_token
+    first, n_held = cfg.expert_range
+    tile = _tile(T, x.dtype)
+    n_rows = ((T * min(k, n_held)) // tile + n_held + 1) * tile
+    xf = x.reshape(T, d)
+    with jax.named_scope("moe_route"):
+        top_p, top_e, _ = _route(cfg, xf, lp["router"])
+        local = top_e.reshape(-1) - first                     # (T*k,)
+        held = (local >= 0) & (local < n_held)
+        onehot = jax.nn.one_hot(jnp.where(held, local, -1), n_held,
+                                dtype=jnp.int32)              # (T*k, E_h)
+        rows = onehot.sum(0)
+        rank = (jnp.cumsum(onehot, 0) * onehot).sum(-1) - 1
+        blocks = -(-rows // tile)
+        start = jnp.cumsum(blocks) - blocks
+        dest = jnp.where(held, start[jnp.clip(local, 0, n_held - 1)] * tile
+                         + rank, n_rows)
+        # each row of the layout names its token (T: none, a zero row)
+        src = jnp.full((n_rows,), T, jnp.int32).at[dest].set(
+            jnp.arange(T * k, dtype=jnp.int32) // k, mode="drop")
+        xbuf = jnp.concatenate([xf, jnp.zeros((1, d), x.dtype)])[src]
+    with jax.named_scope("moe_experts"):
+        y = _grouped_ffn(xbuf, lp["we_gate"], lp["we_up"], lp["we_down"],
+                         start, rows, layer, tile=tile, chunks=-(-T // tile))
+    with jax.named_scope("moe_route"):
+        yk = jnp.take(y, jnp.minimum(dest, n_rows - 1), axis=0)
+        w = jnp.where(held, top_p.reshape(-1), 0.0)[:, None]
+        out = jnp.where(held[:, None], yk.astype(jnp.float32) * w, 0.0)
+        out = out.reshape(T, k, d).sum(1).astype(x.dtype).reshape(b, s, d)
+    stats = jnp.stack([rows.sum(), jnp.int32(n_held), rows.max()])
+    return out, stats.astype(jnp.int32)
+
+
 def moe_ffn(cfg: ArchConfig, lp, x) -> Tuple[jax.Array, jax.Array]:
     """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
 
@@ -272,6 +364,9 @@ def moe_ffn(cfg: ArchConfig, lp, x) -> Tuple[jax.Array, jax.Array]:
     (replicated experts).
     """
     from repro.sharding_hints import get_rule
+    if cfg.expert_range != (0, cfg.num_experts):
+        raise ValueError("training dispatch holds the whole expert bank; "
+                         f"got held_experts={cfg.held_experts}")
     impl = get_rule("moe_impl", "dense")
     if impl == "a2a":
         return moe_ffn_a2a(cfg, lp, x)
@@ -308,6 +403,29 @@ def loss_fn(cfg: ArchConfig, params, batch, *, window: int = 0):
     return loss, {"loss": loss, "xent": xent, "aux": aux}
 
 
+def _moe_serve(cfg: ArchConfig, lp, experts, x):
+    """The expert block of layer ``lp["layer"]`` of a serving scan."""
+    with jax.named_scope("mlp"):
+        return moe_ffn_held(cfg, {**lp, **experts},
+                            cm.rms_norm(x, lp["ln2"], cfg.norm_eps),
+                            layer=lp["layer"])
+
+
+def _scanned(params):
+    """A serving scan's per-layer inputs, and the expert weights it leaves
+    whole: the grouped kernel reads them from the stack, where a scanned
+    slice would be copied every layer before the kernel could read it."""
+    layers = params["layers"]
+    rest = {k: v for k, v in layers.items() if k not in EXPERTS}
+    rest["layer"] = jnp.arange(layers["ln2"].shape[0], dtype=jnp.int32)
+    return rest, {k: layers[k] for k in EXPERTS}
+
+
+def _fold_stats(stats):
+    """Per-layer routing counters (L, 3) -> one step's (3,)."""
+    return jnp.concatenate([stats[:, :2].sum(0), stats[:, 2:].max(0)])
+
+
 init_cache = tfm.init_cache
 cache_spec = tfm.cache_spec
 cache_to_kv_dtype = tfm.cache_to_kv_dtype
@@ -319,118 +437,70 @@ def decode_step(cfg: ArchConfig, params, token, cache, pos, *,
                 window: int = 0):
     # xs/ys cache streaming, bksd layout (see transformer.decode_step)
     x = tfm._embed(cfg, params, token)
+    layers, experts = _scanned(params)
 
     def layer(x, scanned):
         lp, ck, cv = scanned
         a, ck, cv = tfm.attn_decode(cfg, lp, x, ck, cv, pos, window=window)
         x = x + a
-        m, _ = _moe_block(cfg, lp, x)
+        m, _ = _moe_serve(cfg, lp, experts, x)
         return x + m, (ck, cv)
 
-    x, (ck, cv) = lax.scan(layer, x, (params["layers"], cache["k"],
-                                      cache["v"]))
+    x, (ck, cv) = lax.scan(layer, x, (layers, cache["k"], cache["v"]))
     return tfm._logits(cfg, params, x), {"k": ck, "v": cv}
 
 
 def decode_step_batch(cfg: ArchConfig, params, tokens, cache, pos, *,
-                      window: int = 0, attn_backend=None):
+                      window: int = 0, attn_backend=None,
+                      with_stats: bool = False):
     """Lane-major decode: tokens (B, 1); pos (B,) per-lane (see
-    transformer.decode_step_batch).  The MoE block routes all B lane
-    tokens through one dispatch instead of B single-token dispatches.
-    An int8 cache (``k_scale`` leaf) takes the quantizing-write + q8
-    attention path, same as the dense transformer; a paged cache
-    (``page_table`` leaf) streams page pools through the scan."""
-    x = tfm._embed(cfg, params, tokens)
-    if "page_table" in cache:
-        return _decode_step_batch_paged(cfg, params, x, cache, pos,
-                                        window=window,
-                                        attn_backend=attn_backend)
-    quantized = "k_scale" in cache
-
-    if quantized:
-        def layer(x, scanned):
-            lp, ck, cv, cks, cvs = scanned
-            a, ck, cv, cks, cvs = tfm.attn_decode_batch(
-                cfg, lp, x, ck, cv, pos, window=window,
-                backend=attn_backend, cks=cks, cvs=cvs)
-            x = x + a
-            m, _ = _moe_block(cfg, lp, x)
-            return x + m, (ck, cv, cks, cvs)
-
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer, x, (params["layers"], cache["k"], cache["v"],
-                       cache["k_scale"], cache["v_scale"]))
-        return tfm._logits(cfg, params, x), {"k": ck, "v": cv,
-                                             "k_scale": cks,
-                                             "v_scale": cvs}
+    transformer.decode_step_batch), the held experts' dropless layer in
+    place of the dense MLP.  The cache's leaves pick the attention path:
+    ring or paged (``page_table``), float or int8 (scale leaves).  With
+    ``with_stats`` also returns the step's routing counters
+    (``ROUTING_STATS``)."""
+    with jax.named_scope("embed"):
+        x = tfm._embed(cfg, params, tokens)
+    pt = cache.get("page_table")
+    names = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages") \
+        if pt is not None else ("k", "v", "k_scale", "v_scale")
+    names = tuple(n for n in names if n in cache)
+    layers, experts = _scanned(params)
 
     def layer(x, scanned):
-        lp, ck, cv = scanned
-        a, ck, cv = tfm.attn_decode_batch(cfg, lp, x, ck, cv, pos,
-                                          window=window,
-                                          backend=attn_backend)
+        lp, ck, cv, *scales = scanned
+        kw = dict(cks=scales[0], cvs=scales[1]) if scales else {}
+        a, *kv = tfm.attn_decode_batch(cfg, lp, x, ck, cv, pos,
+                                       window=window, backend=attn_backend,
+                                       page_table=pt, **kw)
         x = x + a
-        m, _ = _moe_block(cfg, lp, x)
-        return x + m, (ck, cv)
+        m, stats = _moe_serve(cfg, lp, experts, x)
+        return x + m, (tuple(kv), stats)
 
-    x, (ck, cv) = lax.scan(layer, x, (params["layers"], cache["k"],
-                                      cache["v"]))
-    return tfm._logits(cfg, params, x), {"k": ck, "v": cv}
-
-
-def _decode_step_batch_paged(cfg: ArchConfig, params, x, cache, pos, *,
-                             window: int = 0, attn_backend=None):
-    """Paged scan bodies (see transformer._decode_step_batch_paged) with
-    the MoE block in place of the dense MLP."""
-    pt = cache["page_table"]
-    quantized = "k_scale_pages" in cache
-
-    if quantized:
-        def layer(x, scanned):
-            lp, ck, cv, cks, cvs = scanned
-            a, ck, cv, cks, cvs = tfm.attn_decode_batch(
-                cfg, lp, x, ck, cv, pos, window=window,
-                backend=attn_backend, cks=cks, cvs=cvs, page_table=pt)
-            x = x + a
-            m, _ = _moe_block(cfg, lp, x)
-            return x + m, (ck, cv, cks, cvs)
-
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer, x, (params["layers"], cache["k_pages"],
-                       cache["v_pages"], cache["k_scale_pages"],
-                       cache["v_scale_pages"]))
-        return tfm._logits(cfg, params, x), {
-            "k_pages": ck, "v_pages": cv, "k_scale_pages": cks,
-            "v_scale_pages": cvs, "page_table": pt}
-
-    def layer(x, scanned):
-        lp, ck, cv = scanned
-        a, ck, cv = tfm.attn_decode_batch(cfg, lp, x, ck, cv, pos,
-                                          window=window,
-                                          backend=attn_backend,
-                                          page_table=pt)
-        x = x + a
-        m, _ = _moe_block(cfg, lp, x)
-        return x + m, (ck, cv)
-
-    x, (ck, cv) = lax.scan(layer, x, (params["layers"], cache["k_pages"],
-                                      cache["v_pages"]))
-    return tfm._logits(cfg, params, x), {"k_pages": ck, "v_pages": cv,
-                                         "page_table": pt}
+    x, (kv, stats) = lax.scan(layer, x,
+                              (layers, *(cache[n] for n in names)))
+    with jax.named_scope("head"):
+        logits = tfm._logits(cfg, params, x)
+    cache = {**cache, **dict(zip(names, kv))}
+    if with_stats:
+        return logits, cache, _fold_stats(stats)
+    return logits, cache
 
 
 def prefill(cfg: ArchConfig, params, tokens, cache_len: int, *,
-            window: int = 0, cache_dtype=jnp.bfloat16):
+            window: int = 0, cache_dtype=jnp.bfloat16,
+            with_stats: bool = False):
     b, s = tokens.shape
     x = tfm._embed(cfg, params, tokens)
+    layers, experts = _scanned(params)
 
     def layer(x, lp):
         a, (kk, vv) = tfm.attn(cfg, lp, x, window=window)
         x = x + a
-        m, _ = _moe_block(cfg, lp, x)
-        return x + m, (kk.astype(cache_dtype), vv.astype(cache_dtype))
+        m, stats = _moe_serve(cfg, lp, experts, x)
+        return x + m, (kk.astype(cache_dtype), vv.astype(cache_dtype), stats)
 
-    x, (ks, vs) = lax.scan(layer, x, params["layers"])
+    x, (ks, vs, stats) = lax.scan(layer, x, layers)
     cache = init_cache(cfg, b, cache_len, cache_dtype)
     keep = min(s, cache_len)
     # (L, B, S, KV, D) stacked attn outputs -> bksd (L, B, KV, S, D)
@@ -443,4 +513,5 @@ def prefill(cfg: ArchConfig, params, tokens, cache_len: int, *,
     if s > cache_len:
         ck = jnp.roll(ck, s % cache_len, axis=3)
         cv = jnp.roll(cv, s % cache_len, axis=3)
-    return tfm._logits(cfg, params, x), {"k": ck, "v": cv}
+    out = (tfm._logits(cfg, params, x), {"k": ck, "v": cv})
+    return (*out, _fold_stats(stats)) if with_stats else out

@@ -209,6 +209,12 @@ class ContinuousBatchingScheduler:
                 not hasattr(self.mod, "decode_step_batch"):
             decode_mode = "vmapped"
         self.decode_mode = decode_mode
+        # a family whose steps count their routing (MoE) keeps the
+        # counters on the device, in the state, and they reach the
+        # registry at the retirement fetch: no sync of their own
+        self._routing = getattr(self.mod, "ROUTING_STATS", ()) \
+            if decode_mode == "batched" else ()
+        self._routing_seen = np.zeros(len(self._routing), np.int64)
         # kv_dtype: None keeps the legacy f32 cache (token-identical to
         # the vmapped reference); 'bf16' halves KV bytes; 'int8' quarters
         # them via the per-slot-scale quantized cache + *_q8 attention.
@@ -355,7 +361,9 @@ class ContinuousBatchingScheduler:
         if self._paged:
             cache_kw.update(page_size=self.page_size,
                             num_pages=self.num_pages)
-        return {
+        routing = {"routing": jnp.zeros((len(self._routing),), jnp.int32)} \
+            if self._routing else {}
+        return {**routing,
             "tokens": jnp.zeros((b, 1), jnp.int32),
             "pos": jnp.zeros((b,), jnp.int32),
             "temp": jnp.zeros((b,), jnp.float32),
@@ -384,18 +392,40 @@ class ContinuousBatchingScheduler:
 
     def _decode_lanes(self, params, tokens, cache, pos):
         """One decode step for every lane: the lane-major batched path
-        (default) or the vmapped B=1 reference."""
+        (default) or the vmapped B=1 reference.  Returns the last logits,
+        the cache and the step's routing counters (None where the family
+        keeps none)."""
         if self.decode_mode == "batched":
-            lg, cache = self.mod.decode_step_batch(
+            kw = {"with_stats": True} if self._routing else {}
+            lg, cache, *stats = self.mod.decode_step_batch(
                 self.cfg, params, tokens, cache, pos,
-                attn_backend=self.attn_backend)
-            return lg.reshape(self.max_slots, -1,
-                              self.cfg.vocab_size)[:, -1], cache
-        return self._decode_slots(params, tokens, cache, pos)
+                attn_backend=self.attn_backend, **kw)
+            return (lg.reshape(self.max_slots, -1,
+                               self.cfg.vocab_size)[:, -1], cache,
+                    stats[0] if stats else None)
+        return (*self._decode_slots(params, tokens, cache, pos), None)
+
+    def _prefill(self, params, prompt):
+        """B=1 prefill of ``prompt`` into a float ring row: (logits,
+        cache row, routing counters or None)."""
+        kw = {"with_stats": True} if self._routing else {}
+        logits, cache1, *stats = self.mod.prefill(
+            self.cfg, params, prompt, self._prefill_len,
+            cache_dtype=jnp.float32, **kw)
+        return logits, cache1, stats[0] if stats else None
+
+    def _count_routing(self, state, stats) -> Dict[str, Any]:
+        """The state's routing counters after one more call's: sums, and
+        a maximum last."""
+        if stats is None:
+            return {}
+        r = state["routing"]
+        return {"routing": jnp.concatenate([r[:-1] + stats[:-1],
+                                            jnp.maximum(r[-1:], stats[-1:])])}
 
     def _step(self, params, state):
-        last, cache = self._decode_lanes(params, state["tokens"],
-                                         state["cache"], state["pos"])
+        last, cache, stats = self._decode_lanes(
+            params, state["tokens"], state["cache"], state["pos"])
         with jax.named_scope("sample"):
             key, sub = jax.random.split(state["key"])
             nxt = _sample(sub, last, state["temp"])
@@ -412,17 +442,16 @@ class ContinuousBatchingScheduler:
             # >= 0).
             stop_hit = write & (nxt[:, None] == state["stop"]).any(axis=-1)
         return {
+            **state,
             "tokens": jnp.where(write[:, None], nxt[:, None],
                                 state["tokens"]),
             "pos": state["pos"] + write.astype(jnp.int32),
-            "temp": state["temp"],
             "active": write & ~stop_hit,
-            "budget": state["budget"],
             "out_buf": out_buf,
             "out_len": state["out_len"] + write.astype(jnp.int32),
-            "stop": state["stop"],
             "key": key,
             "cache": cache,
+            **self._count_routing(state, stats),
         }
 
     def _deactivate(self, state, slot):
@@ -435,9 +464,7 @@ class ContinuousBatchingScheduler:
         """Prefill one prompt (B=1), sample its first token on device, and
         splice cache row + lane state into the live batch."""
         del plen  # static: selects the compiled specialization
-        logits, cache1 = self.mod.prefill(self.cfg, params, prompt,
-                                          self._prefill_len,
-                                          cache_dtype=jnp.float32)
+        logits, cache1, stats = self._prefill(params, prompt)
         # quantize/cast AFTER the float prefill so admission pays the
         # conversion once, and the spliced row matches the live layout
         cache1 = self.mod.cache_to_kv_dtype(self.cfg, cache1, self.kv_dtype)
@@ -449,6 +476,7 @@ class ContinuousBatchingScheduler:
         # the first sampled token can itself be a stop token
         hit = (first == stop_row).any()
         return {
+            **state,
             "tokens": state["tokens"].at[slot, 0].set(first),
             "pos": state["pos"].at[slot].set(prompt.shape[1]),
             "temp": state["temp"].at[slot].set(temp),
@@ -461,6 +489,7 @@ class ContinuousBatchingScheduler:
             "stop": state["stop"].at[slot].set(stop_row),
             "key": key,
             "cache": cache,
+            **self._count_routing(state, stats),
         }
 
     # -- paged jitted programs (page table updates, COW, admission) ----------
@@ -473,9 +502,7 @@ class ContinuousBatchingScheduler:
         Same PRNG discipline as :meth:`_admit` (one split, first token
         sampled from the last prefill logits)."""
         del plen  # static: selects the compiled specialization
-        logits, cache1 = self.mod.prefill(self.cfg, params, prompt,
-                                          self._prefill_len,
-                                          cache_dtype=jnp.float32)
+        logits, cache1, stats = self._prefill(params, prompt)
         cache1 = self.mod.cache_to_kv_dtype(self.cfg, cache1, self.kv_dtype)
         key, sub = jax.random.split(state["key"])
         first = _sample(sub, logits[:, -1], temp[None])[0]
@@ -485,6 +512,7 @@ class ContinuousBatchingScheduler:
         cap = self.max_new_cap
         hit = (first == stop_row).any()
         return {
+            **state,
             "tokens": state["tokens"].at[slot, 0].set(first),
             "pos": state["pos"].at[slot].set(prompt.shape[1]),
             "temp": state["temp"].at[slot].set(temp),
@@ -497,6 +525,7 @@ class ContinuousBatchingScheduler:
             "stop": state["stop"].at[slot].set(stop_row),
             "key": key,
             "cache": cache,
+            **self._count_routing(state, stats),
         }
 
     def _suffix_step(self, params, state, tok, slot, pos_scalar):
@@ -516,9 +545,10 @@ class ContinuousBatchingScheduler:
         key trajectory matches the ring scheduler exactly."""
         tokens = state["tokens"].at[slot, 0].set(tok)
         pos = state["pos"].at[slot].set(pos_scalar)
-        last, cache = self._decode_lanes(params, tokens, state["cache"],
-                                         pos)
-        return last[slot], {**state, "cache": cache}
+        last, cache, stats = self._decode_lanes(params, tokens,
+                                                state["cache"], pos)
+        return last[slot], {**state, "cache": cache,
+                            **self._count_routing(state, stats)}
 
     def _finalize_admit(self, state, logits, slot, temp, budget, plen,
                         stop_row):
@@ -530,6 +560,7 @@ class ContinuousBatchingScheduler:
         cap = self.max_new_cap
         hit = (first == stop_row).any()
         return {
+            **state,
             "tokens": state["tokens"].at[slot, 0].set(first),
             "pos": state["pos"].at[slot].set(plen),
             "temp": state["temp"].at[slot].set(temp),
@@ -541,7 +572,6 @@ class ContinuousBatchingScheduler:
             "out_len": state["out_len"].at[slot].set(1),
             "stop": state["stop"].at[slot].set(stop_row),
             "key": key,
-            "cache": state["cache"],
         }
 
     def _set_pt_row(self, state, slot, row):
@@ -582,6 +612,18 @@ class ContinuousBatchingScheduler:
         with Span("pt_update", self._tracer, slot=slot):
             self.state = fn(self.state, *args)
         self.metrics.counter("sched.pt_updates").inc()
+
+    def _record_routing(self, totals) -> None:
+        """Fold the device's routing counters, fetched with a retirement,
+        into the registry: the sums as counters (by their change since
+        the last fetch, modulo the device's 32 bits), the maximum as a
+        gauge."""
+        totals = np.asarray(totals, np.int64)
+        for name, now, seen in zip(self._routing[:-1], totals[:-1],
+                                   self._routing_seen[:-1]):
+            self.metrics.counter(name).inc(int((now - seen) % 2 ** 32))
+        self._routing_seen = totals
+        self.metrics.gauge(self._routing[-1]).set(int(totals[-1]))
 
     def _rt(self, uid: int):
         """The request's trace row, or None when telemetry is off."""
@@ -1117,9 +1159,14 @@ class ContinuousBatchingScheduler:
                 # duration is real device catch-up time, not dispatch cost
                 with Span("retire_fetch", self._tracer, uid=req.uid,
                           slot=slot):
-                    row, n = jax.device_get((self.state["out_buf"][slot],
-                                             self.state["out_len"][slot]))
+                    row, n, *routing = jax.device_get(
+                        (self.state["out_buf"][slot],
+                         self.state["out_len"][slot],
+                         *([self.state["routing"]] if self._routing
+                           else [])))
                 self.host_syncs += 1
+                if routing:
+                    self._record_routing(routing[0])
             n = int(n)
             produced = [int(t) for t in row[:n]]
             req.output.extend(produced)

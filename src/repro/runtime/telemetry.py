@@ -61,6 +61,11 @@ timestamps measure *dispatch*, not device completion:
   step latency; a tick that merely enqueues can be microseconds while
   the device still chews.
 
+Routing counters of a mixture-of-experts family (``moe.rows_here``,
+``moe.expert_calls``: counters; ``moe.rows_max``: a gauge) are summed on
+the device, in the scheduler's state, and reach the registry at the
+retirement fetch, which fetches them with the request's tokens.
+
 None of the above adds a device→host transfer: telemetry-on and
 telemetry-off schedulers make byte-identical device traffic (guarded
 by ``tests/test_telemetry.py``).  The profiler spans are host time too;

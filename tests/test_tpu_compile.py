@@ -1,7 +1,8 @@
 """Compile the main path's Pallas kernels for a TPU v5e that is described,
 not attached, at TinyLlama's published widths (B=8 lanes, 32 query / 4
 KV heads, head_dim 64, 2048 cache slots, 16-slot pages), and the paged
-decode kernel at the benchmark cells' widths.
+decode kernel and the grouped expert kernel at the benchmark cells'
+widths.
 
 Interpret mode (every other kernel test) cannot see the TPU's tiling
 rules; the chip's compiler, which is installed here, can.  Each case
@@ -109,7 +110,9 @@ def test_decode_attention_compiles_for_v5e(one_chip, layout, dtype,
 # the benchmark cells' decode shapes: lanes, query heads, KV heads,
 # head_dim and page-table width (cache_len / 16)
 CELL_SHAPES = {"qwen3-8b-pp3": dict(b=24, h=32, kvh=8, d=128, w=160),
-               "qwen3-0.6b": dict(b=8, h=16, kvh=8, d=128, w=256)}
+               "qwen3-0.6b": dict(b=8, h=16, kvh=8, d=128, w=256),
+               "qwen3-235b-a22b-ep16": dict(b=64, h=64, kvh=4, d=128,
+                                            w=160)}
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -131,6 +134,30 @@ def test_paged_decode_custom_call_keeps_its_name(one_chip):
     names = [line.split("=")[0].strip() for line in text.splitlines()
              if "tpu_custom_call" in line and "=" in line]
     assert names and all(n.startswith("%decode_attention_paged")
+                         for n in names), names
+
+
+@pytest.mark.parametrize("tokens,tile", [(64, 64), (2048, 256)],
+                         ids=["decode", "prefill"])
+def test_grouped_expert_ffn_compiles_at_cell_widths(one_chip, tokens, tile):
+    """The held experts of the MoE cell (12 layers of 8 experts, d 4096,
+    width 1536, top 8) for a decode batch of 64 lanes and a 2048-token
+    prompt, with the layout the serving layer gives them; the kernel
+    keeps the name
+    ``%grouped_expert_ffn.<n>`` that the benchmark reads from traces."""
+    layers, e, d, f = 12, 8, 4096, 1536
+    n = ((tokens * 8) // tile + e + 1) * tile
+    st = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    args = [st((n, d), jnp.bfloat16), st((layers, e, d, f), jnp.bfloat16),
+            st((layers, e, d, f), jnp.bfloat16),
+            st((layers, e, f, d), jnp.bfloat16),
+            st((e,), jnp.int32), st((e,), jnp.int32), st((), jnp.int32)]
+    text = kops.grouped_expert_ffn.lower(
+        *args, tile=tile, chunks=-(-tokens // tile),
+        interpret=False).compile().as_text()
+    names = [line.split("=")[0].strip() for line in text.splitlines()
+             if "tpu_custom_call" in line and "=" in line]
+    assert names and all(n.split()[-1].startswith("%grouped_expert_ffn")
                          for n in names), names
 
 

@@ -484,44 +484,47 @@ def _decode_attn_pallas_q8_b(q, k_cache, v_cache, valid_len, *,
 
 
 def _decode_attn_paged_ref_b(q, k_cache, v_cache, valid_len, *,
-                             page_table=None, interpret=None):
+                             page_table=None, layer=None, interpret=None):
     """Page pool + per-lane page table: the gather-then-ring jnp oracle."""
     del interpret
     from repro.kernels.ref import decode_attention_paged_ref
     out = decode_attention_paged_ref(q[:, 0], k_cache, v_cache, page_table,
-                                     valid_len)
+                                     valid_len, layer)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_paged_b(q, k_cache, v_cache, valid_len, *, page_table=None,
-                         interpret=None):
+                         layer=None, interpret=None):
     """Page pool + per-lane page table: flash-decode that copies each
     lane's valid pages through its page-table row."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged(q[:, 0], k_cache, v_cache, page_table,
-                                      valid_len, interpret=interpret)
+                                      valid_len, layer=layer,
+                                      interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_paged_ref_q8_b(q, k_cache, v_cache, valid_len, *,
                                 k_scale=None, v_scale=None, page_table=None,
-                                interpret=None):
+                                layer=None, interpret=None):
     """Paged int8 pools + per-slot scale pools: the jnp oracle."""
     del interpret
     from repro.kernels.ref import decode_attention_paged_q8_ref
     out = decode_attention_paged_q8_ref(q[:, 0], k_cache, v_cache, k_scale,
-                                        v_scale, page_table, valid_len)
+                                        v_scale, page_table, valid_len,
+                                        layer)
     return out[:, None].astype(q.dtype)
 
 
 def _decode_attn_paged_q8_b(q, k_cache, v_cache, valid_len, *, k_scale=None,
-                            v_scale=None, page_table=None, interpret=None):
+                            v_scale=None, page_table=None, layer=None,
+                            interpret=None):
     """Paged int8 pools: flash-decode, page-table-indirected payload and
     scale DMA + in-kernel dequant."""
     from repro.kernels import ops as kops
     out = kops.decode_attention_paged_q8(q[:, 0], k_cache, v_cache, k_scale,
                                          v_scale, page_table, valid_len,
-                                         interpret=interpret)
+                                         layer=layer, interpret=interpret)
     return out[:, None].astype(q.dtype)
 
 

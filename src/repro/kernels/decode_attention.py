@@ -240,16 +240,18 @@ def _pad_last(x, n):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, n - x.shape[-1])])
 
 
-def _paged_kernel(valid_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest, scale, ps,
-                  ppb, w, quantized, qk_dtype):
+def _paged_kernel(valid_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+                  scale, ps, ppb, w, quantized, qk_dtype):
+    # K/V are read at the given layer of the stacked pools; the scale rows
+    # come as that layer's pool alone (see the wrapper)
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, slot_ref,
          m_ref, l_ref, acc_ref) = rest
-        pairs = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
-                 (vs_hbm, vs_buf))
+        pairs = ((k_hbm, k_buf, True), (v_hbm, v_buf, True),
+                 (ks_hbm, ks_buf, False), (vs_hbm, vs_buf, False))
     else:
         (o_ref, k_buf, v_buf, sem, slot_ref, m_ref, l_ref, acc_ref) = rest
-        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
+        pairs = ((k_hbm, k_buf, True), (v_hbm, v_buf, True))
     lane = pl.program_id(0)
     n_lanes = pl.num_programs(0)
     kvh, d = k_buf.shape[2], k_buf.shape[4]
@@ -266,7 +268,8 @@ def _paged_kernel(valid_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest, scale, ps,
 
         def page(j, carry):
             src_page = pt_ref[b, first + j]
-            for src, dst in pairs:
+            for src, dst, stacked in pairs:
+                src = src.at[layer_ref[0]] if stacked else src
                 cp = pltpu.make_async_copy(src.at[src_page], dst.at[slot, j],
                                            sem.at[slot])
                 if wait:
@@ -350,7 +353,7 @@ def _paged_kernel(valid_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest, scale, ps,
 
 def decode_attention_paged(q, k, v, page_table, valid_len, *,
                            interpret: bool = False, k_scale=None,
-                           v_scale=None):
+                           v_scale=None, layer=None):
     """Flash-decode against a paged KV pool.
 
     q: (B, H, D); k, v: page pools (P, KV, ps, D) (``P`` physical pages
@@ -362,6 +365,14 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
     With ``k_scale``/``v_scale`` ((P, KV, ps) fp32 scale pools) the
     payload pools are int8, dequantized per slot inside the block loop.
 
+    With ``layer`` (an int32 scalar) ``k``/``v`` and the scales are the
+    pools of every layer stacked, (L, P, KV, ps, D) and (L, P, KV, ps),
+    and the kernel reads layer ``layer`` of them: the index is a scalar
+    prefetch and each page DMA reads ``pool[layer, page]`` from HBM, so a
+    layer scan that carries the whole stack copies nothing before the
+    call.  Heads narrower than 128 lanes are padded, and then only the
+    layer's pools are taken and padded.
+
     One grid step per lane; each lane reads its ``ceil(valid_len / ps)``
     valid pages once, one DMA per page of all KV heads, in blocks of
     pages double-buffered across blocks and lanes.
@@ -369,21 +380,29 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
     quantized = k_scale is not None
     if quantized:
         assert v_scale is not None
+    pools = [k, v] + ([k_scale, v_scale] if quantized else [])
     b, h, d = q.shape
-    n_pool, kvh, ps = k.shape[:3]
-    w = page_table.shape[1]
-    g = h // kvh
     # narrower heads are zero-padded to whole lane rows: zero query lanes
     # add nothing to the scores, zero value lanes are cut from the output
     dp = _round_up_lanes(d)
+    if layer is None or dp != d:
+        if layer is not None:
+            pools = [x[layer] for x in pools]
+        pools, layer = [x[None] for x in pools], 0
+    k, v = pools[:2]
+    n_pool, kvh, ps = k.shape[1:4]
+    w = page_table.shape[1]
+    g = h // kvh
     qg = _pad_last(q.reshape(b, kvh, g, d), dp)
     operands = [qg, _pad_last(k, dp), _pad_last(v, dp)]
     if quantized:
-        # the scales of a page's KV heads as one padded row
+        # the scales of a page's KV heads as one padded row: the DMA of a
+        # (KV, ps) fp32 slab narrower than 128 lanes does not compile, so
+        # the layer's scale pools are taken and laid out here
         sp = _round_up_lanes(kvh * ps)
-        operands += [_pad_last(x.astype(jnp.float32)
+        operands += [_pad_last(x[layer].astype(jnp.float32)
                                 .reshape(n_pool, 1, kvh * ps), sp)
-                     for x in (k_scale, v_scale)]
+                     for x in pools[2:]]
     ppb = _pages_per_block(kvh, ps, d, w, k.dtype.itemsize, quantized)
     # bf16 queries against bf16 keys: the bf16 MXU products are exact, as
     # an f32 upcast's would be
@@ -393,7 +412,8 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
         jnp.asarray(valid_len, jnp.int32).reshape(-1), (b,))
 
     hbm = pl.BlockSpec(memory_space=pltpu.HBM)
-    lane_spec = pl.BlockSpec((1, kvh, g, dp), lambda bi, vr, pr: (bi, 0, 0, 0))
+    lane_spec = pl.BlockSpec((1, kvh, g, dp),
+                             lambda bi, vr, pr, lr: (bi, 0, 0, 0))
     scratch = [pltpu.VMEM((2, ppb, kvh, ps, dp), k.dtype),
                pltpu.VMEM((2, ppb, kvh, ps, dp), v.dtype)]
     if quantized:
@@ -409,7 +429,7 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
                           ppb=ppb, w=w, quantized=quantized,
                           qk_dtype=qk_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[lane_spec] + [hbm] * (len(operands) - 1),
             out_specs=lane_spec,
@@ -422,5 +442,6 @@ def decode_attention_paged(q, k, v, page_table, valid_len, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(valid, page_table.astype(jnp.int32), *operands)
+    )(valid, page_table.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     return out[..., :d].reshape(b, h, d).astype(q.dtype)

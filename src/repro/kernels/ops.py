@@ -117,23 +117,27 @@ def decode_attention_q8(q, k, v, k_scale, v_scale, valid_len, *,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def decode_attention_paged(q, k, v, page_table, valid_len, *,
+def decode_attention_paged(q, k, v, page_table, valid_len, *, layer=None,
                            interpret=None):
     """Paged flash-decode: K/V live in a global page pool, each lane's
     int32 page-table row names the physical pages its valid slots fill;
-    the kernel copies only those pages, one DMA per page of all KV heads."""
+    the kernel copies only those pages, one DMA per page of all KV heads.
+    With ``layer`` the pools are every layer's, stacked, and the kernel
+    reads that layer's pages in place."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
+                                      layer=layer,
                                       interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def decode_attention_paged_q8(q, k, v, k_scale, v_scale, page_table,
-                              valid_len, *, interpret=None):
+                              valid_len, *, layer=None, interpret=None):
     """Paged int8 flash-decode: page-table indirection over int8 payload
     pools AND their per-slot fp32 scale pools, dequantized per block in
-    VMEM."""
+    VMEM.  ``layer`` as in :func:`decode_attention_paged`."""
     return _da.decode_attention_paged(q, k, v, page_table, valid_len,
                                       k_scale=k_scale, v_scale=v_scale,
+                                      layer=layer,
                                       interpret=_interpret(interpret))
 
 

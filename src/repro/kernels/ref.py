@@ -97,7 +97,7 @@ def decode_attention_q8_ref(q, k_q, v_q, k_scale, v_scale, valid_len):
     return out.reshape(b, h, d).astype(q.dtype)
 
 
-def paged_gather(pool, page_table):
+def paged_gather(pool, page_table, layer=None):
     """Gather a lane-major ring-equivalent cache out of a page pool.
 
     pool: (P, KV, ps, D) payloads — or the scale pools (P, KV, ps);
@@ -105,31 +105,35 @@ def paged_gather(pool, page_table):
     (resp. (B, KV, W*ps)) array in which lane b's logical slot t is
     ``pool[page_table[b, t // ps]][:, t % ps]`` — a pure memory reorder,
     so any ring-cache oracle applied to the gather is bit-identical to
-    true paged attention.
+    true paged attention.  With ``layer`` the pool is every layer's,
+    stacked, and the pages of layer ``layer`` are gathered.
     """
-    g = pool[page_table]                # (B, W, KV, ps[, D])
+    # (B, W, KV, ps[, D])
+    g = pool[page_table] if layer is None else pool[layer, page_table]
     b, w = g.shape[:2]
     g = jnp.moveaxis(g, 1, 2)           # (B, KV, W, ps[, D])
     return g.reshape(b, g.shape[1], w * g.shape[3], *g.shape[4:])
 
 
-def decode_attention_paged_ref(q, k_pool, v_pool, page_table, valid_len):
+def decode_attention_paged_ref(q, k_pool, v_pool, page_table, valid_len,
+                               layer=None):
     """Paged decode oracle: gather pages into the equivalent ring layout
-    and reuse the ragged ring oracle.  q: (B, H, D); pools as in
-    :func:`paged_gather`; valid_len counts LOGICAL slots (< W*ps)."""
-    k = paged_gather(k_pool, page_table)
-    v = paged_gather(v_pool, page_table)
+    and reuse the ragged ring oracle.  q: (B, H, D); pools (and
+    ``layer``) as in :func:`paged_gather`; valid_len counts LOGICAL slots
+    (< W*ps)."""
+    k = paged_gather(k_pool, page_table, layer)
+    v = paged_gather(v_pool, page_table, layer)
     return decode_attention_ref(q, k, v, valid_len)
 
 
 def decode_attention_paged_q8_ref(q, k_pool, v_pool, k_scale, v_scale,
-                                  page_table, valid_len):
+                                  page_table, valid_len, layer=None):
     """Paged int8 decode oracle: gather payload AND per-slot scale pools
     through the page table, then reuse the ragged q8 ring oracle."""
-    k = paged_gather(k_pool, page_table)
-    v = paged_gather(v_pool, page_table)
-    ks = paged_gather(k_scale, page_table)
-    vs = paged_gather(v_scale, page_table)
+    k = paged_gather(k_pool, page_table, layer)
+    v = paged_gather(v_pool, page_table, layer)
+    ks = paged_gather(k_scale, page_table, layer)
+    vs = paged_gather(v_scale, page_table, layer)
     return decode_attention_q8_ref(q, k, v, ks, vs, valid_len)
 
 

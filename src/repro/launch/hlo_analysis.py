@@ -128,3 +128,39 @@ def total_wire_bytes(stats: Dict[str, Dict[str, float]]) -> float:
 
 def count_op(hlo_text: str, opname: str) -> int:
     return len(re.findall(rf"\b{re.escape(opname)}\(", hlo_text))
+
+
+# an instruction with an array result: name, dims, opcode, operands
+_DEF_RE = re.compile(r"^\s*(?:ROOT )?%(\S+) = [a-z0-9]+\[([0-9,]*)\]\S* "
+                     r"([a-z][a-z0-9-]*)\(([^)]*)\)")
+# what may have a whole buffer's shape without moving the buffer: the
+# plumbing of tuples, loops and calls, a bitcast, a kernel, and an update
+# in place (a dynamic-update-slice, or a fusion around one, whose inner
+# instructions are checked as well)
+_IN_PLACE_OPS = {"parameter", "get-tuple-element", "tuple", "bitcast",
+                 "while", "call", "conditional", "custom-call",
+                 "opt-barrier", "fusion", "dynamic-update-slice"}
+
+
+def whole_buffer_ops(hlo_text: str, shapes) -> List[str]:
+    """Instructions of optimized HLO text that move a whole array of one
+    of ``shapes`` (dims tuples, e.g. a KV pool and one layer of it): a
+    copy, transpose, slice, conversion or any other instruction with such
+    a result, and a dynamic-update-slice whose update is such an array
+    (a layer written back).  Returns ``"<opcode> %<name> [dims]"`` for
+    each; an empty list means the buffers are only updated in place."""
+    shapes = {tuple(s) for s in shapes}
+    dims, found = {}, []
+    for line in hlo_text.splitlines():
+        m = _DEF_RE.match(line)
+        if not m:
+            continue
+        name, d, op, args = m.groups()
+        d = tuple(int(x) for x in d.split(",") if x)
+        dims[name] = d
+        update = re.findall(r"%([^\s,)]+)", args)[1:2] \
+            if op == "dynamic-update-slice" else []
+        if (d in shapes and op not in _IN_PLACE_OPS) or \
+                any(dims.get(u) in shapes for u in update):
+            found.append(f"{op} %{name} {list(d)}")
+    return found

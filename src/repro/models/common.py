@@ -395,47 +395,69 @@ def _paged_slot(page_table, pos, page_size):
     return phys, p % page_size
 
 
-def cache_write_batch_paged(pool_k, pool_v, page_table, k_new, v_new, pos):
+def _write_rows(pool, phys, off, rows, layer=None):
+    """Write lane b's row ``rows[b]`` ((KV, D) or (KV,)) at ``[phys[b],
+    :, off[b]]`` of a pool (P, KV, ps[, D]), or at ``[layer, phys[b], :,
+    off[b]]`` of a stacked one (L, P, KV, ps[, D]): one
+    ``dynamic_update_slice`` per lane, which XLA performs in place on a
+    pool that a scan carries or a jit donates (a scatter with these
+    indices lays the pool out anew first)."""
+    lead = () if layer is None else (layer,)
+    tail = (0,) * (pool.ndim - len(lead) - 3)
+    for b in range(rows.shape[0]):
+        row = rows[b].astype(pool.dtype)[None, :, None]    # (1, KV, 1[, D])
+        pool = lax.dynamic_update_slice(
+            pool, row.reshape((1,) * len(lead) + row.shape),
+            (*lead, phys[b], 0, off[b], *tail))
+    return pool
+
+
+def cache_write_batch_paged(pool_k, pool_v, page_table, k_new, v_new, pos,
+                            layer=None):
     """Per-lane one-token write into a paged KV pool.
 
     ``pool_k``/``pool_v``: (P, KV, ps, D); ``page_table``: (B, W) int32;
     ``k_new``/``v_new``: (B, KV, 1, D) as in :func:`cache_write_batch`.
+    With ``layer`` the pools are every layer's, stacked (L, P, KV, ps, D),
+    and each lane's row lands at ``[layer, phys, :, off]``.  The rows are
+    written in place (:func:`_write_rows`).
     The allocator guarantees every ACTIVE lane's current page is
     exclusively owned (copy-on-write happens host-side before the step),
-    so the scatter cannot collide; inactive lanes' table rows are all
+    so the writes cannot collide; inactive lanes' table rows are all
     zeros and land in the reserved garbage page 0.
     """
-    ps = pool_k.shape[2]
+    ps = pool_k.shape[-2]
     phys, off = _paged_slot(page_table, pos, ps)
-    pool_k = pool_k.at[phys, :, off].set(k_new[:, :, 0].astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, :, off].set(v_new[:, :, 0].astype(pool_v.dtype))
-    return pool_k, pool_v
+    return (_write_rows(pool_k, phys, off, k_new[:, :, 0], layer),
+            _write_rows(pool_v, phys, off, v_new[:, :, 0], layer))
 
 
 def cache_write_batch_paged_q8(pool_k, pool_v, scale_k, scale_v, page_table,
-                               k_new, v_new, pos):
+                               k_new, v_new, pos, layer=None):
     """Quantizing paged write: int8 payload pools (P, KV, ps, D) plus
     per-slot fp32 scale pools (P, KV, ps) — the paged analogue of
     :func:`cache_write_batch_q8`, same per-(lane, head, slot) scale
-    semantics."""
+    semantics.  ``layer`` as in :func:`cache_write_batch_paged`, the
+    scale pools then stacked (L, P, KV, ps)."""
     from repro.core.quantize import quantize_into
-    ps = pool_k.shape[2]
+    ps = pool_k.shape[-2]
     phys, off = _paged_slot(page_table, pos, ps)
     kq, ks = quantize_into(k_new[:, :, 0], axis=-1)    # (B,KV,D),(B,KV)
     vq, vs = quantize_into(v_new[:, :, 0], axis=-1)
-    pool_k = pool_k.at[phys, :, off].set(kq)
-    pool_v = pool_v.at[phys, :, off].set(vq)
-    scale_k = scale_k.at[phys, :, off].set(ks)
-    scale_v = scale_v.at[phys, :, off].set(vs)
-    return pool_k, pool_v, scale_k, scale_v
+    return tuple(_write_rows(pool, phys, off, rows, layer)
+                 for pool, rows in ((pool_k, kq), (pool_v, vq),
+                                    (scale_k, ks), (scale_v, vs)))
 
 
 def decode_attention_named(q, k_cache, v_cache, valid_len, *,
                            backend: Optional[str] = None,
-                           k_scale=None, v_scale=None, page_table=None):
+                           k_scale=None, v_scale=None, page_table=None,
+                           layer=None):
     """Decode attention through the op-registry named-backend mechanism.
 
-    Caches are (B, KV, S, D) rings or (P, KV, ps, D) page pools.
+    Caches are (B, KV, S, D) rings or (P, KV, ps, D) page pools; with
+    ``layer`` the pools are every layer's stacked, (L, P, KV, ps, D), and
+    layer ``layer`` of them is read (paged backends only).
     ``backend`` is a registry backend name — 'ref' (the jnp
     :func:`attention_decode` oracle), 'pallas' (the ragged flash-decode
     kernel in repro.kernels.decode_attention), or None/'auto' (pallas on
@@ -459,6 +481,9 @@ def decode_attention_named(q, k_cache, v_cache, valid_len, *,
         kw.update(k_scale=k_scale, v_scale=v_scale)
     if paged:
         kw.update(page_table=page_table)
+    if layer is not None:
+        assert paged, "a layer index addresses stacked page pools"
+        kw.update(layer=layer)
     return fn(q, k_cache, v_cache, valid_len, **kw)
 
 

@@ -456,32 +456,44 @@ def decode_step_batch(cfg: ArchConfig, params, tokens, cache, pos, *,
     """Lane-major decode: tokens (B, 1); pos (B,) per-lane (see
     transformer.decode_step_batch), the held experts' dropless layer in
     place of the dense MLP.  The cache's leaves pick the attention path:
-    ring or paged (``page_table``), float or int8 (scale leaves).  With
+    ring or paged (``page_table``), float or int8 (scale leaves).  Ring
+    buffers stream through the layer scan as xs/ys; page pools are
+    carried whole and addressed by layer index
+    (``transformer.decode_layers_paged``), so the scheduler's step,
+    which donates its state, updates them in place and no caller may
+    keep a leaf of a state it has passed to a step.  With
     ``with_stats`` also returns the step's routing counters
     (``ROUTING_STATS``)."""
     with jax.named_scope("embed"):
         x = tfm._embed(cfg, params, tokens)
-    pt = cache.get("page_table")
-    names = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages") \
-        if pt is not None else ("k", "v", "k_scale", "v_scale")
-    names = tuple(n for n in names if n in cache)
     layers, experts = _scanned(params)
 
-    def layer(x, scanned):
-        lp, ck, cv, *scales = scanned
-        kw = dict(cks=scales[0], cvs=scales[1]) if scales else {}
-        a, *kv = tfm.attn_decode_batch(cfg, lp, x, ck, cv, pos,
-                                       window=window, backend=attn_backend,
-                                       page_table=pt, **kw)
-        x = x + a
+    def ffn(lp, x):
         m, stats = _moe_serve(cfg, lp, experts, x)
-        return x + m, (tuple(kv), stats)
+        return x + m, stats
 
-    x, (kv, stats) = lax.scan(layer, x,
-                              (layers, *(cache[n] for n in names)))
+    if "page_table" in cache:
+        x, cache, stats = tfm.decode_layers_paged(
+            cfg, layers, x, cache, pos, ffn, window=window,
+            attn_backend=attn_backend)
+    else:
+        names = tuple(n for n in ("k", "v", "k_scale", "v_scale")
+                      if n in cache)
+
+        def layer(x, scanned):
+            lp, ck, cv, *scales = scanned
+            kw = dict(cks=scales[0], cvs=scales[1]) if scales else {}
+            a, *kv = tfm.attn_decode_batch(cfg, lp, x, ck, cv, pos,
+                                           window=window,
+                                           backend=attn_backend, **kw)
+            x, stats = ffn(lp, x + a)
+            return x, (tuple(kv), stats)
+
+        x, (kv, stats) = lax.scan(layer, x,
+                                  (layers, *(cache[n] for n in names)))
+        cache = {**cache, **dict(zip(names, kv))}
     with jax.named_scope("head"):
         logits = tfm._logits(cfg, params, x)
-    cache = {**cache, **dict(zip(names, kv))}
     if with_stats:
         return logits, cache, _fold_stats(stats)
     return logits, cache

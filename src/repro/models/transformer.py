@@ -124,7 +124,7 @@ def attn_decode(cfg: ArchConfig, lp, x, ck, cv, pos, *, window: int = 0):
 
 def attn_decode_batch(cfg: ArchConfig, lp, x, ck, cv, pos, *,
                       window: int = 0, backend=None, cks=None, cvs=None,
-                      page_table=None):
+                      page_table=None, layer=None):
     """Lane-major ragged decode attention: x (B, 1, d); caches
     (B, KV, S, D); pos (B,) per-lane absolute positions.
 
@@ -142,12 +142,14 @@ def attn_decode_batch(cfg: ArchConfig, lp, x, ck, cv, pos, *,
     With ``page_table`` ((B, W) int32) the caches are global page POOLS
     — (P, KV, ps, D) payloads, (P, KV, ps) scales — and both the write
     and the attention indirect through the lane's table row (paged
-    backend twins); logical capacity becomes W * ps per lane."""
+    backend twins); logical capacity becomes W * ps per lane.  With
+    ``layer`` as well the pools are every layer's, stacked (L, P, KV, ps,
+    D), and are written and read at that layer in place."""
     b = x.shape[0]
     hd = cfg.resolved_head_dim
     paged = page_table is not None
     if paged:
-        cache_size = page_table.shape[1] * ck.shape[2]  # W * ps logical
+        cache_size = page_table.shape[1] * ck.shape[-2]  # W * ps logical
     else:
         cache_size = ck.shape[2]
     with jax.named_scope("qkv"):
@@ -167,26 +169,27 @@ def attn_decode_batch(cfg: ArchConfig, lp, x, ck, cv, pos, *,
         with jax.named_scope("kv_write"):
             if paged:
                 ck, cv = cm.cache_write_batch_paged(ck, cv, page_table, kT,
-                                                    vT, pos)
+                                                    vT, pos, layer)
             else:
                 ck, cv = cm.cache_write_batch(ck, cv, kT, vT, pos)
         with jax.named_scope("attention"):
             out = cm.decode_attention_named(q, ck, cv, valid,
                                             backend=backend,
-                                            page_table=page_table)
+                                            page_table=page_table,
+                                            layer=layer)
             out = out.reshape(b, 1, cfg.q_dim) @ lp["wo"]
         return out, ck, cv
     with jax.named_scope("kv_write"):
         if paged:
             ck, cv, cks, cvs = cm.cache_write_batch_paged_q8(
-                ck, cv, cks, cvs, page_table, kT, vT, pos)
+                ck, cv, cks, cvs, page_table, kT, vT, pos, layer)
         else:
             ck, cv, cks, cvs = cm.cache_write_batch_q8(ck, cv, cks, cvs, kT,
                                                        vT, pos)
     with jax.named_scope("attention"):
         out = cm.decode_attention_named(q, ck, cv, valid, backend=backend,
                                         k_scale=cks, v_scale=cvs,
-                                        page_table=page_table)
+                                        page_table=page_table, layer=layer)
         out = out.reshape(b, 1, cfg.q_dim) @ lp["wo"]
     return out, ck, cv, cks, cvs
 
@@ -394,10 +397,12 @@ def decode_step_batch(cfg: ArchConfig, params, tokens, cache, pos, *,
     the quantizing write + q8 attention path; the branch is static, so
     each cache dtype compiles its own specialization.
 
-    A paged cache (the ``page_table`` leaf marks it) streams the PAGE
-    POOLS through the scan instead of per-lane rings; the page table is
-    layer-invariant, so it rides as a closure constant and comes back
-    unchanged."""
+    A paged cache (the ``page_table`` leaf marks it) is carried through
+    the layer scan whole: the stacked page pools are written and read in
+    place at each layer's index (:func:`decode_layers_paged`).  The
+    scheduler's step donates the state it passes in, so the returned
+    pools are the same device buffers as the given ones, and a caller
+    may keep no leaf of a state it has passed to a step."""
     with jax.named_scope("embed"):
         x = _embed(cfg, params, tokens)
     if "page_table" in cache:
@@ -435,50 +440,57 @@ def decode_step_batch(cfg: ArchConfig, params, tokens, cache, pos, *,
     return _logits(cfg, params, x), {"k": ck, "v": cv}
 
 
+PAGE_POOLS = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
+
+
+def decode_layers_paged(cfg: ArchConfig, layers, x, cache, pos, ffn, *,
+                        window: int = 0, attn_backend=None):
+    """The layer scan of a paged decode step, for every family whose
+    layer is attention then a feed-forward block.
+
+    The scan carries the whole stacked pools (``PAGE_POOLS``: the
+    payloads and, int8, the scale pools) and runs over ``layers`` and
+    the layer's index: each layer writes its token into the pools and
+    reads them at that index, in place, so no layer slice is copied out
+    or written back.  ``ffn(lp, x)`` returns the layer's output and a
+    per-layer value, stacked by the scan.  Returns (x, cache, stacked
+    values); the (B, W) page table is shared by every layer and comes
+    back unchanged."""
+    pt = cache["page_table"]
+    names = tuple(n for n in PAGE_POOLS if n in cache)
+    n_layers = jax.tree.leaves(layers)[0].shape[0]
+
+    def layer(carry, scanned):
+        lp, index = scanned
+        x, (ck, cv, *scales) = carry
+        kw = dict(cks=scales[0], cvs=scales[1]) if scales else {}
+        a, *pools = attn_decode_batch(cfg, lp, x, ck, cv, pos,
+                                      window=window, backend=attn_backend,
+                                      page_table=pt, layer=index, **kw)
+        x, y = ffn(lp, x + a)
+        return (x, tuple(pools)), y
+
+    (x, pools), ys = lax.scan(
+        layer, (x, tuple(cache[n] for n in names)),
+        (layers, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, {**cache, **dict(zip(names, pools))}, ys
+
+
 def _decode_step_batch_paged(cfg: ArchConfig, params, x, cache, pos, *,
                              window: int = 0, attn_backend=None):
-    """Paged twin of the :func:`decode_step_batch` scan bodies: per-layer
-    page-pool slices stream as xs/ys, the (B, W) page table is shared by
-    every layer."""
-    pt = cache["page_table"]
-    quantized = "k_scale_pages" in cache
-
-    if quantized:
-        def layer(x, scanned):
-            lp, ck, cv, cks, cvs = scanned
-            a, ck, cv, cks, cvs = attn_decode_batch(
-                cfg, lp, x, ck, cv, pos, window=window,
-                backend=attn_backend, cks=cks, cvs=cvs, page_table=pt)
-            x = x + a
-            with jax.named_scope("mlp"):
-                x = x + mlp(cfg, lp, x)
-            return x, (ck, cv, cks, cvs)
-
-        x, (ck, cv, cks, cvs) = lax.scan(
-            layer, x, (params["layers"], cache["k_pages"],
-                       cache["v_pages"], cache["k_scale_pages"],
-                       cache["v_scale_pages"]))
-        with jax.named_scope("head"):
-            logits = _logits(cfg, params, x)
-        return logits, {
-            "k_pages": ck, "v_pages": cv, "k_scale_pages": cks,
-            "v_scale_pages": cvs, "page_table": pt}
-
-    def layer(x, scanned):
-        lp, ck, cv = scanned
-        a, ck, cv = attn_decode_batch(cfg, lp, x, ck, cv, pos,
-                                      window=window, backend=attn_backend,
-                                      page_table=pt)
-        x = x + a
+    """Paged :func:`decode_step_batch`: one scan body for bf16 and int8
+    pools, which :func:`decode_layers_paged` carries whole and addresses
+    by layer index."""
+    def ffn(lp, x):
         with jax.named_scope("mlp"):
-            x = x + mlp(cfg, lp, x)
-        return x, (ck, cv)
+            return x + mlp(cfg, lp, x), None
 
-    x, (ck, cv) = lax.scan(layer, x, (params["layers"], cache["k_pages"],
-                                      cache["v_pages"]))
+    x, cache, _ = decode_layers_paged(cfg, params["layers"], x, cache, pos,
+                                      ffn, window=window,
+                                      attn_backend=attn_backend)
     with jax.named_scope("head"):
         logits = _logits(cfg, params, x)
-    return logits, {"k_pages": ck, "v_pages": cv, "page_table": pt}
+    return logits, cache
 
 
 def prefill(cfg: ArchConfig, params, tokens, cache_len: int,

@@ -341,13 +341,17 @@ class ContinuousBatchingScheduler:
         # retirement-to-retirement because the retirement fetch is the
         # scheduler's real sync point
         self._rf_anchor = (0.0, 0.0, 0, 0.0)
-        self._step_fn = jax.jit(self._step)
+        # the decode step consumes the state it is given (donated): page
+        # pools are updated in place, and no leaf of an old state may be
+        # held across a step
+        self._step_fn = jax.jit(self._step, donate_argnums=1)
         self._deactivate_fn = jax.jit(self._deactivate)
         self._admit_fn = jax.jit(self._admit, static_argnames=("plen",))
         if self._paged:
             self._admit_paged_fn = jax.jit(self._admit_paged,
                                            static_argnames=("plen",))
-            self._suffix_step_fn = jax.jit(self._suffix_step)
+            self._suffix_step_fn = jax.jit(self._suffix_step,
+                                           donate_argnums=1)
             self._finalize_admit_fn = jax.jit(self._finalize_admit)
             self._set_pt_row_fn = jax.jit(self._set_pt_row)
             self._set_pt_entry_fn = jax.jit(self._set_pt_entry)

@@ -23,6 +23,7 @@ from repro.configs.base import get_config, reduced
 from repro.kernels import decode_attention as da
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.launch.hlo_analysis import whole_buffer_ops
 from repro.runtime.pagepool import GARBAGE_PAGE, PagePool
 from repro.runtime.scheduler import ContinuousBatchingScheduler, Request
 
@@ -97,16 +98,11 @@ def test_paged_kernel_fragmented_out_of_order_pages(valid_kind, quantized):
                                atol=2e-5, rtol=2e-5)
 
 
-@pytest.mark.parametrize("group", [2, 4])
-@pytest.mark.parametrize("kind", ["bf16", "f32", "int8"])
-def test_paged_kernel_block_edges_at_serving_head_layouts(kind, group):
-    """The paged kernel against the gather oracle at the served models'
-    head layouts (8 KV heads of 128, groups of 2 and 4), one lane per edge
-    of its page blocks: 1, ps and ps+1 slots, exactly one block, one block
-    + 1, and the whole table, whose width is no multiple of the block.
-    Two lanes share their first physical pages (a prefix hit), and the
-    unused tail of every row points at the garbage page 0, which holds
-    large values that a read past a lane's end would show."""
+def _edge_case(kind, group, n_layers=None):
+    """Lanes at the edges of the paged kernel's page blocks, at a served
+    model's head layout (see the test below): (q, page table, valid
+    lengths, pools).  The pools are K, V and, int8, their scales; with
+    ``n_layers`` each is that many layers of distinct data, stacked."""
     rng = np.random.default_rng(2)
     kvh, d, ps = 8, 128, 16
     itemsize = {"bf16": 2, "f32": 4, "int8": 1}[kind]
@@ -126,32 +122,86 @@ def test_paged_kernel_block_edges_at_serving_head_layouts(kind, group):
     shape, sshape = (p, kvh, ps, d), (p, kvh, ps)
     q = jnp.asarray(rng.standard_normal((b, h, d)),
                     jnp.bfloat16 if kind == "bf16" else jnp.float32)
-    if kind == "int8":
-        k, v = (np.asarray(rng.integers(-127, 128, shape), np.int8)
-                for _ in range(2))
-        k[0], v[0] = 127, 127
-        ks, vs = (np.asarray(rng.uniform(0.01, 0.05, sshape), np.float32)
-                  for _ in range(2))
-        ks[0], vs[0] = 1e3, 1e3
-        k, v, ks, vs = map(jnp.asarray, (k, v, ks, vs))
-        got = kops.decode_attention_paged_q8(q, k, v, ks, vs, pt, valid)
-        want = kref.decode_attention_paged_q8_ref(q, k, v, ks, vs, pt,
-                                                  valid)
-        tol = 2e-5
-    else:
+
+    def one_layer():
+        if kind == "int8":
+            k, v = (np.asarray(rng.integers(-127, 128, shape), np.int8)
+                    for _ in range(2))
+            k[0], v[0] = 127, 127
+            ks, vs = (np.asarray(rng.uniform(0.01, 0.05, sshape),
+                                 np.float32) for _ in range(2))
+            ks[0], vs[0] = 1e3, 1e3
+            return [k, v, ks, vs]
         k, v = (np.asarray(rng.standard_normal(shape), np.float32)
                 for _ in range(2))
         k[0], v[0] = 1e4, 1e4
-        dt = q.dtype
-        k, v = jnp.asarray(k, dt), jnp.asarray(v, dt)
-        got = kops.decode_attention_paged(q, k, v, pt, valid)
-        want = kref.decode_attention_paged_ref(q, k, v, pt, valid)
-        # bf16 outputs: one rounding of the result either side
-        tol = 2e-5 if kind == "f32" else 8e-3
+        return [k, v]
+
+    pools = one_layer() if n_layers is None else \
+        [np.stack(x) for x in zip(*(one_layer() for _ in range(n_layers)))]
+    dt = None if kind == "int8" else q.dtype
+    return q, pt, valid, [jnp.asarray(x, dt) for x in pools]
+
+
+def _paged_kernel(q, pools, pt, valid, layer=None):
+    if len(pools) == 4:
+        k, v, ks, vs = pools
+        return kops.decode_attention_paged_q8(q, k, v, ks, vs, pt, valid,
+                                              layer=layer)
+    return kops.decode_attention_paged(q, *pools, pt, valid, layer=layer)
+
+
+def _paged_oracle(q, pools, pt, valid, layer=None):
+    if len(pools) == 4:
+        k, v, ks, vs = pools
+        return kref.decode_attention_paged_q8_ref(q, k, v, ks, vs, pt, valid,
+                                                  layer)
+    return kref.decode_attention_paged_ref(q, *pools, pt, valid, layer)
+
+
+@pytest.mark.parametrize("group", [2, 4])
+@pytest.mark.parametrize("kind", ["bf16", "f32", "int8"])
+def test_paged_kernel_block_edges_at_serving_head_layouts(kind, group):
+    """The paged kernel against the gather oracle at the served models'
+    head layouts (8 KV heads of 128, groups of 2 and 4), one lane per edge
+    of its page blocks: 1, ps and ps+1 slots, exactly one block, one block
+    + 1, and the whole table, whose width is no multiple of the block.
+    Two lanes share their first physical pages (a prefix hit), and the
+    unused tail of every row points at the garbage page 0, which holds
+    large values that a read past a lane's end would show."""
+    q, pt, valid, pools = _edge_case(kind, group)
+    got = _paged_kernel(q, pools, pt, valid)
+    want = _paged_oracle(q, pools, pt, valid)
+    # bf16 outputs: one rounding of the result either side
+    tol = 8e-3 if kind == "bf16" else 2e-5
     assert got.dtype == want.dtype
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_paged_kernel_reads_the_given_layer_of_stacked_pools(kind, layer):
+    """Given ``layer``, the kernel reads that layer of pools stacked over
+    layers in place, and returns exactly what it returns on that layer's
+    pools alone; so does the oracle.  Every layer holds other data, so a
+    read of the wrong layer shows."""
+    q, pt, valid, pools = _edge_case(kind, 4, n_layers=3)
+    alone = [x[layer] for x in pools]
+    got = _paged_kernel(q, pools, pt, valid, layer=jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(_paged_kernel(q, alone, pt,
+                                                           valid),
+                                             np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(_paged_oracle(q, pools, pt, valid, jnp.int32(layer)),
+                   np.float32),
+        np.asarray(_paged_oracle(q, alone, pt, valid), np.float32))
+    others = [_paged_kernel(q, [x[i] for x in pools], pt, valid)
+              for i in range(3) if i != layer]
+    assert all(not np.allclose(np.asarray(o, np.float32),
+                               np.asarray(got, np.float32)) for o in others)
 
 
 def test_paged_gather_matches_ring_oracle_exactly():
@@ -176,6 +226,42 @@ def test_paged_gather_matches_ring_oracle_exactly():
 # ---------------------------------------------------------------------------
 # scheduler-level: token parity, COW, refcounts
 # ---------------------------------------------------------------------------
+
+
+def pool_shapes(cache):
+    """The shapes of a paged cache's stacked pools, of one layer of them,
+    and of one layer with its axis kept."""
+    pools = [v.shape for k, v in cache.items() if k.endswith("_pages")]
+    return {tuple(p) for p in pools} | {tuple(p[1:]) for p in pools} \
+        | {(1, *p[1:]) for p in pools}
+
+
+def pool_bytes(cache):
+    return sum(v.nbytes for k, v in cache.items() if k.endswith("_pages"))
+
+
+@pytest.mark.parametrize("kv_dtype", [None, "int8"], ids=["float", "int8"])
+@pytest.mark.parametrize("arch", ["qwen3-0.6b", "qwen3-moe-235b-a22b"])
+def test_paged_step_updates_pools_in_place(arch, kv_dtype):
+    """The compiled decode step and prefix-hit suffix step of a paged
+    scheduler write and read the page pools in place: no instruction
+    copies, slices, transposes or converts a whole pool or one layer of
+    it, or writes a layer back, and the pools of the donated state alias
+    the step's output.  The pools are float here because XLA's CPU
+    compiler widens a bf16 buffer to f32 around each update, which the
+    TPU's does not: ``test_tpu_compile.py`` checks bf16 pools for the
+    chip.  That the paged step's greedy tokens are the ring layout's is
+    ``test_paged_matches_ring_greedy``, for both families."""
+    cfg = reduced(get_config(arch))
+    s = _sched(cfg, models.init_params(cfg, KEY), max_slots=3,
+               kv_layout="paged", page_size=16, kv_dtype=kv_dtype)
+    cache = s.state["cache"]
+    one = jnp.int32(0)
+    for fn, args in ((s._step_fn, ()), (s._suffix_step_fn, (one,) * 3)):
+        compiled = fn.lower(s.params, s.state, *args).compile()
+        assert whole_buffer_ops(compiled.as_text(), pool_shapes(cache)) == []
+        assert compiled.memory_analysis().alias_size_in_bytes \
+            >= pool_bytes(cache)
 
 
 @pytest.mark.parametrize("family", PAGED_ARCHS, indirect=True)

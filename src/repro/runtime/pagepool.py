@@ -14,18 +14,22 @@ lane's next logical block maps to:
 * Prefix cache — an LRU map from exact padded-prompt-token tuples (at
   page-aligned lengths, plus the full prompt length) to the page run
   holding that prefix's KV.  A hit lets admission map those pages
-  read-only (refcount++) and prefill only the suffix; copy-on-write in
-  the scheduler keeps cached entries pristine when a lane later writes
-  into a shared page.
+  read-only (refcount++) and prefill only the suffix; copy-on-write
+  keeps cached entries pristine when a lane later writes into a shared
+  page.
 
-No jax imports — this is pure host Python/numpy; the scheduler turns
-decisions into device updates.
+* ``LanePages`` — the one owner of every lane's pages: it decides
+  WHICH page and returns the device page-table edits as data
+  (``RowEdit``, ``EntryEdit``, ``CopyEdit``); the scheduler decides
+  WHEN and applies them.
+
+No jax imports — this is pure host Python/numpy.
 """
 from __future__ import annotations
 
-from collections import OrderedDict
-from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from collections import OrderedDict, namedtuple
+from dataclasses import dataclass
+from typing import Callable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -57,10 +61,8 @@ class PagePool:
             raise ValueError(f"bad page_size {page_size}")
         self.num_pages = num_pages
         self.page_size = page_size
-        # optional MetricsRegistry (duck-typed — still no jax here): the
-        # scheduler passes its registry so allocator pressure events
-        # (pool.evictions / pool.alloc_failures) land on the same stats
-        # surface as everything else
+        # optional MetricsRegistry (duck-typed — still no jax here) for
+        # the allocator's pressure events (pool.evictions, ...)
         self.metrics = metrics
         self.refcount = np.zeros((num_pages,), np.int32)
         self.refcount[GARBAGE_PAGE] = 1          # pinned forever
@@ -164,16 +166,6 @@ class PagePool:
     def prefix_entries(self) -> int:
         return len(self._prefixes)
 
-    def entry_page_refs(self) -> np.ndarray:
-        """Per-page reference counts held by prefix-cache entries — the
-        scheduler's ``audit_pages`` combines this with the live lanes'
-        page tables to reconstruct (and assert) the full refcounts."""
-        refs = np.zeros(self.num_pages, np.int64)
-        for entry in self._prefixes.values():
-            for p in entry.pages:
-                refs[p] += 1
-        return refs
-
     def leak_check(self) -> None:
         """Every page is either free, garbage, or reachable from a live
         reference — asserts the refcount/free-list invariant (used by
@@ -188,3 +180,160 @@ class PagePool:
                 assert self.refcount[p] == 0, (p, self.refcount[p])
             else:
                 assert self.refcount[p] > 0, f"leaked page {p}"
+
+
+# Device page-table edits, as data.  Each one's fields are the
+# arguments, in order, of the scheduler program that applies it.
+# Write lane ``slot``'s whole row (``_set_pt_row``):
+RowEdit = namedtuple("RowEdit", "slot row")
+# Point entry ``idx`` of lane ``slot``'s row at ``page`` (``_set_pt_entry``):
+EntryEdit = namedtuple("EntryEdit", "slot idx page")
+# Copy-on-write: copy page ``src`` into ``page`` in every pool and point
+# entry ``idx`` of lane ``slot``'s row at the copy (``_copy_page``):
+CopyEdit = namedtuple("CopyEdit", "src page slot idx")
+
+
+class LanePages:
+    """The pages of every lane: a :class:`PagePool` and ``table``, the
+    host mirror of the (max_slots, pages_per_lane) device page table, so
+    no allocation decision reads the device.  Every non-garbage entry of
+    a lane's row holds one reference on behalf of that lane.
+    ``refuse_alloc(site, slot, n)`` (the fault injector) is consulted
+    before every allocation: True fails it with no eviction rescue."""
+
+    def __init__(self, num_pages: Optional[int], page_size: int,
+                 max_slots: int, pages_per_lane: int, *, capacity: int,
+                 alloc_mode: str, prefix_sharing: bool, metrics=None,
+                 refuse_alloc: Optional[Callable[[str, Optional[int], int],
+                                                 bool]] = None):
+        # auto pool: garbage page + a full complement per lane + one
+        # lane's worth of slack for retained prefix entries
+        if num_pages is None:
+            num_pages = 1 + (max_slots + 1) * pages_per_lane
+        if num_pages < 1 + pages_per_lane:
+            raise ValueError(
+                f"num_pages={num_pages} cannot hold even one "
+                f"lane ({pages_per_lane} pages + garbage page)")
+        self.pool = PagePool(num_pages, page_size, metrics=metrics)
+        self.table = np.zeros((max_slots, pages_per_lane), np.int32)
+        self.page_size = page_size
+        self.pages_per_lane = pages_per_lane
+        self.capacity = capacity
+        self.alloc_mode = alloc_mode              # incremental | full
+        self.prefix_sharing = prefix_sharing
+        self._refuse = refuse_alloc or (lambda site, slot, n: False)
+
+    def occupancy(self) -> float:
+        return 1.0 - self.pool.available() / self.pool.num_pages
+
+    def pages_for(self, plen: int) -> int:
+        """Pages an admission of ``plen`` tokens takes ('full' mode: the
+        whole lane's)."""
+        if self.alloc_mode == "full":
+            return self.pages_per_lane
+        return -(-plen // self.page_size)
+
+    def alloc(self, n: int, *, site: str = "",
+              slot: Optional[int] = None) -> Optional[List[int]]:
+        """Claim ``n`` pages, evicting LRU prefix-cache entries under
+        pressure; None when the pool cannot supply them."""
+        if self._refuse(site, slot, n):
+            self.pool._count("faults.alloc_failures")
+            return None
+        pages = self.pool.alloc(n)
+        while pages is None and self.pool.evict_one():
+            pages = self.pool.alloc(n)
+        return pages
+
+    def take(self, slot: int, n: int) -> Optional[np.ndarray]:
+        """Claim ``n`` fresh pages and map them from the start of lane
+        ``slot``'s row.  No edit: the admission program writes the row."""
+        pages = self.alloc(n, site="admission", slot=slot)
+        if pages is None:
+            return None
+        self.table[slot] = 0
+        self.table[slot, :n] = pages
+        return np.asarray(pages, np.int32)
+
+    def lookup(self, tokens: Sequence[int]) -> Optional[PrefixEntry]:
+        return self.pool.prefix_lookup(tokens) if self.prefix_sharing \
+            else None
+
+    def map_prefix(self, slot: int, entry: PrefixEntry,
+                   plen: int) -> Tuple[int, RowEdit]:
+        """Map ``entry``'s pages read-only into lane ``slot``, reusing at
+        most ``plen - 1`` tokens so one suffix step runs (its logits
+        seed the first token).  Returns the reused length and the edit."""
+        t = min(entry.length, plen - 1)
+        shared = entry.pages[:-(-t // self.page_size)]
+        for p in shared:
+            self.pool.ref(p)
+        self.table[slot] = 0
+        self.table[slot, :len(shared)] = shared
+        return t, RowEdit(slot, self.table[slot].copy())
+
+    def writable(self, slot: int, pos: int,
+                 site: str = "") -> Optional[List[tuple]]:
+        """Make lane ``slot`` the sole owner of the page its write at
+        ``pos`` lands in (first touch / copy-on-write).  Returns the
+        edits, or None when the pool cannot supply the page."""
+        idx = (pos % self.capacity) // self.page_size
+        phys = int(self.table[slot, idx])
+        if phys != GARBAGE_PAGE and self.pool.refcount[phys] <= 1:
+            return []
+        shared = phys != GARBAGE_PAGE
+        got = self.alloc(1, site=site + ("cow" if shared else "first_touch"),
+                         slot=slot)
+        if got is None:
+            return None
+        self.table[slot, idx] = got[0]
+        if not shared:
+            return [EntryEdit(slot, idx, got[0])]
+        self.pool.free(phys)                   # drop the lane's shared ref
+        self.pool._count("sched.cow_copies")
+        return [CopyEdit(phys, got[0], slot, idx)]
+
+    def register(self, slot: int, tokens: Sequence[int]) -> None:
+        """Publish lane ``slot``'s page-aligned prefixes of ``tokens``
+        (and the whole prompt) as prefix-cache entries."""
+        span = -(-len(tokens) // self.page_size)
+        self.pool.prefix_register(tokens,
+                                  [int(p) for p in self.table[slot, :span]])
+
+    def release(self, slot: int) -> RowEdit:
+        """Drop lane ``slot``'s reference on every page of its row and
+        zero the row, on the device too: a freed lane's stale mapping
+        must never alias a reallocated page."""
+        for phys in self.table[slot]:
+            if phys != GARBAGE_PAGE:
+                self.pool.free(int(phys))
+        self.table[slot] = 0
+        return RowEdit(slot, self.table[slot].copy())
+
+    def resident_bytes(self, cache) -> int:
+        """Bytes of ``cache`` holding KV: each pool's referenced pages,
+        the other leaves whole, and the refcounts."""
+        used = self.pool.num_pages - self.pool.available()
+        total = self.pool.refcount.nbytes
+        for k, v in cache.items():
+            nbytes = int(v.size) * v.dtype.itemsize
+            total += (nbytes // self.pool.num_pages) * used \
+                if k.endswith("_pages") else nbytes
+        return total
+
+    def audit(self, live: Sequence[int]) -> None:
+        """Assert the refcount invariant: every page's count equals
+        (1 for the garbage page) + (1 per ``live`` lane mapping it)
+        + (1 per prefix-cache entry spanning it)."""
+        expected = np.zeros(self.pool.num_pages, np.int64)
+        expected[GARBAGE_PAGE] = 1
+        rows = self.table[list(live)].ravel()
+        np.add.at(expected, rows[rows != GARBAGE_PAGE], 1)
+        for entry in self.pool._prefixes.values():
+            np.add.at(expected, list(entry.pages), 1)
+        actual = np.asarray(self.pool.refcount, np.int64)
+        if not np.array_equal(expected, actual):
+            bad = np.nonzero(expected != actual)[0]
+            raise AssertionError(
+                f"refcount leak: pages {bad.tolist()} expected "
+                f"{expected[bad].tolist()} got {actual[bad].tolist()}")

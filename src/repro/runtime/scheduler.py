@@ -1,85 +1,63 @@
 """Slot-based continuous-batching decode scheduler.
 
-The aligned-batch serving loop had two scaling problems the paper's
-"serve many users from one GPU" story can't live with:
+The scheduler keeps ``max_slots`` decode lanes resident on the device.
+All per-token state — last token, per-lane position, temperature, active
+mask, PRNG key, the KV/SSM cache and the output ring — lives in one
+device-side state pytree.  One jitted step advances every lane: model
+decode, then on-device sampling (argmax where a lane's temperature is 0,
+categorical elsewhere), then a scatter into the output buffer.  The host
+loop only dispatches steps and keeps slot lifetimes it can compute
+without reading device data, so a generated token costs **zero host
+syncs**; the one device->host transfer per request is the fetch of its
+output row at retirement.
 
-  * every generated token round-tripped through the host
-    (``np.asarray`` per step) — a sync per token, and
-  * a batch admitted together retired together: one long request held
-    every slot hostage, and all requests shared one global temperature.
+A request is admitted mid-flight into a free lane by one jitted program,
+``_admit``: a B=1 prefill of the prompt, its first token sampled on
+device, and the lane's KV and fields written into the live batch while
+the other lanes keep decoding.  The ring layout splices the prefilled
+row into the lane's cache row.  In the paged layout
+(``kv_layout='paged'``) :class:`~repro.runtime.pagepool.LanePages`
+decides which pages a lane holds and returns the page-table edits as
+data; the scheduler decides when, and applies them.  A prefix-cache hit
+maps the cached pages and prefills only the suffix.
 
-This scheduler keeps ``max_slots`` decode lanes resident on the device.
-ALL per-token state — last token, per-slot position, per-slot
-temperature, active mask, PRNG key, the KV/SSM cache, and the output
-ring — lives in one device-side state pytree.  One jitted step advances
-every lane: model decode, then *on-device sampling* (argmax where a
-lane's temperature is 0, categorical elsewhere), then scatter into the
-output buffer.  The host loop only dispatches steps and bookkeeps slot
-lifetimes it can compute without reading device data, so generating a
-token costs **zero host syncs**; the single device->host transfer per
-request happens at retirement when its output row is fetched.
-
-Requests are admitted mid-flight: a free slot prefill-computes the
-prompt (B=1), samples the first token, and splices cache row + state
-into the live batch while the other lanes keep decoding.  Per-slot
-positions make this correct under rotary embeddings and ring caches.
-
-The decode step itself is lane-major by default
-(``decode_mode='batched'``): the family module's ``decode_step_batch``
-takes the whole (B, 1) token batch and the per-lane position vector,
-does batched QKV projections and ONE fused ragged-attention call across
-all lanes — with the attention implementation resolved by name through
-the op registry (``ref`` = jnp oracle, ``pallas`` = the flash-decode
-kernel with per-lane block early exit).  The pre-PR-2 path — the B=1
-``decode_step`` vmapped over lanes (cache batch axis 1) — survives as
-``decode_mode='vmapped'``, the correctness reference the batched path
-must match token-for-token; families without a batch step fall back to
-it automatically.
+The decode step is lane-major (``decode_mode='batched'``): the family's
+``decode_step_batch`` takes the whole (B, 1) token batch and the
+per-lane positions, with the attention implementation resolved by name
+through the op registry (``ref`` = jnp oracle, ``pallas`` = the decode
+kernel).  ``decode_mode='vmapped'`` — the B=1 ``decode_step`` vmapped
+over lanes — is the reference the batched path must match
+token-for-token; families without a batch step fall back to it.
 
 Prompt-length bucketing (``prefill_buckets``) bounds XLA compiles to a
 few prompt shapes by LEFT-padding each prompt up to its bucket.  The
-models apply no padding mask, so within a bucket this reproduces the
-legacy aligned loop's left-pad semantics (pad tokens are attended,
-positions shift by the pad count) rather than the exact unpadded
-computation — the default (``None``) prefills at exact lengths and is
-bit-identical to a solo run; buckets trade that exactness for bounded
-compile count, exactly as the old engine's batch-level padding did.
+models apply no padding mask, so pad tokens are attended and positions
+shift by the pad count; the default (``None``) prefills at exact lengths
+and is bit-identical to a solo run.
 
-Request lifecycle (PR 8): the scheduler degrades instead of crashing.
-When the paged pool cannot supply a page mid-decode (first touch or
-copy-on-write), the lowest-priority lane is **preempted** — its pages
-released, its prompt + output-so-far requeued at the front of
-``pending`` — and re-admitted through the normal prefill/prefix-cache
-path (vLLM-style recompute preemption), token-identical under greedy.
-A per-lane device-side stop set lets a lane that samples EOS clear its
-own ``active`` bit without a host sync; a periodic done-mask fetch
-(``mask_syncs``, only when a live lane actually has stop tokens)
-retires such lanes early with ``finish_reason="eos"``.  Requests carry
-optional ``deadline_s`` wall-clock deadlines, ``cancel(uid)`` retires a
-lane (or drops a pending request) releasing its pages, and an optional
+Request lifecycle: when the paged pool cannot supply a page mid-decode,
+the lowest-priority lane is **preempted** — its pages released, its
+prompt + output-so-far requeued at the front of ``pending`` — and
+re-admitted through the normal path (recompute preemption,
+token-identical under greedy).  A per-lane device-side stop set lets a
+lane that samples EOS clear its own ``active`` bit without a host sync;
+a periodic done-mask fetch (``mask_syncs``, only when a live lane has
+stop tokens) retires such lanes with ``finish_reason="eos"``.  Requests
+carry optional ``deadline_s`` deadlines, ``cancel(uid)`` retires a lane
+or drops a pending request, an optional
 :class:`~repro.runtime.faults.FaultInjector` is consulted at page
-allocation, admission, and step boundaries so tests can force every
-degraded path deterministically.  A no-progress watchdog turns a
-host/device desync into a diagnostic error instead of a silent spin.
+allocation, admission and step boundaries, and a no-progress watchdog
+turns a host/device desync into a diagnostic error.
 
-Telemetry (PR 9): the scheduler always owns a
-:class:`~repro.runtime.telemetry.MetricsRegistry` — every counter/timer
-the earlier PRs exposed ad hoc (``prefill_s``, ``paged_stats()``,
-``lifecycle_stats()``) is now a view over it, plus TTFT / inter-token /
-queue-time / end-to-end latency histograms recorded at each request's
-lifecycle transitions.  Passing ``telemetry=Telemetry(...)`` also turns
-on the Chrome-trace recorder: per-request lifecycle rows (submit →
-admit → prefix hit/miss → first token → per-tick progress →
-preempt/requeue → finish), exported with
-``telemetry.export_chrome_trace(path)`` and viewable in Perfetto.  The
-scheduler's phases (``telemetry.SPANS``: tick, admission and its parts,
-page-table updates, step dispatch, bookkeeping, retirement) are always
-marked as ``sched.*`` profiler annotations, so a ``jax.profiler`` trace
-puts them beside the device's operations; with ``telemetry=`` they are
-Chrome spans too.  All instrumentation is host-clock only and measures
+Telemetry: a :class:`~repro.runtime.telemetry.MetricsRegistry` always
+holds the counters, timers and TTFT / inter-token / queue / end-to-end
+latency histograms; ``telemetry=Telemetry(...)`` adds the Chrome-trace
+recorder of per-request lifecycle rows.  The scheduler's phases
+(``telemetry.SPANS``) are always marked as ``sched.*`` profiler
+annotations, so a ``jax.profiler`` trace puts them beside the device's
+operations.  All instrumentation is host-clock only and measures
 *dispatch*, not device completion (the zero-host-syncs-per-token
-invariant survives tracing); see ``runtime/telemetry.py`` for the exact
-timestamp semantics.
+invariant survives tracing); see ``runtime/telemetry.py``.
 """
 from __future__ import annotations
 
@@ -87,7 +65,6 @@ import math
 import time
 from collections import deque, namedtuple
 from dataclasses import dataclass, field
-from functools import partial
 from typing import Any, Deque, Dict, List, Optional
 
 import jax
@@ -97,7 +74,8 @@ import numpy as np
 from repro import models
 from repro.configs.base import ArchConfig
 from repro.runtime.faults import FaultInjector
-from repro.runtime.pagepool import GARBAGE_PAGE, PagePool
+from repro.runtime.pagepool import (CopyEdit, EntryEdit, LanePages,
+                                    PagePool, RowEdit)
 from repro.runtime.roofline import HWSpec, RooflineAccountant
 from repro.runtime.telemetry import (PID_SCHED, MetricsRegistry, Span,
                                      Telemetry)
@@ -115,27 +93,20 @@ class Request:
     done: bool = False
     submitted_at: float = 0.0
     finished_at: float = 0.0
-    # lifecycle: extra per-request stop tokens (union'd with the
-    # scheduler's eos_id), an optional wall-clock deadline measured from
-    # submit(), and how the request ended —
+    # extra stop tokens (union'd with the scheduler's eos_id), a
+    # wall-clock deadline from submit(), and how the request ended:
     # "eos" | "length" | "cancelled" | "timeout"
     stop_tokens: Optional[List[int]] = None
     deadline_s: Optional[float] = None
     finish_reason: Optional[str] = None
-    # telemetry: when the admission dispatch that sampled this request's
-    # first token returned (host clock — dispatch-anchored, see
-    # runtime/telemetry.py for exact semantics); survives preemption so
-    # TTFT is recorded once.  ``diagnostics`` is attached on cancel /
-    # timeout retirement: a scheduler-state snapshot (lane ages, free
-    # pages, last-tick duration) that turns "why did this die?" into a
-    # diagnosis.
+    # when the admission dispatch that sampled the first token returned
+    # (host clock; survives preemption so TTFT is recorded once), and
+    # the scheduler snapshot attached on cancel / timeout retirement
     first_token_at: float = 0.0
     diagnostics: Optional[Dict[str, Any]] = None
-    # SLO budgets: per-request TTFT / inter-token-latency targets in
-    # seconds (None = inherit the scheduler-level defaults).  Attainment
-    # is judged at the retirement fetch and rolls into the registry's
-    # ``slo.*`` counters and the ``goodput`` fraction — the metric
-    # chunked prefill will be judged on (ROADMAP).
+    # SLO budgets: TTFT / inter-token-latency targets in seconds (None
+    # = the scheduler's defaults), judged at the retirement fetch into
+    # the registry's ``slo.*`` counters and the ``goodput`` fraction
     slo_ttft_s: Optional[float] = None
     slo_itl_s: Optional[float] = None
 
@@ -179,13 +150,9 @@ class ContinuousBatchingScheduler:
         self.cfg = cfg
         self.params = params
         self.mod = models.get_module(cfg)
-        # telemetry: None keeps the Chrome tracer off (zero trace events,
-        # and the transfer-guard tests prove zero extra device traffic
-        # either way; the sched.* profiler spans are always on); the
-        # MetricsRegistry ALWAYS exists — it is the one
-        # stats surface behind prefill_s/decode_s, paged_stats() and
-        # lifecycle_stats(), whose legacy attributes are now properties
-        # over registry counters (see _METRIC_ATTRS below).
+        # telemetry: None keeps the Chrome tracer off (the sched.*
+        # profiler spans are always on); the MetricsRegistry ALWAYS
+        # exists — the one stats surface (see _METRIC_ATTRS below)
         self.telemetry = telemetry
         self._tracer = telemetry.tracer if telemetry is not None else None
         self.metrics = telemetry.metrics if telemetry is not None \
@@ -199,10 +166,8 @@ class ContinuousBatchingScheduler:
         self.pad_id = pad_id
         self.prefill_buckets = sorted(prefill_buckets) if prefill_buckets \
             else None
-        # 'batched' (default): the family's lane-major decode_step_batch —
-        # one fused ragged-attention call across all lanes.  'vmapped':
-        # the B=1 decode_step vmapped over lanes, kept as the correctness
-        # reference the batched path must match token-for-token.
+        # 'vmapped' (the B=1 decode_step over lanes) is the reference the
+        # lane-major 'batched' step must match token-for-token
         if decode_mode not in ("batched", "vmapped"):
             raise ValueError(f"unknown decode_mode {decode_mode!r}")
         if decode_mode == "batched" and \
@@ -215,9 +180,8 @@ class ContinuousBatchingScheduler:
         self._routing = getattr(self.mod, "ROUTING_STATS", ()) \
             if decode_mode == "batched" else ()
         self._routing_seen = np.zeros(len(self._routing), np.int64)
-        # kv_dtype: None keeps the legacy f32 cache (token-identical to
-        # the vmapped reference); 'bf16' halves KV bytes; 'int8' quarters
-        # them via the per-slot-scale quantized cache + *_q8 attention.
+        # kv_dtype: None keeps an f32 cache (token-identical to the vmapped
+        # reference); 'bf16' halves KV bytes; 'int8' quarters them
         if kv_dtype not in (None, "bf16", "int8"):
             raise ValueError(f"unknown kv_dtype {kv_dtype!r} "
                              "(expected None, 'bf16' or 'int8')")
@@ -226,52 +190,45 @@ class ContinuousBatchingScheduler:
                 "kv_dtype='int8' requires decode_mode='batched' — the "
                 "single-token decode_step has no quantized cache path")
         self.kv_dtype = kv_dtype
-        # kv_layout='paged': block-table/paged KV — per-lane ring buffers
-        # become a global pool of fixed-size pages indirected through a
-        # (B, W) page table, with host-side refcounted allocation and
-        # copy-on-write shared-prefix reuse.  Families that don't expose
-        # ``paged_info`` (e.g. rwkv6's O(1) state has no KV to page) fall
-        # back to the ring layout silently.
+        # kv_layout='paged': a global pool of fixed-size pages behind a
+        # (B, W) page table, whose host side ``self.pages`` owns (None
+        # for the ring layout).  Families without ``paged_info`` (rwkv6
+        # has no KV to page) fall back to the ring layout silently.
         if kv_layout not in ("ring", "paged"):
             raise ValueError(f"unknown kv_layout {kv_layout!r} "
                              "(expected 'ring' or 'paged')")
         self.page_size = page_size
-        self._paged = False
+        self.kv_layout = "ring"
+        self.pages: Optional[LanePages] = None
         self.pool: Optional[PagePool] = None
-        if kv_layout == "paged" and hasattr(self.mod, "paged_info"):
+        self.prefix_sharing = False
+        # a lane's capacity in tokens, and the prefill row's length
+        self._capacity = cache_len
+        cache_kw = {}
+        paged = kv_layout == "paged" and hasattr(self.mod, "paged_info")
+        if paged:
             if decode_mode != "batched":
                 raise ValueError(
                     "kv_layout='paged' requires decode_mode='batched' — "
                     "the vmapped decode_step has no paged cache path")
             info = self.mod.paged_info(cfg, cache_len, page_size)
-            self._paged = True
-            self.pages_per_lane = int(info["pages_per_lane"])
-            self._capacity = int(info["capacity"])
-            self._alloc_mode = info["alloc"]           # incremental | full
-            self.prefix_sharing = bool(info["prefix_sharing"]) and \
-                prefix_sharing
-            # auto pool: garbage page + a full complement per lane + one
-            # lane's worth of slack for retained prefix entries
-            self.num_pages = num_pages if num_pages is not None else \
-                1 + (max_slots + 1) * self.pages_per_lane
-            if self.num_pages < 1 + self.pages_per_lane:
-                raise ValueError(
-                    f"num_pages={self.num_pages} cannot hold even one "
-                    f"lane ({self.pages_per_lane} pages + garbage page)")
-            self.pool = PagePool(self.num_pages, page_size,
-                                 metrics=self.metrics)
-            # host mirrors of the device page table / lane positions —
-            # kept in lockstep so allocation decisions need no device
-            # reads (the zero-syncs-per-token property survives paging)
-            self._pt_host = np.zeros((max_slots, self.pages_per_lane),
-                                     np.int32)
-            self._host_pos = np.zeros(max_slots, np.int64)
-        else:
-            self.prefix_sharing = False
-        self.kv_layout = "paged" if self._paged else "ring"
-        # prefill row length: paged capacity rounds cache_len up to whole
-        # pages, and the splice reads the first n*ps ring slots
-        self._prefill_len = self._capacity if self._paged else cache_len
+            # the pool holds at least one whole lane, so a request that
+            # passes submit's capacity check always fits it
+            self.pages = LanePages(
+                num_pages, page_size, max_slots,
+                int(info["pages_per_lane"]), capacity=int(info["capacity"]),
+                alloc_mode=info["alloc"],
+                prefix_sharing=bool(info["prefix_sharing"])
+                and prefix_sharing,
+                metrics=self.metrics, refuse_alloc=self._alloc_refused)
+            self.kv_layout = "paged"
+            self.pool = self.pages.pool
+            self.num_pages = self.pool.num_pages
+            self.pages_per_lane = self.pages.pages_per_lane
+            self.prefix_sharing = self.pages.prefix_sharing
+            # whole pages: the splice reads the first n*ps ring slots
+            self._capacity = self.pages.capacity
+            cache_kw = {"page_size": page_size, "num_pages": self.num_pages}
         # registry name (ref|pallas|auto); the registry's backend() falls
         # back to 'ref' silently, so reject typos here where the intent
         # is explicit — a misspelled 'pallas' must not benchmark 'ref'
@@ -279,7 +236,7 @@ class ContinuousBatchingScheduler:
             from repro.core.ops import REGISTRY, resolve_decode_backend
             resolved = resolve_decode_backend(
                 attn_backend, quantized=(kv_dtype == "int8"),
-                paged=self._paged)
+                paged=paged)
             known = REGISTRY.op("decode_attention").backends
             if resolved not in known:
                 raise ValueError(
@@ -289,13 +246,8 @@ class ContinuousBatchingScheduler:
         self.pending: Deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * max_slots
         self._steps_left = np.zeros(max_slots, np.int64)
-        # host_syncs / tokens_generated / prefill_s / decode_s and the
-        # paged counters (admissions, prefix_hits, cow_copies, ...) are
-        # registry-backed properties (see _METRIC_ATTRS at module end):
-        # they read as 0 on a fresh registry and are deliberately NOT
-        # zeroed here so a shared Telemetry keeps its totals across
-        # ServingEngine scheduler rebuilds.
-        # -- request-lifecycle state ------------------------------------
+        # the counters are registry-backed properties, NOT zeroed here so
+        # a shared Telemetry keeps its totals across scheduler rebuilds
         self.eos_id = eos_id
         if max_stop_tokens < 1:
             raise ValueError("max_stop_tokens must be >= 1")
@@ -306,65 +258,50 @@ class ContinuousBatchingScheduler:
         if faults is not None and telemetry is not None \
                 and getattr(faults, "telemetry", None) is None:
             faults.telemetry = telemetry       # injected faults leave traces
-        # lifecycle counters (preemptions, eos_finishes, mask_syncs, ...)
-        # are registry-backed properties too — see _METRIC_ATTRS
         self._tick_no = 0
         self._stall_ticks = 0
         # uids cancelled before we could find them (still pending behind
         # other requests, or mid-admission) — consumed at admission time
         self._cancel_requested: set = set()
-        # host mirror of which lanes have a non-empty stop set: the
-        # periodic done-mask fetch only runs when some live lane could
-        # actually stop early, so stop-free workloads keep the strict
-        # zero-host-syncs-per-token property
+        # which lanes have a non-empty stop set: the done-mask fetch runs
+        # only when a live lane could stop early (zero syncs otherwise)
         self._has_stops = np.zeros(max_slots, bool)
         self._stop_sets: List[frozenset] = [frozenset()] * max_slots
-        # SLO defaults: per-request budgets override these; None+None
-        # means no request enters the goodput denominator unless it
-        # carries its own budget
+        # SLO defaults, overridden per request; with neither budget a
+        # request stays out of the goodput denominator
         self.slo_ttft_s = slo_ttft_s
         self.slo_itl_s = slo_itl_s
-        self.state = self._init_state(seed)
-        # roofline accountant: analytic bytes/flops per decode token
-        # from cache/param METADATA + the host-mirrored lane positions —
-        # pure host arithmetic, so accounting adds zero device→host
-        # transfers (transfer-guard tested).  ``_host_valid`` mirrors
-        # each lane's tokens-in-cache for every layout (the paged path
-        # additionally keeps ``_host_pos`` for page allocation).
-        self._host_valid = np.zeros(max_slots, np.int64)
+        self.state = self._init_state(seed, cache_kw)
+        # host mirror of each lane's position (tokens in cache): the
+        # pager's write position and the roofline accountant's count
+        self._pos = np.zeros(max_slots, np.int64)
+        # roofline accountant: analytic bytes/flops per decode token from
+        # cache/param METADATA + the mirrored positions — host arithmetic
         self.roofline = RooflineAccountant(
             cfg, self.state["cache"], params, batch=max_slots,
-            paged=self._paged, page_size=page_size,
+            paged=paged, page_size=page_size,
             pages_per_lane=getattr(self, "pages_per_lane", 0), hw=hw)
-        # achieved-vs-roofline window anchor: (bytes, flops, tokens,
-        # decode_s) at the last utilization record — deltas are measured
-        # retirement-to-retirement because the retirement fetch is the
-        # scheduler's real sync point
+        # (bytes, flops, tokens, decode_s) at the last utilization record:
+        # deltas run retirement-to-retirement, the real sync points
         self._rf_anchor = (0.0, 0.0, 0, 0.0)
         # the decode step consumes the state it is given (donated): page
         # pools are updated in place, and no leaf of an old state may be
         # held across a step
         self._step_fn = jax.jit(self._step, donate_argnums=1)
         self._deactivate_fn = jax.jit(self._deactivate)
+        # the one admission program of both layouts; the rest below run
+        # in the paged layout only
         self._admit_fn = jax.jit(self._admit, static_argnames=("plen",))
-        if self._paged:
-            self._admit_paged_fn = jax.jit(self._admit_paged,
-                                           static_argnames=("plen",))
-            self._suffix_step_fn = jax.jit(self._suffix_step,
-                                           donate_argnums=1)
-            self._finalize_admit_fn = jax.jit(self._finalize_admit)
-            self._set_pt_row_fn = jax.jit(self._set_pt_row)
-            self._set_pt_entry_fn = jax.jit(self._set_pt_entry)
-            self._copy_page_fn = jax.jit(self._copy_page)
+        self._suffix_step_fn = jax.jit(self._suffix_step, donate_argnums=1)
+        self._finalize_admit_fn = jax.jit(self._finalize_admit)
+        self._set_pt_row_fn = jax.jit(self._set_pt_row)
+        self._set_pt_entry_fn = jax.jit(self._set_pt_entry)
+        self._copy_page_fn = jax.jit(self._copy_page)
 
     # -- device-side state and jitted programs ------------------------------
 
-    def _init_state(self, seed: int) -> Dict[str, Any]:
+    def _init_state(self, seed: int, cache_kw) -> Dict[str, Any]:
         b, cap = self.max_slots, self.max_new_cap
-        cache_kw = {"kv_dtype": self.kv_dtype}
-        if self._paged:
-            cache_kw.update(page_size=self.page_size,
-                            num_pages=self.num_pages)
         routing = {"routing": jnp.zeros((len(self._routing),), jnp.int32)} \
             if self._routing else {}
         return {**routing,
@@ -380,7 +317,8 @@ class ContinuousBatchingScheduler:
             "stop": jnp.full((b, self.max_stop_tokens), -1, jnp.int32),
             "key": jax.random.PRNGKey(seed),
             "cache": self.mod.init_cache(self.cfg, b, self.cache_len,
-                                         jnp.float32, **cache_kw),
+                                         jnp.float32,
+                                         kv_dtype=self.kv_dtype, **cache_kw),
         }
 
     def _decode_slots(self, params, tokens, cache, pos):
@@ -414,7 +352,7 @@ class ContinuousBatchingScheduler:
         cache row, routing counters or None)."""
         kw = {"with_stats": True} if self._routing else {}
         logits, cache1, *stats = self.mod.prefill(
-            self.cfg, params, prompt, self._prefill_len,
+            self.cfg, params, prompt, self._capacity,
             cache_dtype=jnp.float32, **kw)
         return logits, cache1, stats[0] if stats else None
 
@@ -463,74 +401,54 @@ class ContinuousBatchingScheduler:
         subsequent masked writes stay masked."""
         return {**state, "active": state["active"].at[slot].set(False)}
 
+    def _open_lane(self, state, slot, first, plen, temp, budget, stop_row,
+                   key, cache=None):
+        """``state`` with lane ``slot``'s fields written: ``first`` as its
+        token and output #1, at position ``plen``, with the split PRNG
+        ``key`` and, where given, the ``cache`` holding its KV.  A first
+        token in the stop set leaves the lane inactive."""
+        hit = (first == stop_row).any()
+        lane = {
+            "tokens": state["tokens"].at[slot, 0].set(first),
+            "pos": state["pos"].at[slot].set(plen),
+            "temp": state["temp"].at[slot].set(temp),
+            "active": state["active"].at[slot].set(~hit),
+            "budget": state["budget"].at[slot].set(budget),
+            "out_buf": state["out_buf"].at[slot].set(
+                jnp.full((self.max_new_cap,), self.pad_id, jnp.int32)
+                .at[0].set(first)),
+            "out_len": state["out_len"].at[slot].set(1),
+            "stop": state["stop"].at[slot].set(stop_row),
+            "key": key,
+        }
+        if cache is not None:
+            lane["cache"] = cache
+        return {**state, **lane}
+
     def _admit(self, params, state, prompt, slot, temp, budget, stop_row,
-               *, plen):
-        """Prefill one prompt (B=1), sample its first token on device, and
-        splice cache row + lane state into the live batch."""
-        del plen  # static: selects the compiled specialization
+               pages=None, *, plen):
+        """Prefill one prompt (B=1), sample its first token on device
+        (one PRNG split, from the last prefill logits), and open lane
+        ``slot`` over the prompt's KV: spliced into its ring row, or into
+        ``pages`` with its table row rewritten.  ``plen`` is static."""
         logits, cache1, stats = self._prefill(params, prompt)
         # quantize/cast AFTER the float prefill so admission pays the
         # conversion once, and the spliced row matches the live layout
         cache1 = self.mod.cache_to_kv_dtype(self.cfg, cache1, self.kv_dtype)
         key, sub = jax.random.split(state["key"])
         first = _sample(sub, logits[:, -1], temp[None])[0]
-        cache = jax.tree.map(lambda c, c1: c.at[:, slot].set(c1[:, 0]),
-                             state["cache"], cache1)
-        cap = self.max_new_cap
-        # the first sampled token can itself be a stop token
-        hit = (first == stop_row).any()
-        return {
-            **state,
-            "tokens": state["tokens"].at[slot, 0].set(first),
-            "pos": state["pos"].at[slot].set(prompt.shape[1]),
-            "temp": state["temp"].at[slot].set(temp),
-            "active": state["active"].at[slot].set(~hit),
-            "budget": state["budget"].at[slot].set(budget),
-            "out_buf": state["out_buf"].at[slot].set(
-                jnp.full((cap,), self.pad_id, jnp.int32)
-                .at[0].set(first)),
-            "out_len": state["out_len"].at[slot].set(1),
-            "stop": state["stop"].at[slot].set(stop_row),
-            "key": key,
-            "cache": cache,
-            **self._count_routing(state, stats),
-        }
+        if pages is None:
+            cache = jax.tree.map(lambda c, c1: c.at[:, slot].set(c1[:, 0]),
+                                 state["cache"], cache1)
+        else:
+            cache = self.mod.cache_splice_paged(self.cfg, state["cache"],
+                                                cache1, slot, pages,
+                                                self.page_size)
+        return {**self._open_lane(state, slot, first, plen, temp, budget,
+                                  stop_row, key, cache),
+                **self._count_routing(state, stats)}
 
-    # -- paged jitted programs (page table updates, COW, admission) ----------
-
-    def _admit_paged(self, params, state, prompt, slot, temp, budget,
-                     pages, stop_row, *, plen):
-        """Paged cold-path admission: prefill the full prompt (B=1 ring
-        row), scatter its KV blocks into the lane's freshly allocated
-        ``pages``, rewrite the lane's table row, and splice lane state.
-        Same PRNG discipline as :meth:`_admit` (one split, first token
-        sampled from the last prefill logits)."""
-        del plen  # static: selects the compiled specialization
-        logits, cache1, stats = self._prefill(params, prompt)
-        cache1 = self.mod.cache_to_kv_dtype(self.cfg, cache1, self.kv_dtype)
-        key, sub = jax.random.split(state["key"])
-        first = _sample(sub, logits[:, -1], temp[None])[0]
-        cache = self.mod.cache_splice_paged(self.cfg, state["cache"],
-                                            cache1, slot, pages,
-                                            self.page_size)
-        cap = self.max_new_cap
-        hit = (first == stop_row).any()
-        return {
-            **state,
-            "tokens": state["tokens"].at[slot, 0].set(first),
-            "pos": state["pos"].at[slot].set(prompt.shape[1]),
-            "temp": state["temp"].at[slot].set(temp),
-            "active": state["active"].at[slot].set(~hit),
-            "budget": state["budget"].at[slot].set(budget),
-            "out_buf": state["out_buf"].at[slot].set(
-                jnp.full((cap,), self.pad_id, jnp.int32)
-                .at[0].set(first)),
-            "out_len": state["out_len"].at[slot].set(1),
-            "stop": state["stop"].at[slot].set(stop_row),
-            "key": key,
-            "cache": cache,
-            **self._count_routing(state, stats),
-        }
+    # -- paged jitted programs (suffix prefill, page table updates, COW) -----
 
     def _suffix_step(self, params, state, tok, slot, pos_scalar):
         """One suffix-prefill step for a prefix-cache hit: feed ``tok``
@@ -558,25 +476,11 @@ class ContinuousBatchingScheduler:
                         stop_row):
         """Close a prefix-hit admission: one PRNG split (mirroring
         :meth:`_admit`), sample the first output token from the last
-        suffix-step logits, splice lane scalars."""
+        suffix-step logits, open the lane."""
         key, sub = jax.random.split(state["key"])
         first = _sample(sub, logits[None], temp[None])[0]
-        cap = self.max_new_cap
-        hit = (first == stop_row).any()
-        return {
-            **state,
-            "tokens": state["tokens"].at[slot, 0].set(first),
-            "pos": state["pos"].at[slot].set(plen),
-            "temp": state["temp"].at[slot].set(temp),
-            "active": state["active"].at[slot].set(~hit),
-            "budget": state["budget"].at[slot].set(budget),
-            "out_buf": state["out_buf"].at[slot].set(
-                jnp.full((cap,), self.pad_id, jnp.int32)
-                .at[0].set(first)),
-            "out_len": state["out_len"].at[slot].set(1),
-            "stop": state["stop"].at[slot].set(stop_row),
-            "key": key,
-        }
+        return self._open_lane(state, slot, first, plen, temp, budget,
+                               stop_row, key)
 
     def _set_pt_row(self, state, slot, row):
         cache = dict(state["cache"])
@@ -599,23 +503,9 @@ class ContinuousBatchingScheduler:
         return {**state, "cache": cache}
 
     # -- telemetry plumbing --------------------------------------------------
-    # Every hook below is host-only (time.perf_counter + dict appends):
-    # telemetry can never add a device->host transfer, so the
-    # zero-host-syncs-per-token invariant holds with tracing on.  What
-    # each timestamp MEANS under async dispatch is documented in
-    # runtime/telemetry.py and docs/serving.md — in short, span ends
-    # measure dispatch, and the per-token latency histograms are
-    # anchored at the real sync points (retirement fetch, done-mask
-    # fetch).
-
-    def _pt_update(self, slot: int, fn, *args) -> None:
-        """Dispatch one page-table update (``fn`` one of the
-        ``_set_pt_*``/``_copy_page`` programs) for lane ``slot``.  Each
-        returns a new state, so it copies the whole pool on the device:
-        ``sched.pt_updates`` counts them."""
-        with Span("pt_update", self._tracer, slot=slot):
-            self.state = fn(self.state, *args)
-        self.metrics.counter("sched.pt_updates").inc()
+    # Every hook below is host-only, so tracing adds no device->host
+    # transfer; span ends measure dispatch, and the latency histograms
+    # are anchored at the real sync points (retirement, done-mask fetch).
 
     def _record_routing(self, totals) -> None:
         """Fold the device's routing counters, fetched with a retirement,
@@ -642,7 +532,6 @@ class ContinuousBatchingScheduler:
         the FIRST admission so preempt/re-admit cycles don't re-count."""
         now = time.perf_counter()
         queue_s = t_pop - req.submitted_at
-        self._host_valid[slot] = plen     # roofline: tokens in cache
         self.metrics.histogram("req.queue_s").record(queue_s)
         rt = self._rt(req.uid)
         if rt is not None:
@@ -718,6 +607,7 @@ class ContinuousBatchingScheduler:
         retirements (``Request.diagnostics``) and to the no-progress
         watchdog error."""
         now = time.perf_counter()
+        pool = self._pool_stats()
         return {
             "tick": self._tick_no,
             "last_tick_ms": round(self._last_tick_s * 1e3, 3),
@@ -725,75 +615,64 @@ class ContinuousBatchingScheduler:
                             for r in self.slots if r is not None},
             "pending_uids": [r.uid for r in self.pending],
             "free_lanes": sum(r is None for r in self.slots),
-            "free_pages": self.pool.available() if self._paged else None,
-            "pool_occupancy_frac": (
-                1.0 - self.pool.available() / self.num_pages
-                if self._paged else None),
-            "prefix_hit_ratio": (
-                self.prefix_hits / self.admissions
-                if self._paged and self.admissions else None),
+            **{k: pool[k] for k in ("free_pages", "pool_occupancy_frac",
+                                    "prefix_hit_ratio")},
         }
 
     # -- host-side page bookkeeping ------------------------------------------
+    # ``self.pages`` decides which page and returns the table edits; the
+    # scheduler decides when, and applies them through :meth:`_apply`.
 
-    def _alloc_pages(self, n: int, *, site: str = "",
-                     slot: Optional[int] = None) -> Optional[List[int]]:
-        """Claim ``n`` pages, evicting LRU prefix-cache entries under
-        pressure; None when the pool genuinely cannot supply them.  The
-        fault injector is consulted FIRST so an injected failure models
-        hard exhaustion (no eviction rescue) deterministically."""
-        if self.faults is not None and self.faults.on_alloc(
-                site, tick=self._tick_no, slot=slot, n=n):
-            self.metrics.counter("faults.alloc_failures").inc()
-            return None
-        pages = self.pool.alloc(n)
-        while pages is None and self.pool.evict_one():
-            pages = self.pool.alloc(n)
-        return pages
+    def _apply(self, edit) -> None:
+        """Write one page-table edit to the device with its program.  Each
+        copies the whole pool on the device: ``sched.pt_updates``."""
+        fn = {RowEdit: self._set_pt_row_fn, EntryEdit: self._set_pt_entry_fn,
+              CopyEdit: self._copy_page_fn}[type(edit)]
+        args = [jnp.asarray(a, jnp.int32) for a in edit]
+        with Span("pt_update", self._tracer, slot=edit.slot):
+            self.state = fn(self.state, *args)
+        self.metrics.counter("sched.pt_updates").inc()
+
+    def _pool_stats(self) -> Dict[str, Any]:
+        """The page pool's free pages, occupancy, prefix cache and KV bytes
+        resident.  The ring has no pool: its buffers are all resident."""
+        cache = self.state["cache"]
+        if self.pages is None:
+            return {"free_pages": None, "pool_occupancy_frac": None,
+                    "prefix_hit_ratio": None, "prefix_entries": 0,
+                    "kv_bytes_resident": sum(int(v.size) * v.dtype.itemsize
+                                             for v in cache.values())}
+        return {"free_pages": self.pool.available(),
+                "pool_occupancy_frac": self.pages.occupancy(),
+                "prefix_hit_ratio": (self.prefix_hits / self.admissions
+                                     if self.admissions else None),
+                "prefix_entries": self.pool.prefix_entries(),
+                "kv_bytes_resident": self.pages.resident_bytes(cache)}
+
+    def _alloc_refused(self, site: str, slot: Optional[int], n: int) -> bool:
+        """Whether the fault injector fails this page allocation (hard
+        exhaustion: no eviction rescue)."""
+        return self.faults is not None and self.faults.on_alloc(
+            site, tick=self._tick_no, slot=slot, n=n)
 
     def _ensure_writable(self, slot: int, pos: int, site: str = "") -> bool:
-        """Guarantee lane ``slot`` exclusively owns the page its write at
-        ``pos`` lands in: allocate on first touch, copy-on-write when the
-        page is shared (prefix reuse keeps refcount > 1).  Invariant:
-        every non-garbage entry in a lane's table row holds exactly one
-        refcount on behalf of that lane.
-
-        Returns False — WITHOUT raising — when the pool cannot supply
-        the page even after LRU eviction; the caller preempts a lane to
-        free pages and retries."""
-        idx = (pos % self._capacity) // self.page_size
-        phys = int(self._pt_host[slot, idx])
-        if phys == GARBAGE_PAGE:
-            got = self._alloc_pages(1, site=site + "first_touch", slot=slot)
-            if got is None:
-                return False
-            self._pt_host[slot, idx] = got[0]
-            self._pt_update(slot, self._set_pt_entry_fn, jnp.int32(slot),
-                            jnp.int32(idx), jnp.int32(got[0]))
-        elif self.pool.refcount[phys] > 1:
-            got = self._alloc_pages(1, site=site + "cow", slot=slot)
-            if got is None:
-                return False
-            self._pt_host[slot, idx] = got[0]
-            self._pt_update(slot, self._copy_page_fn, jnp.int32(phys),
-                            jnp.int32(got[0]), jnp.int32(slot),
-                            jnp.int32(idx))
-            self.pool.free(phys)               # drop the lane's shared ref
-            self.cow_copies += 1
-        return True
+        """Make lane ``slot`` own the page its write at ``pos`` lands in
+        (first touch / copy-on-write).  False when the pool cannot
+        supply it: the caller preempts a lane to free pages and
+        retries."""
+        edits = self.pages.writable(slot, pos, site)
+        for edit in edits or ():
+            self._apply(edit)
+        return edits is not None
 
     def _prepare_writes(self, extra: Optional[int] = None) -> None:
-        """Run the COW/allocation check for every lane about to write —
-        all active lanes with steps left, plus ``extra`` (a lane mid
-        suffix-prefill).  Called before every device step that writes
-        KV; 'full' allocation mode owns all pages up-front so only
-        incremental mode does work here.
-
-        When a page cannot be supplied, the lowest-priority lane is
-        preempted (releasing its pages) and the check retries — the
-        writing lane itself is the last candidate, in which case it is
-        preempted instead of written."""
-        if self._alloc_mode != "incremental":
+        """Run the COW/allocation check for every lane about to write
+        (except ``extra``, a lane mid suffix-prefill) before a device
+        step that writes KV; 'full' allocation mode owns all pages
+        up-front.  When a page cannot be supplied, the lowest-priority
+        lane is preempted and the check retries — the writing lane
+        itself is the last candidate."""
+        if self.pages is None or self.pages.alloc_mode != "incremental":
             return
         with Span("prepare_writes", self._tracer):
             for slot in range(self.max_slots):
@@ -802,7 +681,7 @@ class ContinuousBatchingScheduler:
                 while self.slots[slot] is not None \
                         and self._steps_left[slot] > 0 \
                         and not self._ensure_writable(
-                            slot, int(self._host_pos[slot])):
+                            slot, int(self._pos[slot])):
                     victim = self._preempt_lowest(protect=extra)
                     if victim is None or victim == slot:
                         break
@@ -851,31 +730,22 @@ class ContinuousBatchingScheduler:
         self.tokens_generated += n
         req.prompt = list(req.prompt) + produced
         req.max_new_tokens -= n
-        self.slots[slot] = None
-        self._steps_left[slot] = 0
-        self._host_valid[slot] = 0
-        self._set_stop_host(slot, None)
         self.state = self._deactivate_fn(self.state, jnp.int32(slot))
-        if self._paged:
-            self._release_lane_pages(slot)
+        self._free_lane(slot)
         self.pending.appendleft(req)
         self.preemptions += 1
         rt = self._rt(req.uid)
         if rt is not None:
             rt.preempted(n)
 
-    def _release_lane_pages(self, slot: int) -> None:
-        """Drop the lane's reference on every page in its table row and
-        zero the row on host AND device — a retired lane's stale mapping
-        must never alias a reallocated page."""
-        for idx in range(self.pages_per_lane):
-            phys = int(self._pt_host[slot, idx])
-            if phys != GARBAGE_PAGE:
-                self.pool.free(phys)
-        self._pt_host[slot] = 0
-        self._host_pos[slot] = 0
-        self._pt_update(slot, self._set_pt_row_fn, jnp.int32(slot),
-                        jnp.zeros((self.pages_per_lane,), jnp.int32))
+    def _free_lane(self, slot: int) -> None:
+        """Give lane ``slot`` back, with every page it held."""
+        self.slots[slot] = None
+        self._steps_left[slot] = 0
+        self._pos[slot] = 0
+        self._set_stop_host(slot, None)
+        if self.pages is not None:
+            self._apply(self.pages.release(slot))
 
     # -- host-side scheduling ------------------------------------------------
 
@@ -893,50 +763,28 @@ class ContinuousBatchingScheduler:
                     f"stop tokens exceed max_stop_tokens="
                     f"{self.max_stop_tokens}")
             plen = self._bucket(len(request.prompt))
-            # the last decode step writes KV at position plen + max_new - 2
-            # (the final sampled token is never fed back), so any request
-            # with plen + max_new_tokens - 1 > window would wrap the cache
-            # mid-decode and corrupt its own prefix.  Families whose window
-            # wraps by design (rglru's local attention) or that have no KV
-            # ring at all (rwkv6) set RING_WRAP_SAFE and skip the guard.
-            wrap_safe = getattr(self.mod, "RING_WRAP_SAFE", False)
-            if self._paged:
-                # pool-capacity guard (the old cache_len bound is obsolete:
-                # a lane's logical window wraps at pages_per_lane * page_size
-                # like the ring did, but pages must EXIST in the pool)
-                if plen > self._capacity:
-                    raise ValueError(
-                        f"request {request.uid}: prompt length "
-                        f"{len(request.prompt)} (padded to {plen}) exceeds "
-                        f"the paged lane capacity {self._capacity} "
-                        f"({self.pages_per_lane} pages x {self.page_size})")
-                if not wrap_safe and \
-                        plen + request.max_new_tokens - 1 > self._capacity:
-                    raise ValueError(
-                        f"request {request.uid}: prompt ({plen} padded) + "
-                        f"max_new_tokens ({request.max_new_tokens}) would "
-                        f"wrap the paged window ({self._capacity}) mid-decode "
-                        "and corrupt the prompt prefix; shrink one of them")
-                need = min(-(-(plen + request.max_new_tokens)
-                             // self.page_size), self.pages_per_lane)
-                if need > self.num_pages - 1:
-                    raise ValueError(
-                        f"request {request.uid}: needs {need} pages but the "
-                        f"pool holds only {self.num_pages - 1} allocatable "
-                        f"(num_pages={self.num_pages} incl. garbage page)")
-            elif plen > self.cache_len:
+            # a lane holds ``_capacity`` tokens (cache_len, or whole
+            # pages).  The last decode step writes KV at plen + max_new - 2
+            # (the final sampled token is never fed back), so beyond that
+            # a request would wrap its window and corrupt its own prefix.
+            # Families whose window wraps by design (rglru) or that have
+            # no KV ring (rwkv6) set RING_WRAP_SAFE and skip that guard.
+            cap = self._capacity
+            where = (f"the {self.kv_layout} lane capacity {cap} "
+                     f"(cache_len={self.cache_len})")
+            if plen > cap:
                 raise ValueError(
                     f"request {request.uid}: prompt length "
                     f"{len(request.prompt)} (padded to {plen} by the prefill "
-                    f"bucket) exceeds cache_len={self.cache_len} — the ring "
-                    f"cache would wrap during prefill and corrupt the prefix")
-            elif not wrap_safe and \
-                    plen + request.max_new_tokens - 1 > self.cache_len:
+                    f"bucket) exceeds {where} — the cache would wrap during "
+                    "prefill and corrupt the prefix")
+            if not getattr(self.mod, "RING_WRAP_SAFE", False) and \
+                    plen + request.max_new_tokens - 1 > cap:
                 raise ValueError(
                     f"request {request.uid}: prompt ({plen} padded) + "
                     f"max_new_tokens ({request.max_new_tokens}) would wrap "
-                    f"the ring cache (cache_len={self.cache_len}) mid-decode "
-                    "and corrupt the prompt prefix; shrink one of them")
+                    f"{where} mid-decode and corrupt the prompt prefix; "
+                    "shrink one of them")
             rt = self._rt(request.uid)
             if rt is not None:
                 rt.submitted(len(request.prompt), request.max_new_tokens)
@@ -984,35 +832,24 @@ class ContinuousBatchingScheduler:
                 # before any device work or page refs
                 if req.uid in self._cancel_requested:
                     self._cancel_requested.discard(req.uid)
-                    self._finish_dropped(req, "cancelled")
+                    self._finish(req, "cancelled")
                     continue
                 if self._deadline_expired(req):
-                    self._finish_dropped(req, "timeout")
+                    self._finish(req, "timeout")
                     continue
                 if self.faults is not None:
                     self.faults.on_admission(req, tick=self._tick_no,
                                              scheduler=self)
                     if req.uid in self._cancel_requested:
                         self._cancel_requested.discard(req.uid)
-                        self._finish_dropped(req, "cancelled")
+                        self._finish(req, "cancelled")
                         continue
                 plen = self._bucket(len(req.prompt))
                 toks = np.full((1, plen), self.pad_id, np.int32)
                 toks[0, plen - len(req.prompt):] = req.prompt  # left-pad
                 with Span("admit", self._tracer, uid=req.uid, slot=slot,
-                                plen=plen):
-                    if self._paged:
-                        verdict = self._admit_paged_host(req, slot, toks,
-                                                         plen)
-                    else:
-                        verdict = "ok"
-                        with Span("prefill", self._tracer):
-                            self.state = self._admit_fn(
-                                self.params, self.state, jnp.asarray(toks),
-                                jnp.int32(slot),
-                                jnp.float32(req.temperature),
-                                jnp.int32(req.max_new_tokens),
-                                self._stop_row(req), plen=plen)
+                          plen=plen):
+                    verdict = self._admit_lane(req, slot, toks, plen)
                 if verdict == "dropped":
                     continue                   # cancelled mid-admission
                 if verdict == "defer":
@@ -1028,6 +865,7 @@ class ContinuousBatchingScheduler:
                 self._set_stop_host(slot, req)
                 # the sampled-at-prefill first token is output token #1
                 self._steps_left[slot] = req.max_new_tokens - 1
+                self._pos[slot] = plen
                 self._record_admit(req, slot, plen, t_pop)
                 admitted = True
                 break
@@ -1035,118 +873,100 @@ class ContinuousBatchingScheduler:
             self.prefill_s += time.perf_counter() - t0
         return admitted
 
-    def _admit_paged_host(self, req: Request, slot: int, toks: np.ndarray,
-                          plen: int) -> str:
-        """Paged admission: prefix-cache lookup first (map shared pages
-        read-only and prefill only the suffix), else allocate pages and
-        run the full prefill + splice.
+    def _admit_lane(self, req: Request, slot: int, toks: np.ndarray,
+                    plen: int) -> str:
+        """Open lane ``slot`` for ``req``.  The ring layout has no pages
+        to take.  In the paged layout a prefix-cache hit maps the cached
+        pages and prefills only the suffix; a miss takes fresh pages.
 
         Returns ``"ok"``, ``"defer"`` (pool cannot supply the pages even
         after LRU eviction and preemption — requeue), or ``"dropped"``
         (cancelled mid-admission — request finished, do not requeue).
-        Both failure paths fully unwind: every ref this admission took
-        is released and the counters roll back, so an aborted prefix-hit
-        leaks nothing."""
-        ps = self.page_size
-        npages = self.pages_per_lane if self._alloc_mode == "full" \
-            else -(-plen // ps)
-        self.admissions += 1
-        self.prefill_tokens_total += plen
+        Both failure paths release every ref this admission took, and
+        only an admission that opens its lane is counted."""
+        if self.pages is None:
+            self._prefill_lane(req, slot, toks, plen)
+            return "ok"
         with Span("prefix_lookup", self._tracer, tokens=plen):
             key_tokens = [int(t) for t in toks[0]]
-            entry = self.pool.prefix_lookup(key_tokens) \
-                if self.prefix_sharing else None
+            entry = self.pages.lookup(key_tokens)
         if entry is not None:
-            # cap the reused length at plen - 1 so at least one suffix
-            # step runs — its logits seed the first sampled token
-            t = min(entry.length, plen - 1)
-            nshared = -(-t // ps)
-            shared = list(entry.pages[:nshared])
-            self.prefix_hits += 1
-            self.prefill_tokens_saved += t
-            rt = self._rt(req.uid)
-            if rt is not None:
-                rt.prefix_lookup(True, t)
-            for p in shared:
-                self.pool.ref(p)
-            self._pt_host[slot] = 0
-            self._pt_host[slot, :nshared] = shared
-            row = np.zeros((self.pages_per_lane,), np.int32)
-            row[:nshared] = shared
-            self._pt_update(slot, self._set_pt_row_fn, jnp.int32(slot),
-                            jnp.asarray(row))
-            # suffix prefill: one batched step per remaining prompt token
-            logits = None
-            aborted = None
-            with Span("suffix_prefill", self._tracer, uid=req.uid,
-                            tokens=plen - t):
-                for i in range(t, plen):
-                    if self.faults is not None:
-                        self.faults.on_suffix_step(req, slot, i,
-                                                   tick=self._tick_no,
-                                                   scheduler=self)
-                    if req.uid in self._cancel_requested:
-                        self._cancel_requested.discard(req.uid)
-                        aborted = "dropped"
-                        break
-                    self._prepare_writes(extra=slot)
-                    while not self._ensure_writable(slot, i,
-                                                    site="suffix:"):
-                        if self._preempt_lowest(protect=slot) is None:
-                            aborted = "defer"
-                            break
-                    if aborted:
-                        break
-                    logits, self.state = self._suffix_step_fn(
-                        self.params, self.state, jnp.int32(toks[0, i]),
-                        jnp.int32(slot), jnp.int32(i))
-            if aborted:
-                # unwind: drop every ref this lane holds (shared pages
-                # it mapped AND pages the suffix loop allocated/COW'd)
-                # and roll the admission counters back
-                self._release_lane_pages(slot)
-                self.admissions -= 1
-                self.prefix_hits -= 1
-                self.prefill_tokens_total -= plen
-                self.prefill_tokens_saved -= t
-                if aborted == "dropped":
-                    self._finish_dropped(req, "cancelled")
-                return aborted
-            self.state = self._finalize_admit_fn(
-                self.state, logits, jnp.int32(slot),
-                jnp.float32(req.temperature),
-                jnp.int32(req.max_new_tokens), jnp.int32(plen),
-                self._stop_row(req))
+            verdict = self._admit_prefix_hit(req, slot, toks, plen, entry)
+            if verdict != "ok":
+                return verdict
         else:
             rt = self._rt(req.uid)
             if rt is not None:
                 rt.prefix_lookup(False, 0)
-            with Span("alloc", self._tracer, pages=npages):
-                pages = self._alloc_pages(npages, site="admission",
-                                          slot=slot)
+            n = self.pages.pages_for(plen)
+            with Span("alloc", self._tracer, pages=n):
+                pages = self.pages.take(slot, n)
             if pages is None:
-                self.admissions -= 1
-                self.prefill_tokens_total -= plen
                 return "defer"
-            self._pt_host[slot] = 0
-            self._pt_host[slot, :npages] = pages
-            with Span("prefill", self._tracer):
-                self.state = self._admit_paged_fn(
-                    self.params, self.state, jnp.asarray(toks),
-                    jnp.int32(slot), jnp.float32(req.temperature),
-                    jnp.int32(req.max_new_tokens),
-                    jnp.asarray(pages, jnp.int32), self._stop_row(req),
-                    plen=plen)
-        self._host_pos[slot] = plen
-        if self.prefix_sharing:
-            # publish this lane's page-aligned prefixes (and the full
-            # prompt).  COW keeps the entries pristine once the lane
-            # decodes past them.
-            span_full = -(-plen // ps)
+            self._prefill_lane(req, slot, toks, plen, pages)
+        self.admissions += 1
+        self.prefill_tokens_total += plen
+        if self.pages.prefix_sharing:
             with Span("prefix_register", self._tracer, tokens=plen):
-                self.pool.prefix_register(
-                    key_tokens,
-                    [int(p) for p in self._pt_host[slot, :span_full]])
+                self.pages.register(slot, key_tokens)
+        return "ok"
+
+    def _prefill_lane(self, req: Request, slot: int, toks: np.ndarray,
+                      plen: int, pages: Optional[np.ndarray] = None) -> None:
+        """Dispatch the admission program for lane ``slot``."""
+        with Span("prefill", self._tracer):
+            self.state = self._admit_fn(
+                self.params, self.state, jnp.asarray(toks), jnp.int32(slot),
+                jnp.float32(req.temperature), jnp.int32(req.max_new_tokens),
+                self._stop_row(req), pages, plen=plen)
+
+    def _admit_prefix_hit(self, req: Request, slot: int, toks: np.ndarray,
+                          plen: int, entry) -> str:
+        """Map the hit's shared pages into lane ``slot``, prefill the
+        rest of the prompt one batched suffix step per token, and open
+        the lane from the last step's logits."""
+        t, row_edit = self.pages.map_prefix(slot, entry, plen)
+        rt = self._rt(req.uid)
+        if rt is not None:
+            rt.prefix_lookup(True, t)
+        self._apply(row_edit)
+        logits = None
+        aborted = None
+        with Span("suffix_prefill", self._tracer, uid=req.uid,
+                  tokens=plen - t):
+            for i in range(t, plen):
+                if self.faults is not None:
+                    self.faults.on_suffix_step(req, slot, i,
+                                               tick=self._tick_no,
+                                               scheduler=self)
+                if req.uid in self._cancel_requested:
+                    self._cancel_requested.discard(req.uid)
+                    aborted = "dropped"
+                    break
+                self._prepare_writes(extra=slot)
+                while not self._ensure_writable(slot, i, site="suffix:"):
+                    if self._preempt_lowest(protect=slot) is None:
+                        aborted = "defer"
+                        break
+                if aborted:
+                    break
+                logits, self.state = self._suffix_step_fn(
+                    self.params, self.state, jnp.int32(toks[0, i]),
+                    jnp.int32(slot), jnp.int32(i))
+        if aborted:
+            # unwind: drop every ref this lane holds (shared pages it
+            # mapped AND pages the suffix loop allocated/COW'd)
+            self._apply(self.pages.release(slot))
+            if aborted == "dropped":
+                self._finish(req, "cancelled")
+            return aborted
+        self.state = self._finalize_admit_fn(
+            self.state, logits, jnp.int32(slot),
+            jnp.float32(req.temperature),
+            jnp.int32(req.max_new_tokens), jnp.int32(plen),
+            self._stop_row(req))
+        self.prefix_hits += 1
+        self.prefill_tokens_saved += t
         return "ok"
 
     def _retire_slot(self, slot: int, reason: str,
@@ -1184,28 +1004,12 @@ class ContinuousBatchingScheduler:
             if reason == "eos":
                 self.eos_finishes += 1
                 self.eos_steps_saved += max(req.max_new_tokens - n, 0)
-            elif reason == "cancelled":
-                self.cancellations += 1
-            elif reason == "timeout":
-                self.deadline_misses += 1
-            if reason in ("cancelled", "timeout"):
+            elif reason in ("cancelled", "timeout"):
                 # the lane may still be active on device: mask it out so its
                 # writes stop before the slot is reused
                 self.state = self._deactivate_fn(self.state, jnp.int32(slot))
-            req.finish_reason = reason
-            req.done = True
-            req.finished_at = time.perf_counter()
-            if reason in ("cancelled", "timeout"):
-                # attach the why-did-this-die snapshot before the lane state
-                # is torn down (satellite: "stuck" becomes a diagnosis)
-                req.diagnostics = self.telemetry_snapshot()
-            self._record_finish(req)
-            self.slots[slot] = None
-            self._steps_left[slot] = 0
-            self._host_valid[slot] = 0
-            self._set_stop_host(slot, None)
-            if self._paged:
-                self._release_lane_pages(slot)
+            self._finish(req, reason)
+            self._free_lane(slot)
 
     def _retire_finished(self) -> None:
         for slot, req in enumerate(self.slots):
@@ -1213,18 +1017,20 @@ class ContinuousBatchingScheduler:
                 continue
             self._retire_slot(slot, "length")
 
-    def _finish_dropped(self, req: Request, reason: str) -> None:
-        """Finish a request that never reached a lane (cancelled or
-        expired while pending) — no device state to unwind."""
+    def _finish(self, req: Request, reason: str) -> None:
+        """Close ``req`` with ``reason`` and record it.  A cancelled or
+        timed-out request carries the why-did-this-die snapshot, taken
+        before its lane (if it holds one) is freed."""
         req.finish_reason = reason
         req.done = True
         req.finished_at = time.perf_counter()
-        req.diagnostics = self.telemetry_snapshot()
+        if reason in ("cancelled", "timeout"):
+            req.diagnostics = self.telemetry_snapshot()
+            if reason == "cancelled":
+                self.cancellations += 1
+            else:
+                self.deadline_misses += 1
         self._record_finish(req)
-        if reason == "cancelled":
-            self.cancellations += 1
-        elif reason == "timeout":
-            self.deadline_misses += 1
 
     def cancel(self, uid: int) -> bool:
         """Cancel a request by uid.  A pending request is dropped before
@@ -1237,7 +1043,7 @@ class ContinuousBatchingScheduler:
                 # identity-based removal: Request is a dataclass with
                 # field equality, and two requests can be field-equal
                 self.pending = deque(x for x in self.pending if x is not r)
-                self._finish_dropped(r, "cancelled")
+                self._finish(r, "cancelled")
                 return True
         for slot, req in enumerate(self.slots):
             if req is not None and req.uid == uid:
@@ -1259,7 +1065,7 @@ class ContinuousBatchingScheduler:
             self.pending = deque(x for x in self.pending
                                  if not any(x is r for r in expired))
             for r in expired:
-                self._finish_dropped(r, "timeout")
+                self._finish(r, "timeout")
 
     def _reconcile_eos(self) -> None:
         """Periodic done-mask fetch: retire lanes whose device-side stop
@@ -1296,9 +1102,6 @@ class ContinuousBatchingScheduler:
             tick_span.record = any(did.values())
             tick_span.args = {"tick": self._tick_no, **did,
                               "pending": len(self.pending)}
-        if self._tracer is not None and tick_span.record and self._paged:
-            self._tracer.counter_event("free_pages",
-                                       {"free": self.pool.available()})
         return busy
 
     def _tick(self):
@@ -1314,9 +1117,8 @@ class ContinuousBatchingScheduler:
         admitted = self._admit_pending()
         t0 = time.perf_counter()
         worked = False
-        if self._paged and any(self._steps_left[s] > 0
-                               for s, r in enumerate(self.slots)
-                               if r is not None):
+        if any(self._steps_left[s] > 0
+               for s, r in enumerate(self.slots) if r is not None):
             # every writing lane must own its target page before the
             # step lands (first-touch allocation / copy-on-write) —
             # this can preempt lanes, so re-check below
@@ -1336,16 +1138,14 @@ class ContinuousBatchingScheduler:
             # device reads
             with Span("account", self._tracer):
                 rf_bytes, rf_flops = self.roofline.step_cost(
-                    [int(self._host_valid[s]) for s in work])
+                    [int(self._pos[s]) for s in work])
                 self.metrics.counter("roofline.analytic_bytes").inc(rf_bytes)
                 self.metrics.counter("roofline.analytic_flops").inc(rf_flops)
                 self.metrics.counter("roofline.tokens").inc(len(work))
                 for slot in work:
                     req = self.slots[slot]
                     self._steps_left[slot] -= 1
-                    self._host_valid[slot] += 1
-                    if self._paged:
-                        self._host_pos[slot] += 1
+                    self._pos[slot] += 1
                     rt = self._rt(req.uid)
                     if rt is not None:
                         rt.progressed(req.max_new_tokens
@@ -1380,21 +1180,23 @@ class ContinuousBatchingScheduler:
                     self._last_tick_s)
             self.metrics.gauge("sched.live_lanes").set(
                 sum(r is not None for r in self.slots))
-            if self._paged:
-                self.metrics.gauge("pool.free_pages").set(
-                    self.pool.available())
+            if self.pages is not None:
+                free = self.pool.available()
+                self.metrics.gauge("pool.free_pages").set(free)
                 self.metrics.gauge("pool.occupancy_frac").set(
-                    1.0 - self.pool.available() / self.num_pages)
+                    self.pages.occupancy())
                 if self.admissions:
                     self.metrics.gauge("sched.prefix_hit_ratio").set(
                         self.prefix_hits / self.admissions)
+                if self._tracer is not None and (admitted or worked
+                                                 or retired):
+                    self._tracer.counter_event("free_pages", {"free": free})
         return busy, {"admitted": admitted, "worked": worked,
                       "retired": retired}
 
     def _raise_stalled(self) -> None:
         lanes = [f"slot {s}: uid={r.uid} steps_left="
-                 f"{int(self._steps_left[s])}"
-                 + (f" pos={int(self._host_pos[s])}" if self._paged else "")
+                 f"{int(self._steps_left[s])} pos={int(self._pos[s])}"
                  for s, r in enumerate(self.slots) if r is not None]
         snap = self.telemetry_snapshot()
         raise RuntimeError(
@@ -1415,35 +1217,19 @@ class ContinuousBatchingScheduler:
             pass
 
     def free_slots(self) -> FreeCapacity:
-        """Free admission capacity: open decode lanes, and (paged layout
-        only) allocatable pages in the pool — ``pages`` is None for the
-        ring layout, where lanes are the only resource."""
-        lanes = sum(r is None for r in self.slots)
-        pages = self.pool.available() if self._paged else None
-        return FreeCapacity(lanes, pages)
+        """Free admission capacity: open decode lanes, and allocatable
+        pages in the pool (None for the ring layout: lanes only)."""
+        return FreeCapacity(sum(r is None for r in self.slots),
+                            self._pool_stats()["free_pages"])
 
     def kv_bytes_resident(self) -> int:
-        """Device bytes actually holding KV state right now.  Ring: the
-        full per-lane buffers (allocated whether or not a lane is live).
-        Paged: only the referenced pages, plus the page-table and
-        refcount bookkeeping arrays — the number the ISSUE's residency
-        claim is measured on."""
-        cache = self.state["cache"]
-        if not self._paged:
-            return sum(int(v.size) * v.dtype.itemsize
-                       for k, v in cache.items())
-        used = self.num_pages - self.pool.available()
-        total = 0
-        for k, v in cache.items():
-            nbytes = int(v.size) * v.dtype.itemsize
-            if k.endswith("_pages"):
-                total += (nbytes // self.num_pages) * used
-            else:                   # page_table + dense per-lane leaves
-                total += nbytes
-        return total + self.pool.refcount.nbytes
+        """Device bytes holding KV state now: the ring's whole buffers, or
+        the referenced pages plus the page table and refcounts."""
+        return self._pool_stats()["kv_bytes_resident"]
 
     def paged_stats(self) -> Dict[str, Any]:
         """Prefix-cache / paging counters for benchmarks and tests."""
+        pool = self._pool_stats()
         return {
             "layout": self.kv_layout,
             "admissions": self.admissions,
@@ -1458,10 +1244,8 @@ class ContinuousBatchingScheduler:
             "cow_copies": self.cow_copies,
             "preemptions": self.preemptions,
             "lru_evictions": self.metrics.counter("pool.evictions").value,
-            "kv_bytes_resident": self.kv_bytes_resident(),
-            "free_pages": (self.pool.available() if self._paged else None),
-            "prefix_entries": (self.pool.prefix_entries()
-                               if self._paged else 0),
+            **{k: pool[k] for k in ("kv_bytes_resident", "free_pages",
+                                    "prefix_entries")},
         }
 
     def lifecycle_stats(self) -> Dict[str, Any]:
@@ -1521,7 +1305,7 @@ class ContinuousBatchingScheduler:
             "bytes_per_token": bpt,
             "flops_per_token": fl / tok if tok else 0.0,
             "kv_read_bytes_per_token_max": self.roofline.kv_read_bytes(
-                self._prefill_len),
+                self._capacity),
             "roofline_tok_per_s": self.roofline.roofline_tok_per_s(bpt),
             "achieved_tok_per_s": tok / dt if dt > 0 else 0.0,
             "mbu": mbu,
@@ -1552,32 +1336,16 @@ class ContinuousBatchingScheduler:
         + (1 per prefix-cache entry spanning it).  Raises AssertionError
         on any mismatch — the refcount-leak canary the fault-injection
         suite runs after every degraded path."""
-        if not self._paged:
-            return
-        expected = np.zeros(self.num_pages, np.int64)
-        expected[GARBAGE_PAGE] = 1
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            for phys in self._pt_host[slot]:
-                if int(phys) != GARBAGE_PAGE:
-                    expected[int(phys)] += 1
-        expected += self.pool.entry_page_refs()
-        actual = np.asarray(self.pool.refcount, np.int64)
-        if not np.array_equal(expected, actual):
-            bad = np.nonzero(expected != actual)[0]
-            raise AssertionError(
-                f"refcount leak: pages {bad.tolist()} expected "
-                f"{expected[bad].tolist()} got {actual[bad].tolist()}")
+        if self.pages is not None:
+            self.pages.audit([s for s, r in enumerate(self.slots)
+                              if r is not None])
 
 
 # -- metric-backed attributes (the single stats surface) ---------------------
-# The ad-hoc counters of PRs 1-8 (prefill_s/decode_s timers, paged and
-# lifecycle tallies) now LIVE in the MetricsRegistry; the attribute names
-# every test/bench/engine already uses are preserved as read-write
-# properties over the registry cells, so `sched.preemptions += 1`,
-# `sched.prefill_s = 0.0` (bench warmup resets) and
-# `metrics.snapshot()["sched.preemptions"]` all see one number.
+# The counters live in the MetricsRegistry; these read-write properties
+# over its cells make `sched.preemptions += 1`, `sched.prefill_s = 0.0`
+# (bench warmup resets) and `metrics.snapshot()["sched.preemptions"]`
+# all see one number.
 
 _METRIC_ATTRS = {
     "host_syncs": "sched.host_syncs",

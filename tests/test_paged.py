@@ -24,8 +24,10 @@ from repro.kernels import decode_attention as da
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.launch.hlo_analysis import whole_buffer_ops
-from repro.runtime.pagepool import GARBAGE_PAGE, PagePool
+from repro.runtime.pagepool import (GARBAGE_PAGE, CopyEdit, EntryEdit,
+                                    LanePages, PagePool, RowEdit)
 from repro.runtime.scheduler import ContinuousBatchingScheduler, Request
+from repro.runtime.telemetry import MetricsRegistry
 
 KEY = jax.random.PRNGKey(0)
 
@@ -367,7 +369,7 @@ def test_no_page_leaks_across_admit_retire_cycles(tiny_sched_family):
         s.run()
         s.pool.leak_check()
         assert all(r is None for r in s.slots)
-        assert (s._pt_host == GARBAGE_PAGE).all()      # rows cleared
+        assert (s.pages.table == GARBAGE_PAGE).all()   # rows cleared
     while s.pool.evict_one():
         pass
     s.pool.leak_check()
@@ -475,6 +477,98 @@ def test_pagepool_prefix_lru_eviction():
     assert not pool.evict_one()
     assert pool.available() == 9
     pool.leak_check()
+
+
+# ---------------------------------------------------------------------------
+# LanePages: the one owner of the lanes' pages, table edits as data
+# ---------------------------------------------------------------------------
+
+
+def _lane_pages(**kw):
+    """Two lanes of three 4-token pages over a 10-page pool."""
+    kw.setdefault("prefix_sharing", True)
+    return LanePages(10, 4, 2, 3, capacity=12, alloc_mode="incremental",
+                     **kw)
+
+
+def test_lane_pages_first_touch_returns_one_entry_edit():
+    lp = _lane_pages()
+    (edit,) = lp.writable(0, 5)                   # position 5: entry 1
+    assert isinstance(edit, EntryEdit)
+    assert (edit.slot, edit.idx) == (0, 1) and edit.page != GARBAGE_PAGE
+    assert lp.table[0, 1] == edit.page
+    assert lp.pool.refcount[edit.page] == 1       # the lane's one ref
+    assert lp.writable(0, 6) == []                # owned: nothing to do
+    lp.audit([0])
+
+
+def test_lane_pages_write_to_shared_page_copies_it():
+    lp = _lane_pages()
+    prompt = [1, 2, 3, 4, 5, 6]                   # 1.5 pages
+    lp.take(0, lp.pages_for(len(prompt)))
+    lp.register(0, prompt)
+    t, row = lp.map_prefix(1, lp.lookup(prompt), len(prompt) + 1)
+    assert t == 6 and isinstance(row, RowEdit) and row.slot == 1
+    src = int(lp.table[1, 1])
+    assert list(row.row) == list(lp.table[0, :2]) + [GARBAGE_PAGE]
+    refs = int(lp.pool.refcount[src])
+    (edit,) = lp.writable(1, 6)                   # into the shared page
+    assert isinstance(edit, CopyEdit)
+    assert (edit.src, edit.slot, edit.idx) == (src, 1, 1)
+    assert edit.page != src and lp.table[1, 1] == edit.page
+    assert lp.pool.refcount[src] == refs - 1      # the lane's ref moved
+    assert lp.pool.refcount[edit.page] == 1
+    assert lp.lookup(prompt).pages[1] == src      # the entry is untouched
+    lp.audit([0, 1])
+
+
+def test_lane_pages_release_returns_zero_row_and_frees_every_ref():
+    lp = _lane_pages(prefix_sharing=False)
+    lp.take(0, 2)
+    lp.writable(0, 8)                             # first touch of page 3
+    edit = lp.release(0)
+    assert isinstance(edit, RowEdit) and edit.slot == 0
+    assert (edit.row == GARBAGE_PAGE).all()
+    assert (lp.table[0] == GARBAGE_PAGE).all()
+    assert lp.pool.available() == 9
+    lp.audit([])
+    lp.pool.leak_check()
+
+
+def test_lane_pages_audit_raises_on_planted_leak():
+    lp = _lane_pages()
+    lp.take(0, 2)
+    lp.audit([0])
+    lp.pool.ref(int(lp.table[0, 0]))              # a ref nobody holds
+    with pytest.raises(AssertionError, match="refcount leak"):
+        lp.audit([0])
+
+
+def test_lane_pages_refused_alloc_takes_no_pages_and_evicts_nothing():
+    """An injected allocation failure is hard exhaustion: no pages, no
+    LRU eviction to rescue it, counted in faults.alloc_failures."""
+    metrics = MetricsRegistry()
+    asked = []
+
+    def refuse(site, slot, n):
+        asked.append((site, slot, n))
+        return site == "admission"
+
+    lp = _lane_pages(metrics=metrics, refuse_alloc=refuse)
+    lp.writable(0, 0)
+    lp.writable(0, 4)                             # first touches pass
+    lp.register(0, [1, 2, 3, 4, 5])
+    lp.release(0)                                 # entries keep 2 pages
+    entries, free = lp.pool.prefix_entries(), lp.pool.available()
+    assert lp.take(1, 2) is None
+    assert asked[-1] == ("admission", 1, 2)
+    assert lp.pool.prefix_entries() == entries
+    assert lp.pool.available() == free
+    assert (lp.table[1] == GARBAGE_PAGE).all()
+    snap = metrics.snapshot()
+    assert snap["faults.alloc_failures"] == 1
+    assert snap.get("pool.evictions", 0) == 0
+    lp.audit([])
 
 
 @pytest.fixture(scope="module")

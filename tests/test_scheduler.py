@@ -1,5 +1,7 @@
 """Continuous-batching scheduler: zero host syncs per token, per-request
 temperature, mid-flight admission, and parity with the aligned baseline."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -25,6 +27,48 @@ def _sched(cfg, params, **kw):
     kw.setdefault("cache_len", 64)
     kw.setdefault("max_new_cap", 16)
     return ContinuousBatchingScheduler(cfg, params, **kw)
+
+
+def _module(lowered) -> str:
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+@pytest.mark.parametrize("kv_layout", ["ring", "paged"])
+def test_one_admission_program_named_admit(tiny, kv_layout):
+    """Both layouts admit through one program whose module is
+    ``jit__admit``, and no other scheduler program's name contains
+    ``jit__admit`` or ``jit__step``: the benchmark finds admission and
+    decode-step device time by those substrings."""
+    cfg, params = tiny
+    s = _sched(cfg, params, kv_layout=kv_layout, page_size=16)
+    i32, plen = jnp.int32, 8
+    stop = jnp.full((s.max_stop_tokens,), -1, jnp.int32)
+    pages = jnp.ones((1,), jnp.int32) if kv_layout == "paged" else None
+    admit = s._admit_fn.lower(s.params, s.state,
+                              jnp.zeros((1, plen), jnp.int32), i32(0),
+                              jnp.float32(0), i32(4), stop, pages, plen=plen)
+    assert _module(admit) == "jit__admit"
+    if kv_layout == "ring":
+        return
+    others = {
+        "_step_fn": (s.params, s.state),
+        "_deactivate_fn": (s.state, i32(0)),
+        "_suffix_step_fn": (s.params, s.state, i32(1), i32(0), i32(7)),
+        "_finalize_admit_fn": (s.state, jnp.zeros((cfg.vocab_size,)),
+                               i32(0), jnp.float32(0), i32(4), i32(plen),
+                               stop),
+        "_set_pt_row_fn": (s.state, i32(0),
+                           jnp.zeros((s.pages_per_lane,), jnp.int32)),
+        "_set_pt_entry_fn": (s.state, i32(0), i32(0), i32(1)),
+        "_copy_page_fn": (s.state, i32(1), i32(2), i32(0), i32(0)),
+    }
+    assert set(others) | {"_admit_fn"} == {
+        k for k in vars(s) if k.endswith("_fn")}
+    names = {k: _module(getattr(s, k).lower(*a)) for k, a in others.items()}
+    assert names["_step_fn"] == "jit__step"
+    for k, name in names.items():
+        assert "jit__admit" not in name, k
+        assert k == "_step_fn" or "jit__step" not in name, k
 
 
 def test_decode_loop_zero_host_syncs_per_token(tiny):
